@@ -8,8 +8,8 @@ pallas conv entry, ``dispatch_call`` re-resolves through its declared
 ``degrade_to`` chain (im2col), and the decision's ``measured_words`` /
 ``bound_ratio`` are re-priced for the degraded kernel. The sweep records
 that cost for the ResNet-50 shapes (the paper's §5 set) straight from
-``ops.explain`` on both backends — the 3.9-7.2x words gap a degraded
-dispatch pays — plus one *live* row: a rate-1.0 launch campaign actually
+``ops.explain`` on both backends — the words gap a degraded dispatch
+pays — plus one *live* row: a rate-1.0 launch campaign actually
 faults an eager conv2d, and the row records the repriced decision the
 quarantined dispatcher then reports. All fields are static word counts,
 identical on every CI leg.

@@ -36,6 +36,9 @@ def main(argv=None) -> None:
                          "(e.g. 'fig4' or 'fig4,serving')")
     args = ap.parse_args(argv)
 
+    from repro.launch.devices import use_compile_cache
+
+    use_compile_cache()
     from . import (autotune_bench, conv_bench, dist_bench,
                    fig2_single_processor, fig3_parallel, fig4_gemmini_tiling,
                    kernel_bench, roofline_table, serving_bench)

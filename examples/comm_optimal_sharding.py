@@ -9,7 +9,7 @@ cross-checks the result against the unsharded oracle.
     PYTHONPATH=src python examples/comm_optimal_sharding.py
 """
 
-from repro.launch import fake_devices
+from repro.launch import fake_devices, make_host_mesh
 
 fake_devices(8)  # before any jax device query
 
@@ -23,7 +23,7 @@ from repro.plan import ConvSpec, MatmulSpec, TPU_V5E, plan as make_plan  # noqa:
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     target = TPU_V5E.with_mesh((("data", 4), ("model", 2)))

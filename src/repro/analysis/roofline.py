@@ -9,7 +9,9 @@ are NOT in cost_analysis: we parse the post-SPMD module text and sum the
 result sizes of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute, with ring-model wire multipliers (all-reduce 2x).
 
-Hardware model: TPU v5e -> 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the published peaks of one chip, looked up by the
+``jax.Device.device_kind`` a run models (:data:`PEAKS`). A kind the table
+does not hold is an error, never a default.
 """
 
 from __future__ import annotations
@@ -20,9 +22,35 @@ from typing import Dict, Optional
 
 import numpy as np
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per inter-chip link
+
+
+V5E_KIND = "TPU v5 lite"  # what jax reports as a v5e's device_kind
+
+# ``jax.Device.device_kind`` -> peaks. Source: Google Cloud documentation,
+# "TPU v5e": per chip 197 TFLOP/s bf16, 819 GB/s of HBM bandwidth, and
+# 1,600 Gbit/s of inter-chip interconnect over four links.
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E_KIND: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                        ici_bw=1600e9 / 8 / 4),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The :data:`PEAKS` entry of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 # The unit every words_fn / bound / static audit counts in: 32-bit words.
 WORD_BYTES = 4
@@ -60,10 +88,11 @@ def words_to_bytes(words, dtypes=None):
 
 
 def hbm_seconds(words: float, chips: int = 1) -> float:
-    """Roofline memory time for a word count — the bridge from the static
+    """Roofline memory time for a word count on v5e chips (what the
+    planner's ``TPU_V5E`` target describes) — the bridge from the static
     auditor's exact HBM words to the same time model the dry-run rooflines
-    use (``memory_s = bytes / (chips * HBM_BW)``)."""
-    return words_to_bytes(words) / (chips * HBM_BW)
+    use (``memory_s = bytes / (chips * hbm_bw)``)."""
+    return words_to_bytes(words) / (chips * PEAKS[V5E_KIND].hbm_bw)
 
 
 # Per-DMA-transfer issue latency (descriptor setup + dispatch), the alpha of
@@ -154,18 +183,23 @@ class Roofline:
     collectives: Dict[str, float]
     model_flops: float
     bytes_per_device: Dict[str, float]
+    device_kind: str = V5E_KIND  # the chip the dry run compiles for
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / (self.chips * PEAK_FLOPS)
+        return self.hlo_flops / (self.chips * self.peaks.flops_bf16)
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
+        return self.hlo_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.wire_bytes_per_chip / ICI_BW
+        return self.wire_bytes_per_chip / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -188,12 +222,12 @@ class Roofline:
         """Model FLOPs / (roofline step time * peak): the roofline-fraction
         score (upper bounds real MFU)."""
         return self.model_flops / (
-            self.step_time_s * self.chips * PEAK_FLOPS + 1e-30)
+            self.step_time_s * self.chips * self.peaks.flops_bf16 + 1e-30)
 
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
             "wire_bytes_per_chip": self.wire_bytes_per_chip,
             "collectives": self.collectives,

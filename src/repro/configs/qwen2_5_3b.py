@@ -1,5 +1,5 @@
 """qwen2.5-3b [dense]: 36L d_model=2048 16H (GQA kv=2) d_ff=11008
-vocab=151936 - GQA, QKV bias. [hf:Qwen/Qwen2.5-0.5B; hf]"""
+vocab=151936 - GQA, QKV bias. [hf:Qwen/Qwen2.5-3B; hf]"""
 from repro.models.config import ModelConfig, reduced
 
 CONFIG = ModelConfig(

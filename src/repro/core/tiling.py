@@ -32,7 +32,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 from scipy.optimize import linprog
 
-from .conv_model import ConvShape, Precision, ceil_div
+from .conv_model import ConvShape, Precision, ceil_div, round_up
 
 AXES = ("N", "cI", "cO", "wO", "hO", "q6", "q7", "r6", "r7")
 
@@ -360,38 +360,124 @@ def optimize_blocking(
 
 
 # ---------------------------------------------------------------------------
-# Kernel-level footprints: what the lowered conv2d kernel actually holds in
-# VMEM for a (bN, b_cI, b_cO, b_hO, b_wO) tile. Differs from the lifted
-# Blocking model in two ways: the kernel always unrolls the full (h_F, w_F)
-# filter (no q/r blocking), and its input window is the exact halo extent
-# (b_hO - 1) * sh + h_F rather than the lifted (b_hO + b_q7 - 1) * b_r7.
+# Kernel-level geometry: what the lowered channels-last conv2d kernel streams
+# and holds in VMEM for a (bN, b_cI, b_cO, b_hO, b_wO) tile. The kernel runs
+# a stride-1 conv on the space-to-depth input (s_h x s_w phases folded into
+# the channel axis, so the filter shrinks to ceil(h_F/s_h) x ceil(w_F/s_w)
+# taps and no tap is a strided slice) laid out (H, W, N, C): channels on the
+# lane axis, the batch on the sublane axis, and both spatial axes leading
+# and untiled. It unrolls every tap (no q/r blocking), and its input window
+# is the exact halo extent b_hO + h_F' - 1 rows by b_wO + w_F' - 1 columns.
 # ---------------------------------------------------------------------------
 
+def layout_align(align: Tuple[int, int], p_in: float) -> Tuple[int, int]:
+    """(sublane, lane) multiples a kernel's blocks obey for a target's
+    ``(align_sublane, align_lane)`` and an input stream of ``p_in`` words:
+    packed dtypes stack 1/p_in rows per 32-bit sublane (bf16 -> 16)."""
+    sub, lane = (max(int(a), 1) for a in align)
+    if sub > 1:
+        sub = int(round(sub / min(p_in, 1.0)))
+    return sub, lane
+
+
+def _conv_layout_align(shape: ConvShape, align: Tuple[int, int]
+                       ) -> Tuple[int, int]:
+    """The alignment conv tiles are snapped to: the channels-last kernel's
+    for 16- and 32-bit streams. int8 streams feed the quantized conv
+    (``kernels/quant.py``), which keeps the NCHW halo geometry and takes its
+    tiles as planned."""
+    return (1, 1) if shape.prec.p_I < 0.5 else align
+
+
+def conv_kernel_tiles(shape: ConvShape, tiles: Sequence[int],
+                      align: Tuple[int, int] = (1, 1)
+                      ) -> Tuple[int, int, int, int, int]:
+    """Snap ``(bN, b_cI, b_cO, b_hO, b_wO)`` to blocks the kernel can lower:
+    clamped to the problem, bN sublane-aligned, b_cO lane-aligned, and b_cI
+    such that a block of its space-to-depth channels fills whole lanes (or
+    one block holds every channel). The spatial blocks are free. Idempotent;
+    with ``align=(1, 1)`` only the clamps apply. Aligned blocks may overhang
+    the problem — the kernel zero-pads, and the word counts charge the
+    padding."""
+    sub, lane = layout_align(_conv_layout_align(shape, align), shape.prec.p_I)
+    bN, b_cI, b_cO, bh, bw = (max(1, int(b)) for b in tiles)
+    phases = shape.sh * shape.sw
+    q = lane // math.gcd(lane, phases)
+    b_cI = shape.c_I if b_cI >= shape.c_I else round_up(b_cI, q)
+    return (round_up(min(bN, shape.N), sub), min(b_cI, shape.c_I),
+            round_up(min(b_cO, shape.c_O), lane), min(bh, shape.h_O),
+            min(bw, shape.w_O))
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvKernelGeometry:
+    """Launch geometry of the channels-last conv2d kernel: the single source
+    of truth shared by its lowering, its HBM-word counter, its access plan
+    and the planner's VMEM fit. Extents after the ``p`` suffix are padded;
+    channel extents count space-to-depth channels."""
+
+    tiles: Tuple[int, int, int, int, int]  # conv_kernel_tiles(...)
+    b_c: int  # space-to-depth channels per reduction block (lane-aligned)
+    hF: int  # taps of the stride-1 space-to-depth filter
+    wF: int
+    Np: int
+    Cp: int  # padded space-to-depth channels (n_ci * b_c)
+    cOp: int
+    hOp: int
+    wOp: int
+    Hp: int  # padded space-to-depth input rows / columns
+    Wp: int
+    h_in: int  # halo window of one tile
+    w_in: int
+    grid: Tuple[int, int, int, int, int]  # (n, co, h, w, ci), ci innermost
+
+
+def conv_kernel_geometry(shape: ConvShape, tiles: Sequence[int],
+                         align: Tuple[int, int] = (1, 1)
+                         ) -> ConvKernelGeometry:
+    _, lane = layout_align(_conv_layout_align(shape, align), shape.prec.p_I)
+    t = conv_kernel_tiles(shape, tiles, align)
+    bN, b_cI, b_cO, bh, bw = t
+    phases = shape.sh * shape.sw
+    hF = ceil_div(shape.h_F, shape.sh)
+    wF = ceil_div(shape.w_F, shape.sw)
+    n_ci = ceil_div(shape.c_I, b_cI)
+    b_c = round_up(b_cI * phases, lane)
+    Np, cOp = round_up(shape.N, bN), round_up(shape.c_O, b_cO)
+    hOp, wOp = round_up(shape.h_O, bh), round_up(shape.w_O, bw)
+    grid = (Np // bN, cOp // b_cO, hOp // bh, wOp // bw, n_ci)
+    return ConvKernelGeometry(
+        tiles=t, b_c=b_c, hF=hF, wF=wF, Np=Np, Cp=n_ci * b_c, cOp=cOp,
+        hOp=hOp, wOp=wOp, Hp=hOp + hF - 1, Wp=wOp + wF - 1,
+        h_in=bh + hF - 1, w_in=bw + wF - 1, grid=grid)
+
+
 def conv_kernel_footprints(shape: ConvShape, tiles: Sequence[int],
-                           prec: Optional[Precision] = None
+                           prec: Optional[Precision] = None,
+                           align: Tuple[int, int] = (1, 1)
                            ) -> Dict[str, float]:
-    """Words each array block of the spatially-tiled conv2d kernel occupies
-    in fast memory, for kernel tiles ``(bN, b_cI, b_cO, b_hO, b_wO)``.
+    """Words each array block of the conv2d kernel occupies in fast memory
+    for kernel tiles ``(bN, b_cI, b_cO, b_hO, b_wO)``, padding included.
     ``prec`` overrides the shape's own word-widths — the byte-weighted view
     a quantized storage policy (``repro.quant.PrecisionSpec.precision``)
     prices the same tiles at (int8 streams take a quarter of the VMEM the
     shape's nominal precision would charge)."""
-    bN, b_cI, b_cO, b_hO, b_wO = tiles
     p = prec if prec is not None else shape.prec
-    h_in = (b_hO - 1) * shape.sh + shape.h_F
-    w_in = (b_wO - 1) * shape.sw + shape.w_F
+    g = conv_kernel_geometry(shape, tiles, align)
+    bN, _, b_cO, bh, bw = g.tiles
     return {
-        "input": p.p_I * bN * b_cI * h_in * w_in,
-        "filter": p.p_F * b_cO * b_cI * shape.h_F * shape.w_F,
-        "output": p.p_O * bN * b_cO * b_hO * b_wO,
+        "input": p.p_I * bN * g.h_in * g.w_in * g.b_c,
+        "filter": p.p_F * g.hF * g.wF * g.b_c * b_cO,
+        "output": p.p_O * bN * bh * bw * b_cO,
     }
 
 
 def conv_kernel_tiles_fit(shape: ConvShape, tiles: Sequence[int],
-                          mem: MemoryModel) -> bool:
+                          mem: MemoryModel,
+                          align: Tuple[int, int] = (1, 1)) -> bool:
     """Whether the kernel tile's halo-window footprint obeys the same
     double-buffered capacity discipline the blocking LP planned under."""
-    fp = conv_kernel_footprints(shape, tiles)
+    fp = conv_kernel_footprints(shape, tiles, align=align)
     if mem.mode == "split":
         return (fp["input"] + fp["filter"] <= mem.M_eff
                 and fp["output"] <= mem.M_acc_eff)
@@ -399,25 +485,32 @@ def conv_kernel_tiles_fit(shape: ConvShape, tiles: Sequence[int],
 
 
 def fit_conv_kernel_tiles(shape: ConvShape, tiles: Sequence[int],
-                          mem: MemoryModel) -> Tuple[int, int, int, int, int]:
-    """Shrink kernel tiles (best-gain axis first) until the halo-window
-    footprint fits; the LP solution is usually already feasible, but its
-    lifted model can undercount when it blocked the filter taps."""
-    b = list(tiles)
-    while not conv_kernel_tiles_fit(shape, b, mem):
-        cur = sum(conv_kernel_footprints(shape, b).values())
-        best_i, best_gain = None, 0.0
+                          mem: MemoryModel, align: Tuple[int, int] = (1, 1)
+                          ) -> Tuple[int, int, int, int, int]:
+    """Snap kernel tiles to the layout (:func:`conv_kernel_tiles`), then
+    shrink them (best-gain axis first) until the halo-window footprint
+    fits; the LP solution is usually already feasible, but its lifted model
+    can undercount when it blocked the filter taps, and it prices no lane
+    or sublane padding."""
+    def total(t):
+        return sum(conv_kernel_footprints(shape, t, align=align).values())
+
+    b = list(conv_kernel_tiles(shape, tiles, align))
+    while not conv_kernel_tiles_fit(shape, b, mem, align):
+        cur = total(b)
+        best, best_gain = None, 0.0
         for i in range(5):
             if b[i] == 1:
                 continue
             trial = list(b)
             trial[i] = max(1, b[i] // 2)
-            gain = cur - sum(conv_kernel_footprints(shape, trial).values())
+            trial = list(conv_kernel_tiles(shape, trial, align))
+            gain = cur - total(trial)
             if gain > best_gain:
-                best_i, best_gain = i, gain
-        if best_i is None:
+                best, best_gain = trial, gain
+        if best is None:
             break
-        b[best_i] = max(1, b[best_i] // 2)
+        b = best
     return tuple(b)
 
 

@@ -3,8 +3,8 @@
 One value object — :class:`DistConvGeometry` — is the single source of truth
 shared by the executable ``shard_map`` paths (``repro.distributed.halo``) and
 the inter-device word counters (``conv2d_dist_comm_words`` /
-``allgather_comm_words``), exactly as PR 4's ``_launch_geometry`` ties the
-single-device kernel lowering to its HBM-word counter.
+``allgather_comm_words``), exactly as ``core.tiling.conv_kernel_geometry``
+ties the single-device kernel lowering to its HBM-word counter.
 
 The scheme (Demmel & Dinh 2018, Li et al. 2021): snap the integer processor
 grid of a :class:`~repro.core.parallel_tiling.ParallelBlocking` onto a device

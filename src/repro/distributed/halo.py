@@ -27,7 +27,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.parallel_tiling import ParallelBlocking, optimize_parallel_blocking
@@ -173,9 +172,10 @@ def halo_conv(x, w, stride=(1, 1), blocking=None, mesh: Optional[Mesh] = None,
             ol = jax.lax.psum(ol, "cI")
         return ol.astype(out_dtype)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P("N", "cI", "hO", "wO"), P(None, "cI", None, None)),
-                  out_specs=P("N", None, "hO", "wO"), check_rep=False)
+    f = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("N", "cI", "hO", "wO"), P(None, "cI", None, None)),
+        out_specs=P("N", None, "hO", "wO"), check_vma=False)
     out = f(xp, wp)
     if full_output:
         return out
@@ -223,9 +223,10 @@ def allgather_conv(x, w, stride=(1, 1), blocking=None,
                         out_dtype=jnp.float32)
         return ol.astype(out_dtype)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P("N", "cI", "hO", "wO"), P(None, "cI", None, None)),
-                  out_specs=P("N", None, "hO", "wO"), check_rep=False)
+    f = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("N", "cI", "hO", "wO"), P(None, "cI", None, None)),
+        out_specs=P("N", None, "hO", "wO"), check_vma=False)
     out = f(xp, wp)
     if full_output:
         return out

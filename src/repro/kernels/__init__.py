@@ -4,7 +4,9 @@ from ``repro.plan.plan``) or ``target=`` (a ``HardwareTarget``); the
 pre-redesign per-module planners (``plan_conv_tiles``, ``plan_tiles``) are
 retired, and so is the ``use_pallas=`` shim (``kernels/ops.py``) — pick a
 backend with ``repro.ops.ExecutionContext``. Validated against the pure-jnp
-oracles in ref.py with interpret=True on CPU.
+oracles in ref.py; ``repro.plan.pallas_interpret`` decides, from the backend
+the kernels execute on, whether they run interpreted (the CPU) or compiled
+through Mosaic (the TPU).
 
 Consumers should not call these modules directly: the ``repro.ops`` dispatch
 subsystem (ExecutionContext -> Backend -> kernel) routes each call to the

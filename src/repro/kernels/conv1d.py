@@ -14,9 +14,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_model import round_up
-from repro.plan import HardwareTarget
+from repro.plan import HardwareTarget, default_target, pallas_interpret
 
 
 def _conv1d_kernel(x_ref, w_ref, o_ref, *, K: int):
@@ -36,31 +37,29 @@ def conv1d_causal(
     w: jax.Array,  # (K, D)
     tiles: Tuple[int, int] | None = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """``target`` sets the channel-tile lane width and the interpret default;
-    the degenerate LP solution is the closed form in the module docstring."""
-    B, L, D = x.shape
+    """``target`` (default: the attached device's) sets the channel-tile
+    lane width; the degenerate LP solution is the closed form in the module
+    docstring."""
+    target = target if target is not None else default_target()
     K = w.shape[0]
-    lane = target.align_lane if target is not None else 128
-    sublane = target.align_sublane if target is not None else 8
-    bB, bD = tiles or (max(1, min(B, sublane)), max(1, min(D, lane)))
-    if interpret is None:
-        interpret = target.interpret if target is not None else True
-    Bp, Dp = round_up(B, bB), round_up(D, bD)
+    bB, bD, Bp, Dp, grid = _conv1d_geometry(x, w, tiles, target)
+    B, L, D = x.shape
     if (Bp, Dp) != (B, D):
         x = jnp.pad(x, ((0, Bp - B), (0, 0), (0, Dp - D)))
         w = jnp.pad(w, ((0, 0), (0, Dp - D)))
     out = pl.pallas_call(
         functools.partial(_conv1d_kernel, K=K),
-        grid=(Bp // bB, Dp // bD),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((bB, L, bD), lambda i, j: (i, 0, j)),
             pl.BlockSpec((K, bD), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bB, L, bD), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, L, Dp), x.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=target.vmem_limit_bytes),
+        interpret=pallas_interpret(),
     )(x, w)
     return out[:B, :, :D]
 
@@ -70,8 +69,10 @@ def _conv1d_geometry(x, w, tiles: Tuple[int, int] | None,
     """(bB, bD, Bp, Dp, grid) — the launch geometry :func:`conv1d_causal`
     lowers, shared with the words counter and the access plan."""
     B, L, D = x.shape
-    lane = target.align_lane if target is not None else 128
-    sublane = target.align_sublane if target is not None else 8
+    # a lane-wide channel tile and a sublane-high batch tile; a target that
+    # asks for no alignment (the CPU) gets the v5e's (8, 128)
+    align = target.align if target else (1, 1)
+    sublane, lane = (a if a > 1 else v5e for a, v5e in zip(align, (8, 128)))
     bB, bD = tiles or (max(1, min(B, sublane)), max(1, min(D, lane)))
     Bp, Dp = round_up(B, bB), round_up(D, bD)
     return bB, bD, Bp, Dp, (Bp // bB, Dp // bD)

@@ -6,25 +6,37 @@ buffer model) picks the channel/batch/spatial tile sizes; the f32 output tile
 plays the accumulator (held in VMEM across the c_I reduction, which is the
 innermost grid axis); input/filter tiles stream HBM->VMEM in low precision.
 
-Layout: NCHW input, OIHW filter, VALID padding, arbitrary stride — the exact
-7NL CNN of §2.1. Inside a tile the (h_F, w_F) loops are fully unrolled and
-each tap is one MXU GEMM of shape (bN*b_hO*b_wO, b_cI) x (b_cI, b_cO): the
-small-filter lift's q/r axes land in the unroll, channel axes land in the MXU.
+Public contract: NCHW input, OIHW filter, VALID padding, arbitrary stride —
+the exact 7NL CNN of §2.1. Inside, the kernel works channels-last, because
+the TPU tiles the two minor dims of every array by (sublane, lane) and can
+only slice whole tiles:
+
+  * one XLA layout pass (counted by ``conv2d_hbm_words``) turns the input
+    into space-to-depth (H, W, N, C): the s_h x s_w stride phases are folded
+    into the channel axis, so the strided conv becomes a stride-1 conv with
+    ceil(h_F/s_h) x ceil(w_F/s_w) taps and no tap is a strided slice; the
+    filter becomes (taps_h, taps_w, channels, c_O) to match;
+  * channels ride the lanes (padded to whole lanes), the batch rides the
+    sublanes (blocked in whole sublane tiles), and both spatial axes lead,
+    untiled — so the halo windows start at any row and column, and a tap is
+    a window at a static (row, column) offset that collapses, with no
+    relayout, into one MXU GEMM (pixels * bN, b_c) x (b_c, b_cO);
+  * a second layout pass crops the padding and returns NCHW.
 
 Spatial tiling is halo-aware: an output row block [i*bh, (i+1)*bh) needs the
-overlapping input window starting at row i*bh*sh of (bh - 1)*sh + h_F rows
-(consecutive windows share an h_F - sh row halo), and similarly for columns.
-Overlapping windows cannot be expressed with blocked BlockSpecs, so the input
-and filter stay in ANY/HBM memory and the kernel streams each window itself
-with ``pltpu.make_async_copy`` into a two-slot VMEM scratch, double-buffered
-across the c_I reduction grid axis: while the MXU runs the taps of reduction
-step ci, the DMAs for step ci + 1 are already in flight (§5's
-double-buffering, which is also why the LP halves usable capacity).
+overlapping input window of bh + taps_h - 1 space-to-depth rows, and
+similarly for columns. Overlapping windows cannot be expressed with blocked
+BlockSpecs, so the input and filter stay in ANY/HBM memory and the kernel
+streams each window itself with ``pltpu.make_async_copy`` into a two-slot
+VMEM scratch, double-buffered across the c_I reduction grid axis: while the
+MXU runs the taps of reduction step ci, the DMAs for step ci + 1 are already
+in flight (§5's double-buffering, which is also why the LP halves usable
+capacity).
 
 ``conv2d_hbm_words`` reports the measured HBM words one dispatch moves,
-computed from the same launch geometry the kernel lowers (grid x DMA window
-sizes + output stores) — the number ``ops.explain`` places next to the
-paper's Thm 2.1 lower bound.
+computed from the same launch geometry the kernel lowers (layout passes +
+grid x DMA window sizes + output stores) — the number ``ops.explain``
+places next to the paper's Thm 2.1 lower bound.
 """
 
 from __future__ import annotations
@@ -38,9 +50,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.conv_model import Precision, round_up
+from repro.core.conv_model import ConvShape, Precision
+from repro.core.tiling import ConvKernelGeometry, conv_kernel_geometry
 from repro.plan import (ConvSpec, ExecutionPlan, HardwareTarget,
-                        resolve_kernel_plan, warn_legacy_kernel_kwargs)
+                        pallas_interpret, resolve_kernel_plan,
+                        warn_legacy_kernel_kwargs)
+
+# Rows of one MXU GEMM per tap: the kernel walks its output tile in chunks of
+# output pixels x bN holding about this many rows, so the tap operand and
+# the f32 partial sum stay a few hundred KiB whatever the tile size.
+_GEMM_ROWS = 512
 
 
 def _conv_spec(N: int, c_I: int, c_O: int, h_O: int, w_O: int, h_F: int,
@@ -50,39 +69,69 @@ def _conv_spec(N: int, c_I: int, c_O: int, h_O: int, w_O: int, h_F: int,
                     sw=sw, sh=sh, prec=Precision(p_in, p_in, 1.0))
 
 
-def _normalize_tiles(tiles: Sequence[int], h_O: int, w_O: int
-                     ) -> Tuple[int, int, int, int, int]:
-    """Accept the legacy (bN, b_cI, b_cO) triple (spatial kept whole) or the
-    full (bN, b_cI, b_cO, b_hO, b_wO) planner tuple."""
-    if len(tiles) == 3:
-        return (*tiles, h_O, w_O)
-    bN, b_cI, b_cO, bh, bw = tiles
-    return (bN, b_cI, b_cO, max(1, min(bh, h_O)), max(1, min(bw, w_O)))
-
-
-def _launch_geometry(N: int, c_I: int, c_O: int, H: int, W: int, h_F: int,
-                     w_F: int, sh: int, sw: int,
-                     tiles: Tuple[int, int, int, int, int]):
-    """Padded dims, halo-window extents, and grid — the single source of
-    truth shared by the kernel lowering and the HBM-word counter."""
-    bN, b_cI, b_cO, bh, bw = tiles
+def _geometry(x, w, stride, plan=None, target=None, tiles=None, ctx=None
+              ) -> Tuple[ConvShape, ConvKernelGeometry, HardwareTarget]:
+    """Resolve tiles for these operands and lay out the launch."""
+    N, c_I, H, W = x.shape
+    c_O, _, h_F, w_F = w.shape
+    sh, sw = stride
     h_O = (H - h_F) // sh + 1
     w_O = (W - w_F) // sw + 1
-    Np, cIp, cOp = round_up(N, bN), round_up(c_I, b_cI), round_up(c_O, b_cO)
-    hOp, wOp = round_up(h_O, bh), round_up(w_O, bw)
-    # padded input must cover the last block's halo window
-    Hp = max(H, (hOp - 1) * sh + h_F)
-    Wp = max(W, (wOp - 1) * sw + w_F)
-    h_in = (bh - 1) * sh + h_F
-    w_in = (bw - 1) * sw + w_F
-    grid = (Np // bN, cOp // b_cO, hOp // bh, wOp // bw, cIp // b_cI)
-    return Np, cIp, cOp, hOp, wOp, Hp, Wp, h_in, w_in, grid
+    spec = _conv_spec(N, c_I, c_O, h_O, w_O, h_F, w_F, sh, sw,
+                      jnp.dtype(x.dtype).itemsize * 8)
+    t, tgt = resolve_kernel_plan(spec, plan=plan, target=target, tiles=tiles,
+                                 ctx=ctx)
+    if len(t) == 3:  # legacy (bN, b_cI, b_cO) triple: spatial kept whole
+        t = (*t, h_O, w_O)
+    shape = spec.to_shape(spec.prec)
+    g = conv_kernel_geometry(shape, t, tgt.align)
+    return shape, g, tgt
+
+
+def _input_layout(x: jax.Array, shape: ConvShape, g: ConvKernelGeometry
+                  ) -> jax.Array:
+    """NCHW -> padded space-to-depth (Hp, Wp, Np, Cp); the channel order
+    (c, phase_h, phase_w) keeps each c_I block contiguous."""
+    N, C, H, W = x.shape
+    sh, sw = shape.sh, shape.sw
+    rows, cols = g.Hp * sh, g.Wp * sw
+    cpad = g.grid[4] * g.tiles[1]
+    x = x[:, :, :min(H, rows), :min(W, cols)]
+    x = jnp.pad(x, ((0, g.Np - N), (0, cpad - C), (0, rows - x.shape[2]),
+                    (0, cols - x.shape[3])))
+    x = x.reshape(g.Np, cpad, g.Hp, sh, g.Wp, sw).transpose(2, 4, 0, 1, 3, 5)
+    x = x.reshape(g.Hp, g.Wp, g.Np, cpad * sh * sw)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, g.Cp - x.shape[3])))
+
+
+def _filter_layout(w: jax.Array, shape: ConvShape, g: ConvKernelGeometry
+                   ) -> jax.Array:
+    """OIHW -> (taps_h, taps_w, Cp, cOp), matching :func:`_input_layout`."""
+    c_O, C, h_F, w_F = w.shape
+    sh, sw = shape.sh, shape.sw
+    cpad = g.grid[4] * g.tiles[1]
+    w = jnp.pad(w, ((0, g.cOp - c_O), (0, cpad - C), (0, g.hF * sh - h_F),
+                    (0, g.wF * sw - w_F)))
+    w = w.reshape(g.cOp, cpad, g.hF, sh, g.wF, sw).transpose(2, 4, 1, 3, 5, 0)
+    w = w.reshape(g.hF, g.wF, cpad * sh * sw, g.cOp)
+    return jnp.pad(w, ((0, 0), (0, 0), (0, g.Cp - w.shape[2]), (0, 0)))
+
+
+def _gemm_block(bh: int, bw: int, bN: int) -> Tuple[int, int]:
+    """(rows, cols) of output pixels per tap GEMM: whole rows of the tile
+    while ``rows * bw * bN`` stays within ``_GEMM_ROWS``, else part of one
+    row (at least one pixel)."""
+    if bw * bN <= _GEMM_ROWS:
+        return max(r for r in range(1, bh + 1)
+                   if bh % r == 0 and r * bw * bN <= _GEMM_ROWS), bw
+    return 1, max(c for c in range(1, bw + 1)
+                  if bw % c == 0 and (c == 1 or c * bN <= _GEMM_ROWS))
 
 
 def _conv_kernel(x_hbm, w_hbm, o_ref, x_vmem, w_vmem, acc_ref, sems, *,
-                 n_ci: int, tiles: Tuple[int, int, int, int, int],
-                 h_in: int, w_in: int, h_F: int, w_F: int, sh: int, sw: int):
-    bN, b_cI, b_cO, bh, bw = tiles
+                 g: ConvKernelGeometry):
+    bN, _, b_cO, bh, bw = g.tiles
+    b_c, n_ci = g.b_c, g.grid[4]
     n, co, h, wb, ci = (pl.program_id(i) for i in range(5))
 
     def stream(slot, ci_idx):
@@ -90,11 +139,12 @@ def _conv_kernel(x_hbm, w_hbm, o_ref, x_vmem, w_vmem, acc_ref, sems, *,
         input window of this (n, h, wb) tile and the (co, ci) filter block."""
         return (
             pltpu.make_async_copy(
-                x_hbm.at[pl.ds(n * bN, bN), pl.ds(ci_idx * b_cI, b_cI),
-                         pl.ds(h * bh * sh, h_in), pl.ds(wb * bw * sw, w_in)],
+                x_hbm.at[pl.ds(h * bh, g.h_in), pl.ds(wb * bw, g.w_in),
+                         pl.ds(n * bN, bN), pl.ds(ci_idx * b_c, b_c)],
                 x_vmem.at[slot], sems.at[slot, 0]),
             pltpu.make_async_copy(
-                w_hbm.at[pl.ds(co * b_cO, b_cO), pl.ds(ci_idx * b_cI, b_cI)],
+                w_hbm.at[:, :, pl.ds(ci_idx * b_c, b_c),
+                         pl.ds(co * b_cO, b_cO)],
                 w_vmem.at[slot], sems.at[slot, 1]),
         )
 
@@ -114,24 +164,25 @@ def _conv_kernel(x_hbm, w_hbm, o_ref, x_vmem, w_vmem, acc_ref, sems, *,
     for cp in stream(slot, ci):
         cp.wait()
 
-    x = x_vmem[slot]  # (bN, b_cI, h_in, w_in)
-    w = w_vmem[slot]  # (b_cO, b_cI, h_F, w_F)
-    acc = acc_ref[...]
-    for hf in range(h_F):
-        for wf in range(w_F):
-            # strided tap window: (bN, b_cI, bh, bw)
-            tap = jax.lax.slice(
-                x,
-                (0, 0, hf, wf),
-                (bN, b_cI, hf + (bh - 1) * sh + 1, wf + (bw - 1) * sw + 1),
-                (1, 1, sh, sw),
-            )
-            # MXU GEMM: (bN*bh*bw, b_cI) @ (b_cI, b_cO)
-            lhs = tap.transpose(0, 2, 3, 1).reshape(bN * bh * bw, b_cI)
-            rhs = w[:, :, hf, wf].T  # (b_cI, b_cO)
-            out = jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
-            acc = acc + out.reshape(bN, bh, bw, b_cO).transpose(0, 3, 1, 2)
-    acc_ref[...] = acc
+    rows, cols = _gemm_block(bh, bw, bN)
+    per_row = bw // cols
+
+    def chunk(t, carry):
+        r0, c0 = (t // per_row) * rows, (t % per_row) * cols
+        part = None
+        for hf in range(g.hF):
+            for wf in range(g.wF):
+                # MXU GEMM: (rows * cols * bN, b_c) @ (b_c, b_cO)
+                tap = x_vmem[slot, pl.ds(r0 + hf, rows), pl.ds(c0 + wf, cols)]
+                out = jnp.dot(tap.reshape(rows * cols * bN, b_c),
+                              w_vmem[slot, hf, wf],
+                              preferred_element_type=jnp.float32)
+                part = out if part is None else part + out
+        acc_ref[pl.ds(r0, rows), pl.ds(c0, cols)] += part.reshape(
+            rows, cols, bN, b_cO)
+        return carry
+
+    jax.lax.fori_loop(0, (bh // rows) * per_row, chunk, 0)
 
     @pl.when(ci == n_ci - 1)
     def _store():
@@ -143,64 +194,49 @@ def conv2d(
     w: jax.Array,  # (c_O, c_I, h_F, w_F)
     stride: Tuple[int, int] = (1, 1),
     out_dtype=jnp.float32,
-    ctx=None,  # ExecutionContext (duck-typed: .target/.interpret/.autotune)
+    ctx=None,  # ExecutionContext (duck-typed: .target/.autotune)
     tiles: Optional[Sequence[int]] = None,
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Direct convolution with paper-LP tiling. VALID padding.
 
     Execution policy rides ``ctx`` (an ``repro.ops.ExecutionContext``:
-    target, interpret override, autotune policy). Tiles come from (in
-    priority order) an explicit legacy ``tiles`` tuple — (bN, b_cI, b_cO) or
+    target, autotune policy). Tiles come from (in priority order) an
+    explicit legacy ``tiles`` tuple — (bN, b_cI, b_cO) or
     (bN, b_cI, b_cO, b_hO, b_wO) — an explicit ``plan``
     (:class:`repro.plan.ExecutionPlan`, the dispatcher/autotuner handoff),
-    or a fresh plan resolved for the context's target (default TPU_V5E;
-    tuned winner when one is stored). ``target=``/``tiles=`` are legacy
-    (DeprecationWarning; lint VRF015); ``interpret`` defaults to the
-    target's policy (True everywhere until a real TPU backend is attached).
+    or a fresh plan resolved for the context's target (the attached
+    device's preset by default; tuned winner when one is stored). Tiles are
+    snapped to the target's layout (``core.tiling.conv_kernel_tiles``).
+    ``target=``/``tiles=`` are legacy (DeprecationWarning; lint VRF015).
     """
     warn_legacy_kernel_kwargs("conv2d", target=target, tiles=tiles)
-    N, c_I, H, W = x.shape
-    c_O, c_I2, h_F, w_F = w.shape
-    assert c_I == c_I2
-    sh, sw = stride
-    h_O = (H - h_F) // sh + 1
-    w_O = (W - w_F) // sw + 1
-    in_bits = jnp.dtype(x.dtype).itemsize * 8
-    t, interpret = resolve_kernel_plan(
-        _conv_spec(N, c_I, c_O, h_O, w_O, h_F, w_F, sh, sw, in_bits),
-        plan=plan, target=target, tiles=tiles, interpret=interpret, ctx=ctx)
-    t = _normalize_tiles(t, h_O, w_O)
-    bN, b_cI, b_cO, bh, bw = t
-    (Np, cIp, cOp, hOp, wOp, Hp, Wp, h_in, w_in,
-     grid) = _launch_geometry(N, c_I, c_O, H, W, h_F, w_F, sh, sw, t)
-
-    if (Np, cIp, Hp, Wp) != (N, c_I, H, W):
-        x = jnp.pad(x, ((0, Np - N), (0, cIp - c_I), (0, Hp - H),
-                        (0, Wp - W)))
-    if (cOp, cIp) != (c_O, c_I):
-        w = jnp.pad(w, ((0, cOp - c_O), (0, cIp - c_I), (0, 0), (0, 0)))
-
+    assert x.shape[1] == w.shape[1]
+    shape, g, tgt = _geometry(x, w, stride, plan=plan, target=target,
+                              tiles=tiles, ctx=ctx)
+    bN, _, b_cO, bh, bw = g.tiles
     out = pl.pallas_call(
-        functools.partial(_conv_kernel, n_ci=grid[4], tiles=t, h_in=h_in,
-                          w_in=w_in, h_F=h_F, w_F=w_F, sh=sh, sw=sw),
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((bN, b_cO, bh, bw),
-                               lambda n, co, h, wb, ci: (n, co, h, wb)),
-        out_shape=jax.ShapeDtypeStruct((Np, cOp, hOp, wOp), out_dtype),
+        functools.partial(_conv_kernel, g=g),
+        grid=g.grid,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((bh, bw, bN, b_cO),
+                               lambda n, co, h, wb, ci: (h, wb, n, co)),
+        out_shape=jax.ShapeDtypeStruct((g.hOp, g.wOp, g.Np, g.cOp),
+                                       out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, bN, b_cI, h_in, w_in), x.dtype),  # double-buffered
-            pltpu.VMEM((2, b_cO, b_cI, h_F, w_F), w.dtype),  # input + filter
-            pltpu.VMEM((bN, b_cO, bh, bw), jnp.float32),  # f32 accumulator
+            pltpu.VMEM((2, g.h_in, g.w_in, bN, g.b_c), x.dtype),  # double-
+            pltpu.VMEM((2, g.hF, g.wF, g.b_c, b_cO), w.dtype),  # buffered
+            pltpu.VMEM((bh, bw, bN, b_cO), jnp.float32),  # f32 accumulator
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        interpret=interpret,
-    )(x, w)
-    return out[:N, :c_O, :h_O, :w_O]
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tgt.vmem_limit_bytes),
+        interpret=pallas_interpret(),
+    )(_input_layout(x, shape, g), _filter_layout(w, shape, g))
+    out = out[:shape.h_O, :shape.w_O, :shape.N, :shape.c_O]
+    return out.transpose(2, 3, 0, 1)
 
 
 def exact_window(H: int, W: int, h_F: int, w_F: int, sh: int, sw: int
@@ -221,18 +257,16 @@ def conv2d_shard(
     ctx=None,
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Shard-local entry for ``repro.distributed``: the same LP-tiled kernel
     as :func:`conv2d`, but the input must be an exact halo window (the shape
     each shard assembles after its ``ppermute`` exchanges — no dead rows).
     Plans resolve for the *local* shape, so each shard tiles its own block."""
     warn_legacy_kernel_kwargs("conv2d_shard", target=target)
-    if ctx is None and (target is not None or interpret is not None):
-        # absorb the legacy kwargs here so the inner conv2d doesn't re-warn
+    if ctx is None and target is not None:
+        # absorb the legacy kwarg here so the inner conv2d doesn't re-warn
         from types import SimpleNamespace
-        ctx = SimpleNamespace(target=target, interpret=interpret,
-                              autotune=None)
+        ctx = SimpleNamespace(target=target, autotune=None)
     N, c_I, H, W = x.shape
     _, _, h_F, w_F = w.shape
     sh, sw = stride
@@ -245,6 +279,22 @@ def conv2d_shard(
                   plan=plan)
 
 
+def _layout_words(x, w, shape: ConvShape, g: ConvKernelGeometry, out_dtype
+                  ) -> Tuple[float, float, float]:
+    """HBM words of the three XLA layout passes around the kernel: input to
+    space-to-depth HWNC (read what is kept, write the padded copy), filter
+    to (taps, Cp, cOp), and the output crop + NCHW transpose."""
+    N, C, H, W = x.shape
+    p_in = jnp.dtype(x.dtype).itemsize / 4.0
+    p_flt = jnp.dtype(w.dtype).itemsize / 4.0
+    p_out = jnp.dtype(out_dtype).itemsize / 4.0
+    kept = N * C * min(H, g.Hp * shape.sh) * min(W, g.Wp * shape.sw)
+    x_words = p_in * (kept + g.Np * g.Hp * g.Wp * g.Cp)
+    w_words = p_flt * (math.prod(w.shape) + g.hF * g.wF * g.Cp * g.cOp)
+    o_words = 2.0 * p_out * shape.N * shape.c_O * shape.h_O * shape.w_O
+    return x_words, w_words, o_words
+
+
 def conv2d_access_plan(
     x,  # array or ShapeDtypeStruct, (N, c_I, H, W)
     w,  # array or ShapeDtypeStruct, (c_O, c_I, h_F, w_F)
@@ -255,72 +305,72 @@ def conv2d_access_plan(
     out_dtype=jnp.float32,
 ):
     """The :class:`repro.verify.access.KernelAccessPlan` of one ``conv2d``
-    launch, restated from :func:`_launch_geometry`.
+    launch, restated from :func:`repro.core.tiling.conv_kernel_geometry`.
 
     The input's ``requires`` region is derived *independently* of the DMA
-    window — from the output rows of the tile through the strided tap
-    arithmetic (output row o, tap hf reads input row o*sh + hf) — so an
-    off-by-one halo window fails the auditor's coverage check even though
-    its word count is unchanged."""
-    from repro.verify.access import (BlockAccess, KernelAccessPlan,
-                                     ScratchAlloc, WindowAccess)
+    window — from the output rows of the tile through the pixel arithmetic
+    (output row o, tap hf reads pixel row o*sh + hf, which lives in
+    space-to-depth row (o*sh + hf) // sh) — so an off-by-one halo window
+    fails the auditor's coverage check even though its word count is
+    unchanged. The layout passes are flat, counted accesses."""
+    from repro.verify.access import (BlockAccess, FlatAccess,
+                                     KernelAccessPlan, ScratchAlloc,
+                                     WindowAccess)
     from repro.verify.hazards import double_buffered_schedule
 
-    N, c_I, H, W = x.shape
-    c_O, _, h_F, w_F = w.shape
-    sh, sw = stride
-    h_O = (H - h_F) // sh + 1
-    w_O = (W - w_F) // sw + 1
-    in_bits = jnp.dtype(x.dtype).itemsize * 8
-    t, _ = resolve_kernel_plan(
-        _conv_spec(N, c_I, c_O, h_O, w_O, h_F, w_F, sh, sw, in_bits),
-        plan=plan, target=target, tiles=tiles)
-    t = _normalize_tiles(t, h_O, w_O)
-    bN, b_cI, b_cO, bh, bw = t
-    (Np, cIp, cOp, hOp, wOp, Hp, Wp, h_in, w_in,
-     grid) = _launch_geometry(N, c_I, c_O, H, W, h_F, w_F, sh, sw, t)
+    shape, g, _ = _geometry(x, w, stride, plan=plan, target=target,
+                            tiles=tiles)
+    bN, _, b_cO, bh, bw = g.tiles
+    b_c, sh, sw = g.b_c, shape.sh, shape.sw
     p_in = jnp.dtype(x.dtype).itemsize / 4.0
     p_flt = jnp.dtype(w.dtype).itemsize / 4.0
     p_out = jnp.dtype(out_dtype).itemsize / 4.0
+    x_layout, w_layout, o_layout = _layout_words(x, w, shape, g, out_dtype)
 
     def x_requires(n, co, h, wb, ci):
-        # first/last output row of the tile -> strided tap extent
+        # first/last output row of the tile -> last pixel row of its taps
         row_lo, row_hi = h * bh, h * bh + bh - 1
         col_lo, col_hi = wb * bw, wb * bw + bw - 1
-        return ((n * bN, (n + 1) * bN),
-                (ci * b_cI, (ci + 1) * b_cI),
-                (row_lo * sh, row_hi * sh + h_F),
-                (col_lo * sw, col_hi * sw + w_F))
+        return ((row_lo, (row_hi * sh + shape.h_F - 1) // sh + 1),
+                (col_lo, (col_hi * sw + shape.w_F - 1) // sw + 1),
+                (n * bN, (n + 1) * bN),
+                (ci * b_c, (ci + 1) * b_c))
 
     accesses = (
+        FlatAccess(name="input layout (NCHW -> space-to-depth HWNC)",
+                   kind="load", words=x_layout),
+        FlatAccess(name="filter layout (OIHW -> taps x Cp x cOp)",
+                   kind="load", words=w_layout),
         WindowAccess(
-            name="input", kind="load", array_shape=(Np, cIp, Hp, Wp),
+            name="input", kind="load", array_shape=(g.Hp, g.Wp, g.Np, g.Cp),
             word_size=p_in,
             window=lambda n, co, h, wb, ci: (
-                (n * bN, bN), (ci * b_cI, b_cI),
-                (h * bh * sh, h_in), (wb * bw * sw, w_in)),
+                (h * bh, g.h_in), (wb * bw, g.w_in), (n * bN, bN),
+                (ci * b_c, b_c)),
             requires=x_requires),
         WindowAccess(
-            name="filter", kind="load", array_shape=(cOp, cIp, h_F, w_F),
+            name="filter", kind="load", array_shape=(g.hF, g.wF, g.Cp, g.cOp),
             word_size=p_flt,
             window=lambda n, co, h, wb, ci: (
-                (co * b_cO, b_cO), (ci * b_cI, b_cI), (0, h_F), (0, w_F)),
+                (0, g.hF), (0, g.wF), (ci * b_c, b_c), (co * b_cO, b_cO)),
             requires=lambda n, co, h, wb, ci: (
-                (co * b_cO, (co + 1) * b_cO), (ci * b_cI, (ci + 1) * b_cI),
-                (0, h_F), (0, w_F))),
+                (0, g.hF), (0, g.wF), (ci * b_c, (ci + 1) * b_c),
+                (co * b_cO, (co + 1) * b_cO))),
         BlockAccess(
-            name="output", kind="store", block_shape=(bN, b_cO, bh, bw),
-            array_shape=(Np, cOp, hOp, wOp), word_size=p_out,
-            index_map=lambda n, co, h, wb, ci: (n, co, h, wb)),
+            name="output", kind="store", block_shape=(bh, bw, bN, b_cO),
+            array_shape=(g.hOp, g.wOp, g.Np, g.cOp), word_size=p_out,
+            index_map=lambda n, co, h, wb, ci: (h, wb, n, co)),
+        FlatAccess(name="output layout (crop + HWNC -> NCHW)", kind="store",
+                   words=o_layout),
     )
     scratch = (
-        ScratchAlloc("x_vmem[2]", 2 * bN * b_cI * h_in * w_in * p_in),
-        ScratchAlloc("w_vmem[2]", 2 * b_cO * b_cI * h_F * w_F * p_flt),
-        ScratchAlloc("acc_f32", float(bN * b_cO * bh * bw)),
+        ScratchAlloc("x_vmem[2]", 2 * bN * g.h_in * g.w_in * b_c * p_in),
+        ScratchAlloc("w_vmem[2]", 2 * g.hF * g.wF * b_c * b_cO * p_flt),
+        ScratchAlloc("acc_f32", float(bN * bh * bw * b_cO)),
     )
     return KernelAccessPlan(
-        op="conv2d", grid=grid, accesses=accesses, scratch=scratch,
-        dma=double_buffered_schedule(grid[4], n_slots=2,
+        op="conv2d", grid=g.grid, accesses=accesses, scratch=scratch,
+        dma=double_buffered_schedule(g.grid[4], n_slots=2,
                                      name="input/filter c_I stream"),
         note="DMA schedule repeats identically per (n, co, h, w) tile")
 
@@ -336,27 +386,19 @@ def conv2d_hbm_words(
 ) -> float:
     """Measured HBM words (32-bit) one ``conv2d`` dispatch moves.
 
-    Counts exactly what the kernel lowers for these arguments: one input
-    halo window + one filter block DMA'd per grid step, one output block
-    stored per (n, co, h, w) tile — padding included. Only shapes/dtypes are
-    consulted, so ``jax.ShapeDtypeStruct`` arguments work (no execution)."""
-    N, c_I, H, W = x.shape
-    c_O, _, h_F, w_F = w.shape
-    sh, sw = stride
-    h_O = (H - h_F) // sh + 1
-    w_O = (W - w_F) // sw + 1
-    in_bits = jnp.dtype(x.dtype).itemsize * 8
-    t, _ = resolve_kernel_plan(
-        _conv_spec(N, c_I, c_O, h_O, w_O, h_F, w_F, sh, sw, in_bits),
-        plan=plan, target=target, tiles=tiles)
-    t = _normalize_tiles(t, h_O, w_O)
-    bN, b_cI, b_cO, bh, bw = t
-    (Np, cIp, cOp, hOp, wOp, _, _, h_in, w_in,
-     grid) = _launch_geometry(N, c_I, c_O, H, W, h_F, w_F, sh, sw, t)
-    n_steps = math.prod(grid)
+    Counts exactly what the kernel lowers for these arguments: the layout
+    passes, one input halo window + one filter block DMA'd per grid step,
+    and one output block stored per (n, co, h, w) tile — padding included.
+    Only shapes/dtypes are consulted, so ``jax.ShapeDtypeStruct`` arguments
+    work (no execution)."""
+    shape, g, _ = _geometry(x, w, stride, plan=plan, target=target,
+                            tiles=tiles)
+    bN, _, b_cO, bh, bw = g.tiles
+    n_steps = math.prod(g.grid)
     p_in = jnp.dtype(x.dtype).itemsize / 4.0
     p_flt = jnp.dtype(w.dtype).itemsize / 4.0
     p_out = jnp.dtype(out_dtype).itemsize / 4.0
-    return (n_steps * bN * b_cI * h_in * w_in * p_in
-            + n_steps * b_cO * b_cI * h_F * w_F * p_flt
-            + Np * cOp * hOp * wOp * p_out)
+    return (sum(_layout_words(x, w, shape, g, out_dtype))
+            + n_steps * bN * g.h_in * g.w_in * g.b_c * p_in
+            + n_steps * g.hF * g.wF * g.b_c * b_cO * p_flt
+            + g.Np * g.hOp * g.wOp * g.cOp * p_out)

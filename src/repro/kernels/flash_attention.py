@@ -23,7 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_model import round_up
 from repro.core.tiling import attention_block_size
-from repro.plan import HardwareTarget
+from repro.plan import HardwareTarget, default_target, pallas_interpret
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -154,7 +154,6 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
     q_seq_len: Optional[int] = None,
     kv_lens: Optional[jax.Array] = None,  # int32 (BH,): valid keys per row
 ) -> jax.Array:
@@ -177,8 +176,8 @@ def flash_attention(
             tq, tk = DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
         block_q = block_q if block_q is not None else tq
         block_k = block_k if block_k is not None else tk
-    if interpret is None:
-        interpret = target.interpret if target is not None else True
+    params = pltpu.CompilerParams(vmem_limit_bytes=(
+        target if target is not None else default_target()).vmem_limit_bytes)
     scale = 1.0 / (Dh ** 0.5)
     bq = min(block_q, round_up(Lq, 8))
     bk = min(block_k, round_up(Lk, 8))
@@ -225,7 +224,8 @@ def flash_attention(
                 scratch_shapes=scratch,
             ),
             out_shape=jax.ShapeDtypeStruct((BH, Lqp, Dh), q.dtype),
-            interpret=interpret,
+            compiler_params=params,
+            interpret=pallas_interpret(),
         )(offs, lens, q, k, v)
         return out[:, :Lq]
 
@@ -245,7 +245,8 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, bq, Dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Lqp, Dh), q.dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        compiler_params=params,
+        interpret=pallas_interpret(),
     )(q, k, v)
     return out[:, :Lq]
 
@@ -305,7 +306,6 @@ def paged_decode_attention(
     tables: jax.Array,   # (B, w) int32 - physical block ids per sequence
     lengths: jax.Array,  # (B,) int32 - valid cache length per sequence
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Decode attention that reads K/V directly out of the paged pool.
 
@@ -321,8 +321,8 @@ def paged_decode_attention(
     KV, block_size = kp.shape[1], kp.shape[2]
     w = tables.shape[1]
     g = H // KV
-    if interpret is None:
-        interpret = target.interpret if target is not None else True
+    params = pltpu.CompilerParams(vmem_limit_bytes=(
+        target if target is not None else default_target()).vmem_limit_bytes)
     scale = 1.0 / (hd ** 0.5)
     qf = q.reshape(B, KV, g, hd)
     kernel = functools.partial(
@@ -348,7 +348,8 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
-        interpret=interpret,
+        compiler_params=params,
+        interpret=pallas_interpret(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, kp, vp)
     return out.reshape(B, H, 1, hd)
 

@@ -13,9 +13,11 @@ double-buffered Pallas kernel the direct path uses for its taps, so the
 comparison isolates the *algorithm's* communication, not kernel quality.
 
 ``im2col_hbm_words`` counts the words the baseline moves: read the input,
-write the expanded patch matrix, then the GEMM's measured stream/store words
-— the number the direct kernel's halo tiling is supposed to beat (the
-paper's 13-150% Im2Col-vs-tiled gap).
+write the expanded patch matrix, the GEMM's measured stream/store words, and
+the pass that turns the GEMM's (pixel, c_O) rows back into NCHW — the number
+the direct kernel's halo tiling is supposed to beat (the paper's 13-150%
+Im2Col-vs-tiled gap), counted as the direct kernel counts its own layout
+passes.
 """
 
 from __future__ import annotations
@@ -57,7 +59,6 @@ def conv2d_im2col(
     out_dtype=jnp.float32,
     ctx=None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Im2Col convolution (VALID padding): patches -> LP-tiled Pallas GEMM.
     Execution policy rides ``ctx``; ``target=`` is legacy (DeprecationWarning;
@@ -65,11 +66,10 @@ def conv2d_im2col(
     from repro.plan import warn_legacy_kernel_kwargs
 
     warn_legacy_kernel_kwargs("conv2d_im2col", target=target)
-    if ctx is None and (target is not None or interpret is not None):
-        # absorb the legacy kwargs so the inner matmul doesn't re-warn
+    if ctx is None and target is not None:
+        # absorb the legacy kwarg so the inner matmul doesn't re-warn
         from types import SimpleNamespace
-        ctx = SimpleNamespace(target=target, interpret=interpret,
-                              autotune=None)
+        ctx = SimpleNamespace(target=target, autotune=None)
     N, c_I, H, W = x.shape
     c_O, c_I2, h_F, w_F = w.shape
     assert c_I == c_I2
@@ -92,8 +92,9 @@ def im2col_hbm_words(
 ) -> float:
     """Measured HBM words (32-bit) one ``conv2d_im2col`` dispatch moves:
     patch expansion (read input + write the expanded matrix, as in the
-    paper's im2col volume model) plus the Pallas GEMM's measured words for
-    the launch geometry its plan resolves. Shapes/dtypes only."""
+    paper's im2col volume model), the Pallas GEMM's measured words for the
+    launch geometry its plan resolves, and the NCHW output pass (read and
+    write the (m, c_O) result). Shapes/dtypes only."""
     N, c_I, H, W = x.shape
     c_O, _, h_F, w_F = w.shape
     sh, sw = stride
@@ -106,7 +107,12 @@ def im2col_hbm_words(
         jax.ShapeDtypeStruct((m, k), x.dtype),
         jax.ShapeDtypeStruct((k, c_O), w.dtype),
         target=target, out_dtype=out_dtype)
-    return expand + gemm
+    return expand + gemm + _output_layout_words(m, c_O, out_dtype)
+
+
+def _output_layout_words(m: int, c_O: int, out_dtype) -> float:
+    """Words of the pass that reorders the GEMM's (m, c_O) rows to NCHW."""
+    return 2.0 * jnp.dtype(out_dtype).itemsize / 4.0 * m * c_O
 
 
 def im2col_access_plan(
@@ -118,9 +124,9 @@ def im2col_access_plan(
 ):
     """The :class:`repro.verify.access.KernelAccessPlan` of one
     ``conv2d_im2col`` dispatch: the GEMM's access plan (same grid, same A/B
-    windows over the patch matrix) prefixed with the XLA patch expansion as
-    flat traffic — read the input once, write the (m, k) patch matrix once —
-    exactly what ``im2col_hbm_words`` charges."""
+    windows over the patch matrix) between the XLA passes as flat traffic —
+    read the input once, write the (m, k) patch matrix once, and reorder the
+    (m, c_O) result to NCHW — exactly what ``im2col_hbm_words`` charges."""
     import dataclasses as _dc
 
     from repro.verify.access import FlatAccess
@@ -144,5 +150,8 @@ def im2col_access_plan(
                    words=p_in * float(m) * k,
                    note="XLA patch expansion writes the (m, k) matrix"),
     )
-    return _dc.replace(gemm, accesses=expand + gemm.accesses,
-                       note="patch expansion + LP-tiled GEMM")
+    layout = FlatAccess(name="im2col_output_layout", kind="store",
+                        words=_output_layout_words(m, c_O, out_dtype),
+                        note="XLA reorders the (m, c_O) result to NCHW")
+    return _dc.replace(gemm, accesses=expand + gemm.accesses + (layout,),
+                       note="patch expansion + LP-tiled GEMM + NCHW pass")

@@ -1,4 +1,4 @@
-"""LP-tiled Pallas matmul (TPU target, validated with interpret=True on CPU).
+"""LP-tiled Pallas matmul.
 
 Block shapes (bm, bn, bk) come from the paper's blocking LP applied to the
 degenerate 7NL CNN (w_F = h_F = w_O = h_O = 1): the same machinery that tiles
@@ -30,7 +30,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_model import Precision, round_up
 from repro.plan import (ExecutionPlan, HardwareTarget, MatmulSpec,
-                        resolve_kernel_plan, warn_legacy_kernel_kwargs)
+                        pallas_interpret, resolve_kernel_plan,
+                        warn_legacy_kernel_kwargs)
 
 
 def _matmul_spec(m: int, n: int, k: int, in_bits: int) -> MatmulSpec:
@@ -83,11 +84,10 @@ def matmul(
     a: jax.Array,  # (m, k)
     b: jax.Array,  # (k, n)
     out_dtype=jnp.float32,
-    ctx=None,  # ExecutionContext (duck-typed: .target/.interpret/.autotune)
+    ctx=None,  # ExecutionContext (duck-typed: .target/.autotune)
     tiles: Tuple[int, int, int] | None = None,
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """C[m,n] = A @ B with LP-chosen VMEM tiling.
 
@@ -101,9 +101,9 @@ def matmul(
     k2, n = b.shape
     assert k == k2, f"inner dims mismatch: {k} vs {k2}"
     in_bits = jnp.dtype(a.dtype).itemsize * 8
-    (bm, bn, bk), interpret = resolve_kernel_plan(
+    (bm, bn, bk), tgt = resolve_kernel_plan(
         _matmul_spec(m, n, k, in_bits),
-        plan=plan, target=target, tiles=tiles, interpret=interpret, ctx=ctx)
+        plan=plan, target=target, tiles=tiles, ctx=ctx)
 
     mp, np_, kp = round_up(m, bm), round_up(n, bn), round_up(k, bk)
     if (mp, kp) != (m, k):
@@ -115,8 +115,8 @@ def matmul(
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, nk=nk, bm=bm, bn=bn, bk=bk),
         grid=(nm, nn, nk),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[
@@ -125,7 +125,9 @@ def matmul(
             pltpu.VMEM((bm, bn), jnp.float32),  # f32 accumulator
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tgt.vmem_limit_bytes),
+        interpret=pallas_interpret(),
     )(a, b)
     return out[:m, :n]
 

@@ -1,7 +1,9 @@
 """Quantized (int8-stream) conv2d / matmul Pallas kernels.
 
-Same LP-tiled launch geometry as ``kernels/conv2d.py`` / ``kernels/matmul.py``
-(the geometry helpers are imported, not restated), but the input and filter
+Same LP tiling as ``kernels/conv2d.py`` / ``kernels/matmul.py`` (the conv
+keeps the NCHW halo-window geometry of ``_launch_geometry`` below, which
+Mosaic does not lower yet; the channels-last rework reached only the bf16
+conv), but the input and filter
 stream HBM->VMEM as int8 — a quarter word per element — so the blocking LP,
 solving against ``Precision(0.25, 0.25, p_out)``, buys roughly 2x bigger
 tiles from the same VMEM and the Thm 2.1 bound itself drops (see
@@ -39,9 +41,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_model import Precision, round_up
 from repro.plan import (ConvSpec, ExecutionPlan, HardwareTarget, MatmulSpec,
-                        resolve_kernel_plan, warn_legacy_kernel_kwargs)
+                        pallas_interpret, resolve_kernel_plan,
+                        warn_legacy_kernel_kwargs)
 
-from .conv2d import _launch_geometry, _normalize_tiles
+
+def _normalize_tiles(tiles: Sequence[int], h_O: int, w_O: int
+                     ) -> Tuple[int, int, int, int, int]:
+    """Accept the legacy (bN, b_cI, b_cO) triple (spatial kept whole) or the
+    full (bN, b_cI, b_cO, b_hO, b_wO) planner tuple."""
+    if len(tiles) == 3:
+        return (*tiles, h_O, w_O)
+    bN, b_cI, b_cO, bh, bw = tiles
+    return (bN, b_cI, b_cO, max(1, min(bh, h_O)), max(1, min(bw, w_O)))
+
+
+def _launch_geometry(N: int, c_I: int, c_O: int, H: int, W: int, h_F: int,
+                     w_F: int, sh: int, sw: int,
+                     tiles: Tuple[int, int, int, int, int]):
+    """Padded dims, halo-window extents, and grid of the NCHW int8 conv —
+    the single source of truth shared by its lowering and its counters."""
+    bN, b_cI, b_cO, bh, bw = tiles
+    h_O = (H - h_F) // sh + 1
+    w_O = (W - w_F) // sw + 1
+    Np, cIp, cOp = round_up(N, bN), round_up(c_I, b_cI), round_up(c_O, b_cO)
+    hOp, wOp = round_up(h_O, bh), round_up(w_O, bw)
+    # padded input must cover the last block's halo window
+    Hp = max(H, (hOp - 1) * sh + h_F)
+    Wp = max(W, (wOp - 1) * sw + w_F)
+    h_in = (bh - 1) * sh + h_F
+    w_in = (bw - 1) * sw + w_F
+    grid = (Np // bN, cOp // b_cO, hOp // bh, wOp // bw, cIp // b_cI)
+    return Np, cIp, cOp, hOp, wOp, Hp, Wp, h_in, w_in, grid
 
 
 def _wordwidth(dtype) -> float:
@@ -139,11 +169,10 @@ def conv2d_q(
     scale: jax.Array,  # (1, c_O) f32: folded s_x * s_w[c_O]
     stride: Tuple[int, int] = (1, 1),
     out_dtype=jnp.bfloat16,
-    ctx=None,  # ExecutionContext (duck-typed: .target/.interpret/.autotune)
+    ctx=None,  # ExecutionContext (duck-typed: .target/.autotune)
     tiles: Optional[Sequence[int]] = None,
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Quantized direct convolution (VALID padding): int8 operand streams,
     f32 accumulation, one folded per-output-channel scale applied at the
@@ -158,10 +187,10 @@ def conv2d_q(
     sh, sw = stride
     h_O = (H - h_F) // sh + 1
     w_O = (W - w_F) // sw + 1
-    t, interpret = resolve_kernel_plan(
+    t, tgt = resolve_kernel_plan(
         _conv_spec_q(N, c_I, c_O, h_O, w_O, h_F, w_F, sh, sw, x.dtype,
                      w.dtype, out_dtype),
-        plan=plan, target=target, tiles=tiles, interpret=interpret, ctx=ctx)
+        plan=plan, target=target, tiles=tiles, ctx=ctx)
     t = _normalize_tiles(t, h_O, w_O)
     bN, b_cI, b_cO, bh, bw = t
     (Np, cIp, cOp, hOp, wOp, Hp, Wp, h_in, w_in,
@@ -180,8 +209,8 @@ def conv2d_q(
                           w_in=w_in, h_F=h_F, w_F=w_F, sh=sh, sw=sw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # constant index map: Pallas fetches the scale vector exactly
             # once per launch (c_O words — what words_fn charges)
             pl.BlockSpec((1, cOp), lambda n, co, h, wb, ci: (0, 0)),
@@ -195,7 +224,9 @@ def conv2d_q(
             pltpu.VMEM((bN, b_cO, bh, bw), jnp.float32),  # f32 accumulator
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tgt.vmem_limit_bytes),
+        interpret=pallas_interpret(),
     )(x, w, scale)
     return out[:N, :c_O, :h_O, :w_O]
 
@@ -366,11 +397,10 @@ def matmul_q(
     b: jax.Array,  # (k, n) int8
     scale: jax.Array,  # (1, n) f32: folded s_a * s_b[n]
     out_dtype=jnp.bfloat16,
-    ctx=None,  # ExecutionContext (duck-typed: .target/.interpret/.autotune)
+    ctx=None,  # ExecutionContext (duck-typed: .target/.autotune)
     tiles: Optional[Tuple[int, int, int]] = None,
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Quantized GEMM: int8 A/B streams double-buffered over k, f32
     accumulator, folded per-column scale applied at the store. Operands come
@@ -382,9 +412,9 @@ def matmul_q(
     k2, n = b.shape
     assert k == k2, f"inner dims mismatch: {k} vs {k2}"
     assert scale.shape == (1, n), f"scale must be (1, {n}), got {scale.shape}"
-    (bm, bn, bk), interpret = resolve_kernel_plan(
+    (bm, bn, bk), tgt = resolve_kernel_plan(
         _matmul_spec_q(m, n, k, a.dtype, b.dtype, out_dtype),
-        plan=plan, target=target, tiles=tiles, interpret=interpret, ctx=ctx)
+        plan=plan, target=target, tiles=tiles, ctx=ctx)
 
     mp, np_, kp = round_up(m, bm), round_up(n, bn), round_up(k, bk)
     if (mp, kp) != (m, k):
@@ -399,8 +429,8 @@ def matmul_q(
         functools.partial(_matmul_q_kernel, nk=nk, bm=bm, bn=bn, bk=bk),
         grid=(nm, nn, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, np_), lambda i, j, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -411,7 +441,9 @@ def matmul_q(
             pltpu.VMEM((bm, bn), jnp.float32),  # f32 accumulator
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tgt.vmem_limit_bytes),
+        interpret=pallas_interpret(),
     )(a, b, scale)
     return out[:m, :n]
 

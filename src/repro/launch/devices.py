@@ -1,4 +1,5 @@
-"""Fake-device configuration for host-CPU multi-device runs.
+"""Device-side process setup: fake host devices for CPU multi-device runs,
+and the persistent compilation cache.
 
 jax locks the device count at first backend initialization, so the
 ``--xla_force_host_platform_device_count`` XLA flag must be set before any
@@ -11,10 +12,14 @@ import-time ``os.environ`` mutation that used to live in ``launch/dryrun.py``.
 from __future__ import annotations
 
 import os
+import pathlib
 import re
 import sys
 
 _FLAG = "--xla_force_host_platform_device_count"
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache (gitignored): this file is src/repro/launch/devices.py
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _declared_count() -> int:
@@ -65,3 +70,19 @@ def fake_devices(n: int) -> int:
         flags = f"{flags} {_FLAG}={n}".strip()
     os.environ["XLA_FLAGS"] = flags
     return n
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory and
+    return it: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads the
+    variable itself, so nothing is set here), else ``<checkout>/.jax_cache``.
+    The path never holds a temporary directory, a pid or a time, so a later
+    process in the same checkout finds what this one compiled. Call before
+    the first compile; every launcher and ``chip_smoke.py`` does."""
+    path = os.environ.get(CACHE_DIR_ENV)
+    if path:
+        return path
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return str(DEFAULT_CACHE_DIR)

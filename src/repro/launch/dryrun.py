@@ -211,16 +211,17 @@ def _extrapolate_cell(arch: str, shape_name: str, multi_pod: bool,
         for k in two["bytes_per_device"]}
     out["model_flops"] = rl.model_flops(arch, shape_name)
     chips = out["chips"]
-    out["compute_s"] = out["hlo_flops"] / (chips * rl.PEAK_FLOPS)
-    out["memory_s"] = out["hlo_bytes"] / (chips * rl.HBM_BW)
-    out["collective_s"] = out["wire_bytes_per_chip"] / rl.ICI_BW
+    peaks = rl.chip_peaks(out["device_kind"])
+    out["compute_s"] = out["hlo_flops"] / (chips * peaks.flops_bf16)
+    out["memory_s"] = out["hlo_bytes"] / (chips * peaks.hbm_bw)
+    out["collective_s"] = out["wire_bytes_per_chip"] / peaks.ici_bw
     terms = {"compute": out["compute_s"], "memory": out["memory_s"],
              "collective": out["collective_s"]}
     out["dominant"] = max(terms, key=terms.get)
     out["step_time_s"] = max(terms.values())
     out["useful_flops_frac"] = out["model_flops"] / max(out["hlo_flops"], 1.0)
     out["mfu"] = out["model_flops"] / (
-        out["step_time_s"] * chips * rl.PEAK_FLOPS + 1e-30)
+        out["step_time_s"] * chips * peaks.flops_bf16 + 1e-30)
     out["extrapolated"] = f"R=1,2 -> R={R}"
     mesh_name = out["mesh"]
     if verbose:

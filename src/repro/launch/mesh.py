@@ -10,6 +10,17 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules and
+    ``with_sharding_constraint`` calls in this repo hand their axes to the
+    partitioner, which ``Explicit`` axes (JAX's default since 0.7) forbid.
+    Every mesh in the repo is built here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     is the DCN/outer-DP axis (hierarchical gradient reduction)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_conv_mesh(blocking):
@@ -45,4 +56,4 @@ def make_conv_mesh(blocking):
             f"blocking grid {dict(zip(DIST_AXES, sizes))} needs {P} devices "
             f"but only {len(devs)} exist (launch.fake_devices(n) must run "
             f"before jax initializes)")
-    return jax.make_mesh(sizes, DIST_AXES, devices=devs[:P])
+    return make_mesh(sizes, DIST_AXES, devices=devs[:P])

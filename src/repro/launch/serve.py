@@ -16,11 +16,74 @@ from __future__ import annotations
 
 import argparse
 import collections
+import dataclasses
 import logging
 import time
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+
+
+def build_engine(arch: str, *, smoke: bool = False,
+                 ckpt_dir: Optional[str] = None, max_len: int = 256,
+                 batch: Optional[int] = 4, engine: str = "slot",
+                 target: Optional[str] = None, backend: Optional[str] = None,
+                 seed: int = 0):
+    """``(cfg, engine)`` for ``arch``: the smoke config, or the published
+    config with its weights built (or restored) in ``cfg.compute_dtype`` —
+    decode never needs f32 master weights, and a 3B model in f32 would not
+    fit beside its KV pool on one 16 GB chip. Weights are random from
+    ``seed`` unless ``ckpt_dir`` is given. ``target`` names a preset;
+    ``None`` takes the attached device's."""
+    from repro.configs import get_config, get_smoke
+    from repro.models import transformer as T
+    from repro.ops import ExecutionContext
+    from repro.plan import default_target, get_target
+    from repro.serving.engine import Engine, WaveEngine
+    from repro.train import checkpoint as ckpt
+
+    cfg = get_smoke(arch) if smoke else get_config(arch)
+    if not smoke:
+        cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+    assert cfg.causal, "encoder-only archs have no decode path"
+    params = jax.jit(T.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+    if ckpt_dir:
+        restored, _ = ckpt.restore(ckpt_dir, {"params": params})
+        params = restored["params"]
+    tgt = get_target(target) if target else default_target()
+    cls = WaveEngine if engine == "wave" else Engine
+    eng = cls(cfg, params, max_len=max_len, batch_size=batch or None,
+              target=tgt, ctx=ExecutionContext(target=tgt, backend=backend))
+    return cfg, eng
+
+
+def make_requests(cfg, n: int, prompt_lens: Tuple[int, int], max_new: int,
+                  temperature: float = 0.0, stop: Sequence[int] = (),
+                  deadline_s: Optional[float] = None, seed: int = 0):
+    """``n`` requests with seeded random prompts whose lengths are drawn
+    uniformly from ``prompt_lens`` (inclusive)."""
+    from repro.serving.engine import Request
+
+    rng = np.random.default_rng(seed)
+    lo, hi = prompt_lens
+    return [Request(prompt=rng.integers(0, cfg.vocab_size,
+                                        size=int(rng.integers(lo, hi + 1)),
+                                        dtype=np.int32),
+                    max_new_tokens=max_new, temperature=temperature,
+                    stop_tokens=tuple(stop), deadline_s=deadline_s)
+            for _ in range(n)]
+
+
+def summarize(reqs: List) -> Tuple[int, dict]:
+    """(generated tokens, finish-reason counts) — only tokens that were
+    really generated count; "error" and "timeout" requests still return
+    what they produced."""
+    total_new = sum(len(r.out_tokens) for r in reqs
+                    if r.out_tokens is not None)
+    return total_new, dict(collections.Counter(r.finish_reason
+                                               for r in reqs))
 
 
 def main():
@@ -39,10 +102,11 @@ def main():
                     help="token id that ends a request (EOS)")
     ap.add_argument("--engine", choices=("slot", "wave"), default="slot",
                     help="continuous batching (slot) or the wave baseline")
-    ap.add_argument("--target", default="cpu_interpret",
+    ap.add_argument("--target", default=None,
                     help="hardware target preset (tpu_v5e | gemmini | "
                          "cpu_interpret); sets plan/precision policy and "
-                         "the default backend")
+                         "the default backend. Default: the attached "
+                         "device's preset")
     ap.add_argument("--backend", choices=("xla", "pallas"), default=None,
                     help="kernel backend override; default resolves from "
                          "REPRO_BACKEND and then the --target preset")
@@ -52,51 +116,26 @@ def main():
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
 
-    from repro.configs import get_config, get_smoke
-    from repro.models import transformer as T
-    from repro.ops import ExecutionContext
-    from repro.plan import get_target
-    from repro.serving.engine import Engine, Request, WaveEngine
-    from repro.train import checkpoint as ckpt
+    from repro.launch.devices import use_compile_cache
 
-    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    assert cfg.causal, "encoder-only archs have no decode path"
-    key = jax.random.PRNGKey(0)
-    params = T.init_params(key, cfg)
-    if args.ckpt_dir:
-        tree = {"params": params}
-        restored, _ = ckpt.restore(args.ckpt_dir, tree)
-        params = restored["params"]
-
-    rng = np.random.default_rng(0)
+    use_compile_cache()
+    cfg, eng = build_engine(args.arch, smoke=args.smoke,
+                            ckpt_dir=args.ckpt_dir, max_len=args.max_len,
+                            batch=args.batch, engine=args.engine,
+                            target=args.target, backend=args.backend)
     stop = () if args.stop_token is None else (args.stop_token,)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size,
-                                        size=rng.integers(2, args.prompt_len + 1),
-                                        dtype=np.int32).astype(np.int32),
-                    max_new_tokens=args.max_new,
-                    temperature=args.temperature,
-                    stop_tokens=stop,
-                    deadline_s=args.deadline_s)
-            for _ in range(args.requests)]
-    cls = WaveEngine if args.engine == "wave" else Engine
-    target = get_target(args.target)
-    eng = cls(cfg, params, max_len=args.max_len,
-              batch_size=args.batch or None, target=target,
-              ctx=ExecutionContext(target=target, backend=args.backend))
+    reqs = make_requests(cfg, args.requests, (2, args.prompt_len),
+                         args.max_new, args.temperature, stop,
+                         args.deadline_s)
     t0 = time.time()
     eng.serve(reqs)
     dt = time.time() - t0
-    # a request may not complete: resilience failures ("error") and expired
-    # deadlines ("timeout") still return whatever tokens were generated, so
-    # count and report per-reason rather than assuming success
-    total_new = sum(len(r.out_tokens) for r in reqs
-                    if r.out_tokens is not None)  # real tokens only
-    reasons = collections.Counter(r.finish_reason for r in reqs)
+    total_new, reasons = summarize(reqs)
     ok = len(reqs) - reasons.get("error", 0) - reasons.get("timeout", 0)
     print(f"[{args.engine}] served {len(reqs)} requests "
           f"(batch={eng.batch_size}), {total_new} generated tokens in "
           f"{dt:.2f}s ({total_new / dt:.1f} tok/s); "
-          f"completed={ok}/{len(reqs)} finish={dict(reasons)}")
+          f"completed={ok}/{len(reqs)} finish={reasons}")
     for i, r in enumerate(reqs[:4]):
         out = [] if r.out_tokens is None else r.out_tokens[:12].tolist()
         print(f"  req{i}: prompt={r.prompt[:8].tolist()}... "

@@ -40,7 +40,8 @@ def main():
     ap.add_argument("--target", default=None,
                     help="hardware target preset (tpu_v5e | gemmini | "
                          "cpu_interpret); sets the plan/precision policy "
-                         "and the default backend")
+                         "and the default backend. Default: the attached "
+                         "device's preset")
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--remat", action="store_true")
     args = ap.parse_args()
@@ -50,10 +51,13 @@ def main():
 
     from repro.configs import get_config, get_smoke
     from repro.data.pipeline import DataConfig
+    from repro.launch.devices import use_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.ops import ExecutionContext, default_context
     from repro.train.optimizer import AdamWConfig
     from repro.train.trainer import TrainConfig, Trainer
 
+    use_compile_cache()
     backend = args.backend
     if args.target:
         from repro.plan import get_target
@@ -68,7 +72,7 @@ def main():
     mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
+        mesh = make_mesh(shape, ("data", "model")[:len(shape)])
 
     dcfg = DataConfig(batch_size=args.batch, seq_len=args.seq,
                       vocab_size=cfg.vocab_size, path=args.data)

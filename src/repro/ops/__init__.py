@@ -23,8 +23,8 @@ Backends are registered in ``repro.ops.registry`` (``xla``, ``pallas``, and
 the ``im2col`` conv baseline); each op entry declares capabilities (accepted
 dtypes, per-row ``q_offset``, key masks) and the dispatcher walks the
 fallback chain until one covers the call. ``ExecutionContext`` carries the
-HardwareTarget (precision policy + plan cache handle), an optional backend
-override, and the Pallas interpret flag — it supersedes the ``use_pallas``
+HardwareTarget (precision policy + plan cache handle) and an optional
+backend override — it supersedes the ``use_pallas``
 booleans that used to thread through the model stack (the last shim,
 ``kernels/ops.py``, is gone). Backend selection from the environment:
 ``REPRO_BACKEND=xla|pallas|im2col`` — the only environment knob (the PR-3

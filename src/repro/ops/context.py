@@ -9,11 +9,11 @@ three inputs of that decision:
 
   * ``target``  - the :class:`repro.plan.HardwareTarget` whose memory model the
                   blocking LP plans against and whose ``precision`` policy sets
-                  stream/accumulator dtypes;
+                  stream/accumulator dtypes (default: the attached device's,
+                  :func:`repro.plan.default_target`);
   * ``backend`` - an explicit backend override (``"xla"`` | ``"pallas"``).
                   ``None`` defers to the ``REPRO_BACKEND`` environment variable
                   and then to the target's own default;
-  * ``interpret`` - Pallas interpret-mode override (``None`` = the target's);
   * ``autotune`` - measured-autotune policy (``None``/``False`` = off,
                   ``True`` = default :class:`repro.plan.AutotunePolicy`, or a
                   policy instance). When set, plan resolution may run one
@@ -27,8 +27,10 @@ process-wide memoized cache in ``repro.plan.planner``, so every consumer of
 one context converges on identical ``ExecutionPlan`` objects.
 
 Backend resolution order: explicit ``ctx.backend`` > ``REPRO_BACKEND`` env var
-> the target default. (The PR-3 ``REPRO_USE_PALLAS`` env var is gone;
-``REPRO_BACKEND`` is the only environment knob.)
+> the target default. Whether a Pallas kernel runs interpreted is not part of
+the context: the kernels ask ``repro.plan.pallas_interpret`` (the CPU
+interprets, an accelerator compiles). ``REPRO_BACKEND`` is the only
+environment knob.
 """
 
 from __future__ import annotations
@@ -39,7 +41,7 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-from repro.plan import HardwareTarget, TPU_V5E
+from repro.plan import HardwareTarget, default_target
 
 BACKEND_ENV = "REPRO_BACKEND"
 
@@ -78,9 +80,8 @@ class ExecutionContext:
     engine's compiled-step cache, exactly as the old ``use_pallas`` bool did.
     """
 
-    target: HardwareTarget = TPU_V5E
+    target: HardwareTarget = dataclasses.field(default_factory=default_target)
     backend: Optional[str] = None  # "xla" | "pallas" | None (resolve)
-    interpret: Optional[bool] = None  # Pallas interpret override
     autotune: Any = None  # None/False | True | repro.plan.AutotunePolicy
 
     # -- backend resolution ---------------------------------------------------
@@ -133,7 +134,7 @@ class ExecutionContext:
 
 
 def default_context() -> ExecutionContext:
-    """The context used when a consumer passes ``ctx=None``: plans against
-    ``TPU_V5E`` (the pre-redesign kernel default) but executes on XLA unless
-    ``REPRO_BACKEND`` asks for Pallas."""
-    return ExecutionContext(target=TPU_V5E, backend=env_backend() or "xla")
+    """The context used when a consumer passes ``ctx=None``: the attached
+    device's target, whose default backend runs there (Pallas on the TPU,
+    XLA on the CPU) unless ``REPRO_BACKEND`` asks for another."""
+    return ExecutionContext(target=default_target())

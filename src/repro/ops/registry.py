@@ -425,8 +425,8 @@ register_backend(Backend(
 
 # ---------------------------------------------------------------------------
 # Pallas backend: LP-tiled kernels. Plans resolve through ctx.plan (the
-# process-wide cache); interpret mode comes from the target unless the
-# context overrides it.
+# process-wide cache); the kernels decide interpret mode from the platform
+# (repro.plan.pallas_interpret).
 #
 # Differentiability: pallas_call has no JVP rule for scratch-carrying
 # kernels, and the missing rule fires inside lax.scan/checkpoint jaxpr
@@ -456,24 +456,21 @@ def _with_xla_vjp(pallas_fn: Callable, xla_fn: Callable, *arrays):
 
 def _pallas_matmul(ctx, plan, a, b, out_dtype=jnp.float32):
     return _with_xla_vjp(
-        lambda a_, b_: _matmul_pallas(a_, b_, out_dtype=out_dtype, plan=plan,
-                                      interpret=ctx.interpret),
+        lambda a_, b_: _matmul_pallas(a_, b_, out_dtype=out_dtype, plan=plan),
         lambda a_, b_: ref.matmul_ref(a_, b_, out_dtype=out_dtype), a, b)
 
 
 def _pallas_conv2d(ctx, plan, x, w, stride=(1, 1), out_dtype=jnp.float32):
     return _with_xla_vjp(
         lambda x_, w_: _conv2d_pallas(x_, w_, stride=stride,
-                                      out_dtype=out_dtype, plan=plan,
-                                      interpret=ctx.interpret),
+                                      out_dtype=out_dtype, plan=plan),
         lambda x_, w_: ref.conv2d_ref(x_, w_, stride=stride,
                                       out_dtype=out_dtype), x, w)
 
 
 def _pallas_conv1d(ctx, plan, x, w):
     return _with_xla_vjp(
-        lambda x_, w_: _conv1d_pallas(x_, w_, target=ctx.target,
-                                      interpret=ctx.interpret),
+        lambda x_, w_: _conv1d_pallas(x_, w_, target=ctx.target),
         ref.conv1d_causal_ref, x, w)
 
 
@@ -501,12 +498,11 @@ def _pallas_attention(ctx, plan, q, k, v, causal=True, q_offset=0,
             if g == 1:
                 out = _flash_pallas(q.reshape(B * H, Lq, Dh), kf, vf,
                                     causal=causal, q_offset=q_offset,
-                                    target=ctx.target, interpret=ctx.interpret)
+                                    target=ctx.target)
                 return out.reshape(B, H, Lq, Dh)
             qf = q.reshape(B * Hkv, g * Lq, Dh)  # groups stacked on seq axis
             out = _flash_pallas(qf, kf, vf, causal=causal, q_offset=q_offset,
-                                q_seq_len=Lq, target=ctx.target,
-                                interpret=ctx.interpret)
+                                q_seq_len=Lq, target=ctx.target)
             return out.reshape(B, H, Lq, Dh)
 
         return _with_xla_vjp(
@@ -525,8 +521,7 @@ def _pallas_attention(ctx, plan, q, k, v, causal=True, q_offset=0,
         vf = v.reshape(B * Hkv, Lk, Dh)
         qf = q.reshape(B * Hkv, g * Lq, Dh)
         out = _flash_pallas(qf, kf, vf, causal=causal, q_offset=row_offs,
-                            q_seq_len=Lq, target=ctx.target,
-                            interpret=ctx.interpret)
+                            q_seq_len=Lq, target=ctx.target)
         return out.reshape(B, H, Lq, Dh)
 
     def xla_fn(q, k, v, row_offs):
@@ -542,8 +537,7 @@ def _pallas_attention_decode(ctx, plan, q, kp, vp, tables, lengths):
     their cotangents are float0)."""
     def fwd(q, kp, vp, tables, lengths):
         return paged_decode_attention(q, kp, vp, tables, lengths,
-                                      target=ctx.target,
-                                      interpret=ctx.interpret)
+                                      target=ctx.target)
 
     return _with_xla_vjp(fwd, xla_attention_decode, q, kp, vp,
                          tables, lengths)
@@ -555,12 +549,11 @@ def _pallas_conv2d_q(ctx, plan, x, w, scale, stride=(1, 1),
     int8 operands carry no meaningful cotangent, and QAT differentiates the
     fake-quantized f32 graph, never the int8 kernel itself."""
     return _conv2d_q_pallas(x, w, scale, stride=stride, out_dtype=out_dtype,
-                            plan=plan, interpret=ctx.interpret)
+                            plan=plan)
 
 
 def _pallas_matmul_q(ctx, plan, a, b, scale, out_dtype=jnp.bfloat16):
-    return _matmul_q_pallas(a, b, scale, out_dtype=out_dtype, plan=plan,
-                            interpret=ctx.interpret)
+    return _matmul_q_pallas(a, b, scale, out_dtype=out_dtype, plan=plan)
 
 
 def _pallas_conv2d_q_words(ctx, plan, x, w, scale=None, stride=(1, 1),
@@ -684,8 +677,8 @@ register_backend(Backend(
                           words_fn=_pallas_matmul_words,
                           access_plan_fn=_pallas_matmul_access),
         # runtime faults demote to the instrumented Im2Col baseline (not
-        # straight to uninstrumented xla) so the 3.9-7.2x words cost of
-        # degradation stays measured (PR 4's conv_bench gap)
+        # straight to uninstrumented xla) so the words cost of degradation
+        # stays measured (conv_bench's Im2Col-vs-tiled gap)
         "conv2d": OpEntry(_pallas_conv2d, spec_fn=_conv2d_plan_spec,
                           words_fn=_pallas_conv2d_words,
                           access_plan_fn=_pallas_conv2d_access,

@@ -61,5 +61,7 @@ from .target import (  # noqa: F401
     TARGETS,
     TPU_V5E,
     HardwareTarget,
+    default_target,
     get_target,
+    pallas_interpret,
 )

@@ -48,9 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.roofline import alpha_beta_seconds
-from repro.core.conv_model import ConvShape, ceil_div, round_up
-from repro.core.tiling import (conv_kernel_tiles_fit, fit_conv_kernel_tiles,
-                               snap_tile)
+from repro.core.conv_model import ConvShape, ceil_div
+from repro.core.tiling import conv_kernel_tiles_fit, fit_conv_kernel_tiles
 
 from . import planner as _planner
 from .ops import ConvSpec, MatmulSpec, OpSpec, as_op_spec, op_from_dict
@@ -319,6 +318,7 @@ def _conv_candidates(op: ConvSpec, target: HardwareTarget,
                      base: Tuple[int, ...]) -> List[Tuple[int, ...]]:
     shape = op.to_shape(target.precision)
     mem = target.memory_model()
+    align = target.align
     caps = (op.N, op.c_I, op.c_O, op.h_O, op.w_O)
     axes = [_axis_options(base[0], caps[0], False),
             _axis_options(base[1], caps[1], False),
@@ -332,8 +332,8 @@ def _conv_candidates(op: ConvSpec, target: HardwareTarget,
                 for bh in axes[3]:
                     for bw in axes[4]:
                         t = fit_conv_kernel_tiles(
-                            shape, (bN, b_cI, b_cO, bh, bw), mem)
-                        if conv_kernel_tiles_fit(shape, t, mem):
+                            shape, (bN, b_cI, b_cO, bh, bw), mem, align)
+                        if conv_kernel_tiles_fit(shape, t, mem, align):
                             seen.setdefault(tuple(t), None)
     return list(seen)
 
@@ -342,8 +342,7 @@ def _matmul_candidates(op: MatmulSpec, target: HardwareTarget,
                        base: Tuple[int, ...]) -> List[Tuple[int, ...]]:
     prec = op.prec or target.precision
     mem = target.memory_model()
-    al = (max(target.align_sublane, 1), max(target.align_lane, 1),
-          max(target.align_lane, 1))
+    al = _planner.matmul_aligns(target, prec)
     caps = (op.m, op.n, op.k)
 
     def fp(t):
@@ -355,12 +354,7 @@ def _matmul_candidates(op: MatmulSpec, target: HardwareTarget,
     for bm in axes[0]:
         for bn in axes[1]:
             for bk in axes[2]:
-                t = (min(snap_tile(bm, al[0], caps[0]),
-                         round_up(caps[0], al[0])),
-                     min(snap_tile(bn, al[1], caps[1]),
-                         round_up(caps[1], al[1])),
-                     min(snap_tile(bk, al[2], caps[2]),
-                         round_up(caps[2], al[2])))
+                t = _planner.snap_matmul_tiles((bm, bn, bk), caps, al)
                 t = _planner._fit_matmul_tiles(t, prec, mem, target)
                 if fp(t) <= mem.M_eff:
                     seen.setdefault(tuple(t), None)

@@ -32,12 +32,13 @@ from repro.core.conv_model import ConvShape, Precision, ceil_div, round_up
 from repro.core.parallel_tiling import optimize_parallel_blocking
 from repro.core.sharding_opt import ShardingPlan, plan_conv_sharding
 from repro.core.tiling import (Blocking, attention_block_size,
-                               conv_kernel_footprints, fit_conv_kernel_tiles,
-                               matmul_blocking, optimize_blocking, snap_tile)
+                               conv_kernel_footprints, conv_kernel_geometry,
+                               fit_conv_kernel_tiles, layout_align,
+                               matmul_blocking, optimize_blocking)
 
 from .ops import (AttentionSpec, ConvSpec, MatmulSpec, OpSpec, as_op_spec,
                   op_from_dict)
-from .target import HardwareTarget, TPU_V5E
+from .target import HardwareTarget, TPU_V5E, default_target
 
 # v2: conv tiles/grid widened from (bN, b_cI, b_cO) / 3-axis grids to the
 # spatial-blocked (bN, b_cI, b_cO, b_hO, b_wO) / 5-axis form. v1 conv dumps
@@ -208,12 +209,13 @@ class ExecutionPlan:
 
     def kernel_footprints(self) -> Dict[str, float]:
         """Words the lowered conv2d kernel actually allocates per tile: the
-        exact halo window ((b_hO - 1) * sh + h_F) x ((b_wO - 1) * sw + w_F)
-        for the input and the full unrolled (h_F, w_F) filter block — the
-        view ``fit_conv_kernel_tiles`` clamped the tiles against."""
+        halo window of the space-to-depth input and the full unrolled filter
+        block, lane/sublane padding included — the view
+        ``fit_conv_kernel_tiles`` clamped the tiles against."""
         if not isinstance(self.op, ConvSpec):
             raise TypeError("kernel_footprints() on a non-conv plan")
-        return conv_kernel_footprints(self.to_shape(), self.tiles)
+        return conv_kernel_footprints(self.to_shape(), self.tiles,
+                                      align=self.target.align)
 
     def pallas_specs(self):
         """(grid, in_specs, out_specs) mirroring what the kernels lower.
@@ -225,21 +227,20 @@ class ExecutionPlan:
         express), so the in_specs carry only the memory space; the output
         spec is blocked as before."""
         from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
 
         if isinstance(self.op, AttentionSpec):
             raise TypeError("pallas_specs() on an attention plan: the flash "
                             "kernels own their BlockSpecs (tiles = (bq, bk))")
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
         if isinstance(self.op, MatmulSpec):
             bm, bn, bk = self.tiles
             return (self.grid, in_specs,
                     pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
         bN, b_cI, b_cO, b_hO, b_wO = self.tiles
         return (self.grid, in_specs,
-                pl.BlockSpec((bN, b_cO, b_hO, b_wO),
-                             lambda n, co, h, w, ci: (n, co, h, w)))
+                pl.BlockSpec((b_hO, b_wO, bN, b_cO),
+                             lambda n, co, h, w, ci: (h, w, n, co)))
 
     # -- (de)serialization ----------------------------------------------------
     def to_dict(self) -> Dict[str, Any]:
@@ -431,15 +432,14 @@ def _plan_conv(op: ConvSpec, target: HardwareTarget) -> ExecutionPlan:
     blk = optimize_blocking(shape, mem, align=_conv_align(shape, target))
     t = blk.as_conv_tile()
     # Kernel tiles carry the LP's spatial choice: the kernel blocks h_O/w_O
-    # with overlapping input halos of (b - 1) * s + f rows/cols. The lifted
-    # LP footprint can undercount the kernel's (it may block filter taps the
-    # kernel unrolls in full), so clamp against the exact halo-window model.
+    # with overlapping input halos. The lifted LP footprint can undercount
+    # the kernel's (it may block filter taps the kernel unrolls in full, and
+    # it prices no lane/sublane padding), so snap the tiles to the kernel's
+    # layout and clamp them against its exact halo-window model.
     tiles = fit_conv_kernel_tiles(shape, (
-        max(1, min(op.N, t["N"])), t["cI"], t["cO"],
-        max(1, min(op.h_O, t["hO"])), max(1, min(op.w_O, t["wO"]))), mem)
-    grid = (ceil_div(op.N, tiles[0]), ceil_div(op.c_O, tiles[2]),
-            ceil_div(op.h_O, tiles[3]), ceil_div(op.w_O, tiles[4]),
-            ceil_div(op.c_I, tiles[1]))
+        t["N"], t["cI"], t["cO"], t["hO"], t["wO"]), mem,
+        align=target.align)
+    grid = conv_kernel_geometry(shape, tiles, target.align).grid
     vol = blk.comm_volume()
     lb = single_processor_bound(shape, mem.M_eff).value
     sharding = None
@@ -470,6 +470,28 @@ def _parallel_section(shape: ConvShape, P: int, M_eff: float
         lower_bound=combined_parallel_bound(shape, P, M_eff))
 
 
+def matmul_aligns(target: HardwareTarget, prec) -> Tuple[int, int, int]:
+    """(bm, bn, bk) multiples: m rides the sublanes of A (packed dtypes
+    stack more rows per sublane), n and k ride lanes."""
+    sub, lane = layout_align(target.align, prec.p_I)
+    return sub, lane, lane
+
+
+def snap_matmul_tiles(tiles: Tuple[int, int, int], dims: Tuple[int, int, int],
+                      aligns: Tuple[int, int, int]) -> Tuple[int, int, int]:
+    """Round each tile down to its alignment multiple (at least one) and cap
+    it at the dim rounded up to that multiple. A dim below one aligned tile
+    gets a whole aligned tile: the kernel zero-pads it, because Mosaic
+    slices HBM only in whole (sublane, lane) tiles."""
+    out = []
+    for v, dim, al in zip(tiles, dims, aligns):
+        if al <= 1:
+            out.append(max(1, min(v, dim)))
+        else:
+            out.append(min(max(al, (v // al) * al), round_up(dim, al)))
+    return out[0], out[1], out[2]
+
+
 def _fit_matmul_tiles(tiles: Tuple[int, int, int], prec, mem,
                       target: HardwareTarget) -> Tuple[int, int, int]:
     """Shrink snapped (bm, bn, bk) until the GEMM tile footprint
@@ -477,8 +499,7 @@ def _fit_matmul_tiles(tiles: Tuple[int, int, int], prec, mem,
     ``mem.M_eff`` (the constraint ``optimize_blocking`` solved under, which
     alignment snapping can violate). Alignment floors are respected."""
     bm, bn, bk = tiles
-    aligns = (max(target.align_sublane, 1), max(target.align_lane, 1),
-              max(target.align_lane, 1))
+    aligns = matmul_aligns(target, prec)
 
     def fp(t):
         return (t[0] * t[2] * prec.p_I + t[2] * t[1] * prec.p_F
@@ -513,14 +534,9 @@ def _plan_matmul(op: MatmulSpec, target: HardwareTarget) -> ExecutionPlan:
                           align_m=target.align_sublane,
                           align_n=target.align_lane,
                           align_k=target.align_lane)
-    bm, bk, bn = blk.b["N"], blk.b["cI"], blk.b["cO"]
-    bm = snap_tile(bm, target.align_sublane, op.m)
-    bn = snap_tile(bn, target.align_lane, op.n)
-    bk = snap_tile(bk, target.align_lane, op.k)
-    # clamp so the BlockSpecs divide the padded problem evenly
-    bm = min(bm, round_up(op.m, max(target.align_sublane, 1)))
-    bn = min(bn, round_up(op.n, max(target.align_lane, 1)))
-    bk = min(bk, round_up(op.k, max(target.align_lane, 1)))
+    bm, bn, bk = snap_matmul_tiles(
+        (blk.b["N"], blk.b["cO"], blk.b["cI"]), (op.m, op.n, op.k),
+        matmul_aligns(target, prec))
     # MXU alignment can inflate a tile past the LP's feasible point (e.g. the
     # lane snap turns b_k = 1 into 128 on tall-skinny im2col GEMMs), silently
     # breaking the double-buffered capacity discipline the kernel allocates
@@ -583,7 +599,7 @@ def warn_legacy_kernel_kwargs(fn: str, **passed) -> None:
     if names:
         warnings.warn(
             f"legacy kernel kwargs {names} on {fn}(): pass "
-            "ctx=ExecutionContext(target=..., interpret=...) instead",
+            "ctx=ExecutionContext(target=...) instead",
             DeprecationWarning, stacklevel=3)
 
 
@@ -592,25 +608,24 @@ def resolve_kernel_plan(
     plan: Optional[ExecutionPlan] = None,
     target: Optional[HardwareTarget] = None,
     tiles: Optional[Tuple[int, ...]] = None,
-    interpret: Optional[bool] = None,
     ctx: Optional[Any] = None,
-) -> Tuple[Tuple[int, ...], bool]:
-    """Shared kernel-side resolution of (tiles, interpret).
+) -> Tuple[Tuple[int, ...], HardwareTarget]:
+    """Shared kernel-side resolution of (tiles, target).
 
     ``op`` is the spec the kernel built from its actual arrays (precision
     included). Priority: explicit legacy ``tiles``, then a caller-supplied
     ``plan`` (validated for geometry and precision), then a fresh plan via
     :func:`resolve_plan` — for ``ctx.target`` (autotune-aware, any object
-    with ``target``/``interpret``/``autotune`` attributes; duck-typed so the
-    kernel layer needs no ``repro.ops`` import) or legacy ``target``. One
-    implementation so conv2d/matmul/... cannot diverge."""
-    if ctx is not None:
-        if target is None:
-            target = ctx.target
-        if interpret is None:
-            interpret = getattr(ctx, "interpret", None)
+    with ``target``/``autotune`` attributes; duck-typed so the kernel layer
+    needs no ``repro.ops`` import), legacy ``target``, or the attached
+    device's :func:`default_target`. The returned target is the one the
+    tiles were planned for: it sets the kernel's layout alignment and its
+    compiler VMEM limit. One implementation so conv2d/matmul/... cannot
+    diverge."""
+    if ctx is not None and target is None:
+        target = ctx.target
     if tiles is None and plan is None:
-        plan, _ = resolve_plan(op, target or TPU_V5E,
+        plan, _ = resolve_plan(op, target or default_target(),
                                autotune=getattr(ctx, "autotune", None))
     if plan is not None:
         if not isinstance(plan.op, type(op)) or (
@@ -623,12 +638,10 @@ def resolve_kernel_plan(
                 f"plan assumed {plan.precision.p_I}-word input streams but "
                 f"the data is {data_p} words: its tiles would overflow the "
                 "modeled fast-memory budget")
-    if interpret is None:
-        if plan is not None:
-            interpret = plan.target.interpret
-        else:
-            interpret = target.interpret if target is not None else True
-    return (tiles if tiles is not None else plan.tiles), interpret
+        if tiles is None:
+            return plan.tiles, plan.target
+        target = target or plan.target
+    return tiles, target or default_target()
 
 
 _PLAN_AUDIT_HOOKS: List[Callable[[ExecutionPlan], None]] = []

@@ -10,17 +10,23 @@ be scattered across ``kernels/*`` and ``benchmarks/*``.
 
 Presets:
   * ``TPU_V5E``      - 16 MiB unified VMEM, bf16 streams / f32 accumulate,
-                       MXU (8, 128) alignment. ``interpret=True`` because this
-                       container has no TPU; flip on real hardware.
+                       MXU (8, 128) alignment.
   * ``GEMMINI``      - the paper's §5 accelerator: 256 KiB scratchpad (int8)
                        + 64 KiB accumulator (f32), split-buffer mode.
-  * ``CPU_INTERPRET``- correctness target: Pallas interpret mode, f32.
+  * ``CPU_INTERPRET``- the CPU: Pallas runs in interpret mode, f32, and no
+                       tile alignment (the interpreter has no (8, 128) tiles).
+
+Where Pallas runs is not a property of a target: :func:`pallas_interpret`
+decides it from the backend the kernels execute on, and
+:func:`default_target` maps the attached device to its preset.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Dict, Optional, Sequence, Tuple
+
+import jax
 
 from repro.core.conv_model import BF16_ACC32, FP32, INT8_ACC32, Precision
 from repro.core.tiling import MemoryModel, TPU_VMEM_WORDS
@@ -45,7 +51,6 @@ class HardwareTarget:
     memory: str = "unified"  # "unified" | "split" (paper eq. 6 vs §5)
     double_buffer: bool = True  # §5: halves usable capacity
     precision: Precision = BF16_ACC32  # default when the OpSpec has none
-    interpret: bool = True  # Pallas interpret default for kernels
     use_pallas: bool = False  # whether consumers should take the Pallas path
     mesh_axes: MeshAxes = ()  # ((name, size), ...) for multi-device targets
     align_sublane: int = 8  # MXU sublane multiple (1 = no alignment)
@@ -57,6 +62,22 @@ class HardwareTarget:
     # ``precision`` above stays the full-precision default for ops that
     # don't quantize.
     quant: Optional[PrecisionSpec] = None
+
+    @property
+    def align(self) -> Tuple[int, int]:
+        """The ``(sublane, lane)`` alignment this target's kernels lay out
+        under."""
+        return (self.align_sublane, self.align_lane)
+
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """The scoped-VMEM limit every Pallas kernel is compiled with. The LP
+        fits one copy of each tile into half of ``vmem_words`` (§5 double
+        buffering); the kernels hold two copies of their streamed tiles plus
+        Pallas' own output buffers and Mosaic's matmul scratch, none of which
+        the LP prices. So the compiler is granted twice the planned capacity
+        (32 MiB for ``TPU_V5E``, of the v5e core's 128 MiB of VMEM)."""
+        return int(2 * self.vmem_words * 4)
 
     def memory_model(self) -> MemoryModel:
         """The capacity model the blocking LP consumes."""
@@ -102,7 +123,6 @@ class HardwareTarget:
             "memory": self.memory,
             "double_buffer": self.double_buffer,
             "precision": list(self.precision.as_tuple()),
-            "interpret": self.interpret,
             "use_pallas": self.use_pallas,
             "mesh_axes": [list(ax) for ax in self.mesh_axes],
             "align_sublane": self.align_sublane,
@@ -112,6 +132,8 @@ class HardwareTarget:
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "HardwareTarget":
+        # plan files written before the platform rule carry an "interpret"
+        # key; where Pallas runs is no longer a property of the target
         return cls(
             name=d["name"],
             vmem_words=float(d["vmem_words"]),
@@ -120,7 +142,6 @@ class HardwareTarget:
             memory=d.get("memory", "unified"),
             double_buffer=bool(d.get("double_buffer", True)),
             precision=Precision(*d.get("precision", (0.5, 0.5, 1.0))),
-            interpret=bool(d.get("interpret", True)),
             use_pallas=bool(d.get("use_pallas", False)),
             mesh_axes=tuple((str(n), int(s)) for n, s in d.get("mesh_axes", ())),
             align_sublane=int(d.get("align_sublane", 8)),
@@ -140,7 +161,6 @@ TPU_V5E = HardwareTarget(
     hbm_words=16 * 2**30 / 4.0,  # 16 GiB HBM per v5e chip
     memory="unified",
     precision=BF16_ACC32,
-    interpret=True,  # no TPU in this container; set False on real hardware
     use_pallas=True,
 )
 
@@ -155,7 +175,6 @@ GEMMINI = HardwareTarget(
 
     memory="split",
     precision=INT8_ACC32,
-    interpret=True,
     use_pallas=False,
     align_sublane=1,
     align_lane=1,
@@ -166,13 +185,40 @@ CPU_INTERPRET = HardwareTarget(
     vmem_words=float(TPU_VMEM_WORDS),
     memory="unified",
     precision=FP32,
-    interpret=True,
     use_pallas=False,
+    align_sublane=1,
+    align_lane=1,
 )
 
 TARGETS: Dict[str, HardwareTarget] = {
     t.name: t for t in (TPU_V5E, GEMMINI, CPU_INTERPRET)
 }
+
+
+# ``jax.Device.device_kind`` -> the preset that describes that device.
+TARGET_BY_DEVICE_KIND: Dict[str, HardwareTarget] = {"TPU v5 lite": TPU_V5E}
+
+
+def default_target() -> HardwareTarget:
+    """The preset of the device JAX runs on: ``CPU_INTERPRET`` on the CPU,
+    the ``TARGET_BY_DEVICE_KIND`` entry on an accelerator. An accelerator
+    this table does not describe is an error, never a silent default."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_INTERPRET
+    try:
+        return TARGET_BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware target describes device kind {dev.device_kind!r} "
+            f"({dev.platform}); known: {sorted(TARGET_BY_DEVICE_KIND)}")
+
+
+def pallas_interpret() -> bool:
+    """The platform rule: Pallas kernels run in interpret mode exactly when
+    they execute on the CPU backend, and compile through Mosaic otherwise.
+    Every kernel asks this one function."""
+    return jax.default_backend() == "cpu"
 
 
 def get_target(name: str) -> HardwareTarget:

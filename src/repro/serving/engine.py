@@ -68,7 +68,7 @@ import numpy as np
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.ops import ExecutionContext
-from repro.plan import CPU_INTERPRET, HardwareTarget
+from repro.plan import HardwareTarget, default_target
 from repro.resilience import errors as flt
 from repro.resilience import faults as fj
 
@@ -304,7 +304,7 @@ class Engine:
         self.retry_backoff_s = retry_backoff_s
         self.cfg, self.params = cfg, params
         self.max_len = max_len
-        self.target = target or CPU_INTERPRET
+        self.target = target or default_target()
         if ctx is None:
             ctx = ExecutionContext(target=self.target)
         self.ctx = ctx.resolved()

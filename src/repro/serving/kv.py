@@ -151,7 +151,13 @@ class BlockAllocator:
             raise ValueError(f"cannot register non-live block {bid}")
         other = self._block_of.get(key)
         if other is not None and other != bid:
-            raise ValueError(f"key already registered to block {other}")
+            if self._rc.get(other, 0) > 0:
+                raise ValueError(f"key already registered to live block "
+                                 f"{other}")
+            # left evictable when an earlier block of its chain was evicted,
+            # so no lookup can reach it: the new block supersedes it
+            del self._evictable[other], self._key_of[other]
+            self._free.append(other)
         prev = self._key_of.get(bid)
         if prev is not None and prev != key:
             del self._block_of[prev]

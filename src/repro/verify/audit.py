@@ -213,7 +213,7 @@ def audit_decision(ap: KernelAccessPlan, decision, target=None
     plan = decision.plan
     if plan is not None and isinstance(plan.op, ConvSpec) and tgt is not None:
         if not conv_kernel_tiles_fit(plan.to_shape(), plan.tiles,
-                                     tgt.memory_model()):
+                                     tgt.memory_model(), tgt.align):
             report.problems.append(
                 f"{ap.op}: plan tiles {plan.tiles} overflow the "
                 "kernel_footprints budget (conv_kernel_tiles_fit)")
@@ -258,7 +258,8 @@ def validate_execution_plan(ep) -> List[str]:
             cover("wO", grid[3], tiles[4], op.w_O)
             cover("cI", grid[4], tiles[1], op.c_I)
             if not conv_kernel_tiles_fit(ep.to_shape(), tiles,
-                                         ep.target.memory_model()):
+                                         ep.target.memory_model(),
+                                         ep.target.align):
                 problems.append(f"conv tiles {tiles} overflow the exact "
                                 "halo-window VMEM budget")
     elif isinstance(op, MatmulSpec):

@@ -386,6 +386,7 @@ def test_dist_conv_subprocess_smoke():
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; never the accelerator
     env.pop("XLA_FLAGS", None)  # prove fake_devices sets it, not the env
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=560, env=env)

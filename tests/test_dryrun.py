@@ -14,7 +14,7 @@ import textwrap
 
 import pytest
 
-from repro.analysis.roofline import (ICI_BW, PEAK_FLOPS, Roofline,
+from repro.analysis.roofline import (PEAKS, V5E_KIND, Roofline,
                                      collective_bytes, model_flops)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -48,10 +48,11 @@ def test_collective_parser_skips_async_done():
 
 
 def test_roofline_terms_and_dominance():
+    peak = PEAKS[V5E_KIND]
     r = Roofline(arch="a", shape="train_4k", mesh="16x16", chips=256,
-                 hlo_flops=256 * PEAK_FLOPS,  # exactly 1s of compute
-                 hlo_bytes=0.0, wire_bytes_per_chip=ICI_BW / 2,
-                 collectives={}, model_flops=0.5 * 256 * PEAK_FLOPS,
+                 hlo_flops=256 * peak.flops_bf16,  # exactly 1s of compute
+                 hlo_bytes=0.0, wire_bytes_per_chip=peak.ici_bw / 2,
+                 collectives={}, model_flops=0.5 * 256 * peak.flops_bf16,
                  bytes_per_device={})
     assert r.compute_s == pytest.approx(1.0)
     assert r.collective_s == pytest.approx(0.5)
@@ -93,9 +94,9 @@ def test_small_mesh_dryrun_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
         os.environ["REPRO_UNROLL_SCANS"] = "0"  # rolled: fast compile
-        import jax
         from repro.launch.dryrun import run_cell
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(data=4, model=4)
         r1 = run_cell("internvl2_1b", "train_4k", mesh=mesh, save=False)
         assert r1["status"] == "ok", r1
         assert r1["hlo_flops"] > 0
@@ -106,6 +107,7 @@ def test_small_mesh_dryrun_subprocess():
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; never the accelerator
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=560, env=env)
     assert "SUBPROCESS_OK" in out.stdout, out.stderr[-2000:]

@@ -69,7 +69,8 @@ def test_conv2d_sweep(case, dtype):
 
 
 def test_conv2d_tiles_from_lp_fit_vmem():
-    """The kernel tiles (halo windows included) must fit inside half-VMEM."""
+    """The kernel tiles (halo windows and lane/sublane padding included)
+    must fit inside half-VMEM."""
     from repro.core.tiling import TPU_VMEM_WORDS
     N, cI, cO, hO, wO, hF, wF = 64, 64, 256, 56, 56, 3, 3
     spec = ConvSpec(N=N, c_I=cI, c_O=cO, w_O=wO, h_O=hO, w_F=wF, h_F=hF,
@@ -77,9 +78,12 @@ def test_conv2d_tiles_from_lp_fit_vmem():
     ep = Planner(TPU_V5E).plan(spec)
     bN, bcI, bcO, bh, bw = ep.conv_tiles()
     assert all(b >= 1 for b in ep.conv_tiles())
+    # channels on 128 lanes, the bf16 batch in 16-row sublane tiles
+    assert bcO % 128 == 0 and bN % 16 == 0
+    lanes = -(-bcI // 128) * 128
     fp = ep.kernel_footprints()
-    words = (0.5 * bN * bcI * ((bh - 1) + hF) * ((bw - 1) + wF)
-             + 0.5 * bcO * bcI * hF * wF + 1.0 * bN * bcO * bh * bw)
+    words = (0.5 * bN * (bh + hF - 1) * (bw + wF - 1) * lanes
+             + 0.5 * hF * wF * lanes * bcO + 1.0 * bN * bh * bw * bcO)
     assert words == pytest.approx(sum(fp.values()))
     assert words <= TPU_VMEM_WORDS / 2 * 1.01
 

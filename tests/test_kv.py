@@ -133,6 +133,31 @@ def test_block_words_and_plan_pool_blocks():
     assert kv.plan_pool_blocks(cfg, 64, 4, target=big) == 1 + 4 * 4
 
 
+def test_register_supersedes_a_chain_tail_left_by_eviction():
+    """Evicting the head of a cached chain leaves its tail registered but
+    unreachable; the next request for the same prefix re-registers the
+    tail key on a new block instead of failing admission."""
+    a = kv.BlockAllocator(5)
+    chain = kv.prefix_chain([1, 0, 0, 1], 2)
+    b1, b2 = a.alloc(), a.alloc()
+    a.register(b1, chain[0])
+    a.register(b2, chain[1])
+    a.free(b1)
+    a.free(b2)  # both evictable, b1 the oldest
+    held = [a.alloc(), a.alloc(), a.alloc()]  # the third evicts b1
+    assert a.lookup(chain[0]) is None and a.lookup(chain[1]) == b2
+    for b in held:
+        a.free(b)
+    n1 = a.alloc()
+    a.register(n1, chain[0])
+    n2 = a.alloc()
+    a.register(n2, chain[1])
+    assert a.lookup(chain[1]) == n2 and a.refcount(b2) == 0
+    a.check()
+    with pytest.raises(ValueError, match="live block"):
+        a.register(a.alloc(), chain[1])
+
+
 def test_randomized_schedules_no_leak_no_double_free():
     hyp = pytest.importorskip("hypothesis")
     st = pytest.importorskip("hypothesis.strategies")

@@ -13,6 +13,7 @@ from repro.core.conv_model import INT8_ACC32, Precision, resnet50_layers
 from repro.kernels.conv2d import conv2d
 from repro.kernels.matmul import matmul
 from repro.kernels.ref import conv2d_ref, matmul_ref
+from repro.ops import ExecutionContext
 from repro.plan import (CPU_INTERPRET, GEMMINI, PLAN_FORMAT_VERSION, TPU_V5E,
                         AttentionSpec, ConvSpec, ExecutionPlan, HardwareTarget,
                         MatmulSpec, Planner, TunedSection, get_target,
@@ -200,9 +201,11 @@ def test_conv2d_plan_matches_legacy_tiles():
                     prec=Precision(1.0, 1.0, 1.0))
     ep = _plan(spec, TPU_V5E)
     got_plan = conv2d(x, w, plan=ep)  # explicit plan handoff: no warning
+    # the kernel lays its tiles out for the target they were planned for
     with pytest.deprecated_call(match="legacy kernel kwargs"):
-        got_tiles = conv2d(x, w, tiles=ep.conv_tiles())
-    got_default = conv2d(x, w)  # plans internally through the same cache
+        got_tiles = conv2d(x, w, tiles=ep.conv_tiles(), target=TPU_V5E)
+    # plans internally through the same cache
+    got_default = conv2d(x, w, ctx=ExecutionContext(target=TPU_V5E))
     np.testing.assert_array_equal(np.asarray(got_plan), np.asarray(got_tiles))
     np.testing.assert_array_equal(np.asarray(got_plan), np.asarray(got_default))
     np.testing.assert_allclose(np.asarray(got_plan),
@@ -290,8 +293,75 @@ def test_hardware_target_from_dict_roundtrip():
     assert t == GEMMINI
 
 
+def test_from_dict_ignores_interpret_key_of_old_plan_files():
+    old = dict(TPU_V5E.to_dict(), interpret=True)
+    assert HardwareTarget.from_dict(old) == TPU_V5E
+
+
+# ---------------------------------------------------------------------------
+# the platform rule: target, backend and interpret mode follow the device
+# ---------------------------------------------------------------------------
+
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", CPU_INTERPRET),
+    ("tpu", "TPU v5 lite", TPU_V5E),
+], ids=["cpu", "v5e"])
+def test_default_target_follows_the_device(monkeypatch, platform, kind, want):
+    from repro.plan import default_target
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Device(platform, kind)])
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    assert default_target() is want
+    assert ExecutionContext().resolved_backend() == (
+        "pallas" if platform == "tpu" else "xla")
+
+
+def test_unknown_accelerator_is_an_error(monkeypatch):
+    from repro.analysis.roofline import chip_peaks
+    from repro.plan import default_target
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Device("tpu", "TPU v9")])
+    with pytest.raises(ValueError, match="TPU v9"):
+        default_target()
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_peaks("TPU v9")
+
+
+def test_pallas_interprets_exactly_on_the_cpu(monkeypatch):
+    from repro.plan import pallas_interpret
+
+    assert pallas_interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The env var is left to JAX; otherwise the cache sits at the fixed
+    <checkout>/.jax_cache."""
+    import pathlib
+
+    from repro.launch import devices
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *kv: updates.append(kv))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert devices.use_compile_cache() == str(tmp_path)
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    want = str(checkout / ".jax_cache")
+    assert devices.use_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]
+
+
 def test_plan_pallas_specs_shapes():
-    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental import pallas as pl
 
     ep = _plan(GEMM, TPU_V5E)
     grid, in_specs, out_spec = ep.pallas_specs()
@@ -299,10 +369,10 @@ def test_plan_pallas_specs_shapes():
     bm, bn, bk = ep.tiles
     # inputs stay in ANY/HBM (the kernels stream double-buffered DMA windows
     # themselves); only the output block is lowered via a blocked BlockSpec
-    assert all(s.memory_space == pltpu.ANY for s in in_specs)
+    assert all(s.memory_space == pl.ANY for s in in_specs)
     assert out_spec.block_shape == (bm, bn)
     cep = _plan(CONV, TPU_V5E)
     cgrid, _, cout = cep.pallas_specs()
     assert cgrid == cep.grid and len(cgrid) == 5
     bN, bcI, bcO, bh, bw = cep.conv_tiles()
-    assert cout.block_shape == (bN, bcO, bh, bw)
+    assert cout.block_shape == (bh, bw, bN, bcO)  # (H, W, N, C) output

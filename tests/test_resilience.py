@@ -252,6 +252,29 @@ def test_device_lost_is_fatal_and_propagates():
     assert not ops.quarantined()  # fatal faults never demote
 
 
+@pytest.mark.parametrize("error", [
+    jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
+                               "memory space vmem"),
+    ValueError("Mosaic failed to compile the kernel"),
+], ids=["runtime", "compile"])
+def test_real_kernel_errors_propagate_undegraded(monkeypatch, error):
+    """Only the TransientFault taxonomy degrades: a real compile or runtime
+    error from a kernel surfaces unchanged and quarantines nothing, so a
+    pallas kernel that fails on the chip can never be served by im2col or
+    xla in silence."""
+    from repro.ops import registry
+
+    def fails(*args, **kwargs):
+        raise error
+
+    monkeypatch.setattr(registry, "_conv2d_pallas", fails)
+    x, w = _conv_args()
+    with ops.record_dispatch() as log, pytest.raises(type(error)):
+        ops.conv2d(x, w, ctx=PALLAS)
+    assert not ops.quarantined()
+    assert log == []  # no decision claims the call was served
+
+
 def test_numeric_fault_corrupts_then_degrades():
     x, w = _conv_args()
     want = np.asarray(ops.conv2d(x, w, ctx=IM2COL))
