@@ -234,6 +234,7 @@ def conv2d(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=tgt.vmem_limit_bytes),
         interpret=pallas_interpret(),
+        name="conv2d_lp",
     )(_input_layout(x, shape, g), _filter_layout(w, shape, g))
     out = out[:shape.h_O, :shape.w_O, :shape.N, :shape.c_O]
     return out.transpose(2, 3, 0, 1)

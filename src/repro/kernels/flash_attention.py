@@ -226,6 +226,7 @@ def flash_attention(
             out_shape=jax.ShapeDtypeStruct((BH, Lqp, Dh), q.dtype),
             compiler_params=params,
             interpret=pallas_interpret(),
+            name="flash_attention",
         )(offs, lens, q, k, v)
         return out[:, :Lq]
 
@@ -247,6 +248,7 @@ def flash_attention(
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=pallas_interpret(),
+        name="flash_attention",
     )(q, k, v)
     return out[:, :Lq]
 
@@ -350,6 +352,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
         compiler_params=params,
         interpret=pallas_interpret(),
+        name="paged_decode_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, kp, vp)
     return out.reshape(B, H, 1, hd)
 

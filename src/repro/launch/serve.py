@@ -86,6 +86,22 @@ def summarize(reqs: List) -> Tuple[int, dict]:
                                                for r in reqs))
 
 
+def latency_summary(reqs: List) -> dict:
+    """p50 and p95 of the time to first token and of the time per output
+    token after it, ``(t_done - t_first) / (tokens - 1)``, in ms over the
+    served requests (requests with fewer than two tokens have no
+    inter-token time)."""
+    ttft = [r.t_first for r in reqs if r.t_first is not None]
+    itl = [(r.t_done - r.t_first) / (len(r.out_tokens) - 1) for r in reqs
+           if r.t_first is not None and len(r.out_tokens) > 1]
+    out = {}
+    for name, xs in (("ttft", ttft), ("itl", itl)):
+        if xs:
+            out[f"{name}_p50_ms"], out[f"{name}_p95_ms"] = (
+                1e3 * float(q) for q in np.percentile(xs, [50, 95]))
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -136,6 +152,10 @@ def main():
           f"(batch={eng.batch_size}), {total_new} generated tokens in "
           f"{dt:.2f}s ({total_new / dt:.1f} tok/s); "
           f"completed={ok}/{len(reqs)} finish={reasons}")
+    stats = dataclasses.asdict(eng.stats)
+    stats["failures"] = dict(stats["failures"])
+    print(f"engine stats: {stats}")
+    print(f"latency: {latency_summary(reqs)}")
     for i, r in enumerate(reqs[:4]):
         out = [] if r.out_tokens is None else r.out_tokens[:12].tolist()
         print(f"  req{i}: prompt={r.prompt[:8].tolist()}... "

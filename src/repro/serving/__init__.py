@@ -1,6 +1,7 @@
 from .engine import (  # noqa: F401
     Engine,
     Request,
+    ServeStats,
     WaveEngine,
     plan_batch_size,
 )

@@ -51,6 +51,17 @@ position-local, so re-prefilling reproduces the incrementally-written
 cache). Per-request ``deadline_s`` adds ``finish_reason="timeout"``. The
 active :func:`repro.resilience.faults.active_campaign` is consulted at the
 ``admit/*``, ``decode/*`` and ``finish/*`` sites.
+
+Observability: ``serve`` opens profiler spans
+(``jax.profiler.TraceAnnotation``, about a microsecond each while no trace
+runs), so that a device trace says which host step the device waits on:
+``serve/admit`` (one per request, ``rid=`` its index) holding
+``serve/prefill``, ``serve/insert`` and ``serve/first_token``;
+``serve/step`` (one per decode round) holding ``serve/decode``,
+``serve/sample``, ``serve/read`` and ``serve/record``; ``serve/pool_check``
+and ``serve/rebuild``. ``Engine.stats`` (:class:`ServeStats`) counts the
+work of the last ``serve`` call, and each request carries ``t_admit``,
+``t_first`` and ``t_done``.
 """
 
 from __future__ import annotations
@@ -101,6 +112,10 @@ class Request:
 
     ``deadline_s``: optional wall-clock budget, measured from admission;
     an expired request keeps the tokens generated so far.
+
+    ``t_admit``, ``t_first``, ``t_done``: seconds from the start of the
+    ``serve`` call to the request's admission, its first token and its
+    finish (``t_first`` stays None if no token was generated).
     """
 
     prompt: np.ndarray  # (len,) int32
@@ -111,6 +126,43 @@ class Request:
     deadline_s: Optional[float] = None
     out_tokens: Optional[np.ndarray] = None
     finish_reason: Optional[str] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
+
+
+@dataclasses.dataclass
+class ServeStats:
+    """What one ``Engine.serve`` call did, counted where the work happens.
+
+    Decode: ``steps`` rounds; ``row_steps_active`` and ``row_steps_total``
+    sum the occupied slots and the pool size ``B`` over them. For each
+    dispatch of the paged decode program, ``kv_read`` adds the KV positions
+    its attention kernel streams (``B * w * block_size``: every row walks
+    the table width ``w`` of the deepest active row) and ``kv_live`` the
+    positions the active rows attend to (each row's masked length,
+    ``pos + 1``). Prefill, admissions and rebuilds alike: ``prefill_real``
+    sums the real tokens, ``prefill_padded`` the bucket lengths dispatched.
+    ``admissions`` counts requests taken into a slot, ``backpressure`` the
+    rounds in which the pool could not cover the next request,
+    ``admit_retries`` and ``numeric_retries`` the prefill and decode
+    re-dispatches after a transient fault, and ``failures`` the requests
+    that ended early, by cause (``admit``: retries exhausted; ``numeric``:
+    persistent non-finite logits; ``timeout``)."""
+
+    steps: int = 0
+    row_steps_active: int = 0
+    row_steps_total: int = 0
+    kv_read: int = 0
+    kv_live: int = 0
+    prefill_real: int = 0
+    prefill_padded: int = 0
+    admissions: int = 0
+    backpressure: int = 0
+    admit_retries: int = 0
+    numeric_retries: int = 0
+    failures: Dict[str, int] = dataclasses.field(
+        default_factory=collections.Counter)
 
 
 @dataclasses.dataclass
@@ -356,6 +408,7 @@ class Engine:
         (self._prefill, self._insert, self._decode, self._sample) = \
             _make_steps(cfg, max_len, self.ctx)
         self.base_key = jax.random.PRNGKey(seed)
+        self.stats = ServeStats()  # of the last serve() call
 
     # -- scheduling policy ----------------------------------------------------
     def _admission_open(self, slots: List[Optional[_Slot]]) -> bool:
@@ -375,6 +428,10 @@ class Engine:
                 raise ValueError("rng_seed must fit in int32")
             if r.deadline_s is not None and r.deadline_s <= 0:
                 raise ValueError("deadline_s must be positive")
+        t_start = time.perf_counter()
+        for r in requests:
+            r.t_admit = r.t_first = r.t_done = None
+        stats = self.stats = ServeStats()
         camp = fj.active_campaign()
         queue: Deque[Tuple[int, Request]] = collections.deque(
             enumerate(requests))
@@ -403,6 +460,7 @@ class Engine:
             r = slot.request
             r.out_tokens = np.asarray(slot.generated, np.int32)
             r.finish_reason = reason
+            r.t_done = time.perf_counter() - t_start
             slots[s] = None
             tok[s], temps[s] = 0, 0.0  # dead row decodes greedily into void
             if self.paged:
@@ -507,6 +565,8 @@ class Engine:
                 tokens[0, plen:written] = slot.generated[:written - plen]
                 mask = np.zeros((1, lp), bool)
                 mask[0, :written] = True
+                stats.prefill_real += written
+                stats.prefill_padded += lp
                 _, row = self._prefill(
                     self.params, jnp.asarray(tokens), jnp.asarray(mask),
                     jnp.asarray(written - 1, jnp.int32))
@@ -531,30 +591,37 @@ class Engine:
             for attempt in range(self.admission_retries + 1):
                 num_inj = None
                 try:
-                    if camp is not None:
-                        inj = camp.draw("admit/launch",
-                                        kinds=("launch", "dma"), op="prefill")
-                        if inj is not None:
-                            raise camp.fault_for(inj, op="prefill",
-                                                 backend=self.ctx.backend)
-                    logits1, row = self._prefill(
-                        self.params, jnp.asarray(tokens), jnp.asarray(mask),
-                        jnp.asarray(plen - 1, jnp.int32))
-                    if camp is not None:
-                        num_inj = camp.draw("admit/numeric",
-                                            kinds=("numeric",), op="prefill")
-                        if num_inj is not None:
-                            logits1 = camp.corrupt_output(logits1, num_inj)
-                    if not np.all(np.isfinite(
-                            np.asarray(logits1, np.float32))):
-                        raise flt.NumericFault("non-finite prefill logits",
-                                               op="prefill",
-                                               injection=num_inj)
+                    with jax.profiler.TraceAnnotation("serve/prefill"):
+                        if camp is not None:
+                            inj = camp.draw("admit/launch",
+                                            kinds=("launch", "dma"),
+                                            op="prefill")
+                            if inj is not None:
+                                raise camp.fault_for(inj, op="prefill",
+                                                     backend=self.ctx.backend)
+                        stats.prefill_real += plen
+                        stats.prefill_padded += lp
+                        logits1, row = self._prefill(
+                            self.params, jnp.asarray(tokens),
+                            jnp.asarray(mask),
+                            jnp.asarray(plen - 1, jnp.int32))
+                        if camp is not None:
+                            num_inj = camp.draw("admit/numeric",
+                                                kinds=("numeric",),
+                                                op="prefill")
+                            if num_inj is not None:
+                                logits1 = camp.corrupt_output(logits1, num_inj)
+                        if not np.all(np.isfinite(
+                                np.asarray(logits1, np.float32))):
+                            raise flt.NumericFault(
+                                "non-finite prefill logits", op="prefill",
+                                injection=num_inj)
                 except flt.TransientFault as e:
                     last = attempt == self.admission_retries
                     if camp is not None:
                         camp.resolve(e, "row_failed" if last else "retried")
                     if not last:
+                        stats.admit_retries += 1
                         time.sleep(min(self.retry_backoff_s * (2 ** attempt),
                                        0.05))
                     continue
@@ -572,6 +639,7 @@ class Engine:
                 if (slot is not None
                         and slot.request.deadline_s is not None
                         and now - slot.t0 >= slot.request.deadline_s):
+                    stats.failures["timeout"] += 1
                     finish(s, "timeout")
             # -- pool integrity: check every round under a campaign (finish
             # may have just corrupted the allocator), periodically otherwise;
@@ -582,9 +650,11 @@ class Engine:
                     if inj is not None:
                         camp.corrupt_allocator(alloc, inj)
                 try:
-                    alloc.check()
+                    with jax.profiler.TraceAnnotation("serve/pool_check"):
+                        alloc.check()
                 except flt.PoolIntegrityFault:
-                    alloc, cache = rebuild_pool()
+                    with jax.profiler.TraceAnnotation("serve/rebuild"):
+                        alloc, cache = rebuild_pool()
                     if camp is not None:
                         camp.resolve_kind("pool", "rebuilt")
             # -- admission: prefill queued requests into freed slots --------
@@ -605,16 +675,21 @@ class Engine:
                                 # injected pool starvation rides the normal
                                 # backpressure path: the request just waits
                                 camp.resolve(inj, "backpressure")
+                                stats.backpressure += 1
                                 break
                         blocks = reserve(r, budget)
                         if blocks is None:
                             if not any(x is not None for x in slots):
                                 try:
-                                    alloc.check()
+                                    with jax.profiler.TraceAnnotation(
+                                            "serve/pool_check"):
+                                        alloc.check()
                                 except flt.PoolIntegrityFault:
                                     # a corrupted allocator can fake
                                     # exhaustion: repair, retry next round
-                                    alloc, cache = rebuild_pool()
+                                    with jax.profiler.TraceAnnotation(
+                                            "serve/rebuild"):
+                                        alloc, cache = rebuild_pool()
                                     if camp is not None:
                                         camp.resolve_kind("pool", "rebuilt")
                                     break
@@ -628,48 +703,62 @@ class Engine:
                                                     // bs),
                                     available_blocks=alloc.available(),
                                     live_blocks=alloc.live_blocks())
+                            stats.backpressure += 1
                             break  # backpressure: wait for a slot to finish
                         slot_blocks[s] = blocks
                         tables[s, :] = 0
                         tables[s, :len(blocks)] = blocks
                         tables_dev.clear()
-                    queue.popleft()
-                    admitted += 1
-                    out = admit_prefill(plen, r.prompt)
-                    if out is None:
-                        # transient faults exhausted the retry budget: this
-                        # request alone fails; its reservation is returned
-                        r.out_tokens = np.asarray([], np.int32)
-                        r.finish_reason = "error"
-                        if self.paged:
-                            for bid in slot_blocks[s]:
-                                alloc.free(bid)
-                            slot_blocks[s] = []
-                            tables[s, :] = 0
-                            tables_dev.clear()
-                        continue
-                    logits1, row = out
-                    slots[s] = _Slot(request=r, budget=budget,
-                                     t0=time.monotonic())
-                    seeds[s] = r.rng_seed if r.rng_seed is not None else rid
-                    temps[s] = r.temperature
-                    pos[s] = plen
-                    if self.paged:
-                        # land the prompt's blocks in the pool (a shared hit
-                        # is rewritten with bit-identical K/V: same tokens,
-                        # positions, params; RoPE is applied pre-cache)
-                        nt = -(-plen // bs)
-                        cache = self._paged_insert(
-                            cache, row,
-                            jnp.asarray(slot_blocks[s][:nt], jnp.int32))
-                    else:
-                        cache = self._insert(cache, row, s)
-                    first, _ = self._sample(
-                        logits1, self.base_key,
-                        jnp.asarray(seeds[s:s + 1]),
-                        jnp.zeros(1, jnp.int32),
-                        jnp.asarray(temps[s:s + 1]))
-                    record(s, int(np.asarray(first)[0]))
+                    with jax.profiler.TraceAnnotation("serve/admit",
+                                                      rid=rid):
+                        queue.popleft()
+                        admitted += 1
+                        stats.admissions += 1
+                        r.t_admit = time.perf_counter() - t_start
+                        out = admit_prefill(plen, r.prompt)
+                        if out is None:
+                            # transient faults exhausted the retry budget:
+                            # this request alone fails; its reservation is
+                            # returned
+                            r.out_tokens = np.asarray([], np.int32)
+                            r.finish_reason = "error"
+                            r.t_done = time.perf_counter() - t_start
+                            stats.failures["admit"] += 1
+                            if self.paged:
+                                for bid in slot_blocks[s]:
+                                    alloc.free(bid)
+                                slot_blocks[s] = []
+                                tables[s, :] = 0
+                                tables_dev.clear()
+                            continue
+                        logits1, row = out
+                        slots[s] = _Slot(request=r, budget=budget,
+                                         t0=time.monotonic())
+                        seeds[s] = (r.rng_seed if r.rng_seed is not None
+                                    else rid)
+                        temps[s] = r.temperature
+                        pos[s] = plen
+                        with jax.profiler.TraceAnnotation("serve/insert"):
+                            if self.paged:
+                                # land the prompt's blocks in the pool (a
+                                # shared hit is rewritten with bit-identical
+                                # K/V: same tokens, positions, params; RoPE
+                                # is applied pre-cache)
+                                nt = -(-plen // bs)
+                                cache = self._paged_insert(
+                                    cache, row, jnp.asarray(
+                                        slot_blocks[s][:nt], jnp.int32))
+                            else:
+                                cache = self._insert(cache, row, s)
+                        with jax.profiler.TraceAnnotation("serve/first_token"):
+                            first, _ = self._sample(
+                                logits1, self.base_key,
+                                jnp.asarray(seeds[s:s + 1]),
+                                jnp.zeros(1, jnp.int32),
+                                jnp.asarray(temps[s:s + 1]))
+                            first = int(np.asarray(first)[0])
+                        r.t_first = time.perf_counter() - t_start
+                        record(s, first)
             active = [s for s in range(B) if slots[s] is not None]
             if not active:
                 # everything admitted this round finished instantly, or
@@ -691,54 +780,73 @@ class Engine:
             # never recorded (active-slot masking). A decode step rewrites
             # the same cache positions with the same values, so the numeric
             # retry below can simply re-run it.
-            steps = np.array([len(slots[s].generated) if slots[s] else 0
-                              for s in range(B)], np.int32)
-            idx = np.where([slots[s] is not None for s in range(B)], pos, 0)
-            for attempt in range(self.numeric_retries + 1):
+            with jax.profiler.TraceAnnotation("serve/step"):
                 if self.paged:
                     # table width follows the deepest active row; dead rows
                     # are all-zero (garbage) tables. Shape-driven retrace.
                     w = max(int(pos[s]) // bs + 1 for s in active)
-                    if w not in tables_dev:
-                        tables_dev[w] = jnp.asarray(tables[:, :w])
-                    logits, cache = self._paged_decode(
-                        self.params, cache, jnp.asarray(tok)[:, None],
-                        jnp.asarray(idx, jnp.int32), tables_dev[w])
-                else:
-                    logits, cache = self._decode(
-                        self.params, cache, jnp.asarray(tok)[:, None],
-                        jnp.asarray(idx, jnp.int32))
-                num_inj = None
-                if camp is not None:
-                    num_inj = camp.draw("decode/numeric",
-                                        kinds=("numeric",), op="decode")
-                    if num_inj is not None:
-                        logits = camp.corrupt_rows(logits, active, num_inj)
-                nxt_dev, bad_dev = self._sample(
-                    logits, self.base_key, jnp.asarray(seeds),
-                    jnp.asarray(steps), jnp.asarray(temps))
-                nxt = np.asarray(nxt_dev)
-                bad = np.asarray(bad_dev)
-                bad_rows = [s for s in active if bad[s]]
-                if not bad_rows:
+                stats.steps += 1
+                stats.row_steps_active += len(active)
+                stats.row_steps_total += B
+                steps = np.array([len(slots[s].generated) if slots[s] else 0
+                                  for s in range(B)], np.int32)
+                idx = np.where([slots[s] is not None for s in range(B)],
+                               pos, 0)
+                for attempt in range(self.numeric_retries + 1):
+                    with jax.profiler.TraceAnnotation("serve/decode"):
+                        if self.paged:
+                            stats.kv_read += B * w * bs
+                            stats.kv_live += sum(int(pos[s]) + 1
+                                                 for s in active)
+                            if w not in tables_dev:
+                                tables_dev[w] = jnp.asarray(tables[:, :w])
+                            logits, cache = self._paged_decode(
+                                self.params, cache, jnp.asarray(tok)[:, None],
+                                jnp.asarray(idx, jnp.int32), tables_dev[w])
+                        else:
+                            logits, cache = self._decode(
+                                self.params, cache, jnp.asarray(tok)[:, None],
+                                jnp.asarray(idx, jnp.int32))
+                    num_inj = None
+                    if camp is not None:
+                        num_inj = camp.draw("decode/numeric",
+                                            kinds=("numeric",), op="decode")
+                        if num_inj is not None:
+                            logits = camp.corrupt_rows(logits, active,
+                                                       num_inj)
+                    with jax.profiler.TraceAnnotation("serve/sample"):
+                        nxt_dev, bad_dev = self._sample(
+                            logits, self.base_key, jnp.asarray(seeds),
+                            jnp.asarray(steps), jnp.asarray(temps))
+                    with jax.profiler.TraceAnnotation("serve/read"):
+                        nxt = np.asarray(nxt_dev)
+                        bad = np.asarray(bad_dev)
+                    bad_rows = [s for s in active if bad[s]]
+                    if not bad_rows:
+                        if camp is not None and num_inj is not None:
+                            camp.resolve(num_inj, "retried")  # unreachable
+                        break
+                    last = attempt == self.numeric_retries
                     if camp is not None and num_inj is not None:
-                        camp.resolve(num_inj, "retried")  # unreachable guard
-                    break
-                last = attempt == self.numeric_retries
-                if camp is not None and num_inj is not None:
-                    camp.resolve(num_inj, "row_failed" if last else "retried")
-                if last:
-                    # persistent NaN on these rows: fail them alone, keep
-                    # their generated-so-far tokens, never record this step
-                    for s in bad_rows:
-                        finish(s, "error")
-                    break
-                time.sleep(min(self.retry_backoff_s * (2 ** attempt), 0.05))
-            for s in active:
-                if slots[s] is None:
-                    continue  # failed/expired rows were closed above
-                pos[s] += 1
-                record(s, int(nxt[s]))
+                        camp.resolve(num_inj,
+                                     "row_failed" if last else "retried")
+                    if last:
+                        # persistent NaN on these rows: fail them alone, keep
+                        # their generated-so-far tokens, never record this
+                        # step
+                        for s in bad_rows:
+                            stats.failures["numeric"] += 1
+                            finish(s, "error")
+                        break
+                    stats.numeric_retries += 1
+                    time.sleep(min(self.retry_backoff_s * (2 ** attempt),
+                                   0.05))
+                with jax.profiler.TraceAnnotation("serve/record"):
+                    for s in active:
+                        if slots[s] is None:
+                            continue  # failed/expired rows were closed above
+                        pos[s] += 1
+                        record(s, int(nxt[s]))
         if camp is not None:
             # the pool dies with the loop: any still-latent corruption from a
             # last-round finish is discarded wholesale — the degenerate

@@ -308,6 +308,7 @@ def test_deadline_expires_with_partial_output(engine_setup):
                              deadline_s=1e-9)])
     assert out[0].finish_reason == "timeout"
     assert 0 < len(out[0].out_tokens) < 5000
+    assert eng.stats.failures == {"timeout": 1}
 
 
 def test_deadline_must_be_positive(engine_setup):
@@ -331,6 +332,9 @@ def test_admission_retry_exhaustion_fails_one_request(engine_setup):
     assert reasons == ["error", "length", "length"]
     assert len(out[0].out_tokens) == 0
     assert camp.summary()["resolutions"] == {"retried": 3, "row_failed": 1}
+    s = eng.stats
+    assert (s.admissions, s.admit_retries, s.failures) == (3, 3, {"admit": 1})
+    assert out[0].t_first is None and out[0].t_done >= out[0].t_admit
 
 
 def test_decode_nan_fails_only_bad_rows_with_clean_prefix(engine_setup):
@@ -344,6 +348,8 @@ def test_decode_nan_fails_only_bad_rows_with_clean_prefix(engine_setup):
         out = eng.serve(_reqs(2))
     camp.verify_accounted()
     assert camp.summary()["resolutions"] == {"row_failed": 2}
+    assert (eng.stats.numeric_retries, eng.stats.failures) == (
+        0, {"numeric": 2})
     for c, f in zip(clean, out):
         assert f.finish_reason == "error"
         # no tokens recorded from the faulted step; the prefix is the
@@ -364,6 +370,7 @@ def test_decode_nan_retry_recovers_idempotently(engine_setup):
         out = eng.serve(_reqs(2))
     camp.verify_accounted()
     assert camp.summary()["resolutions"] == {"retried": 1}
+    assert eng.stats.numeric_retries == 1 and not eng.stats.failures
     for c, f in zip(clean, out):  # the retried step changed nothing
         assert f.finish_reason == c.finish_reason
         assert np.array_equal(f.out_tokens, c.out_tokens)
@@ -379,6 +386,7 @@ def test_injected_oom_rides_backpressure_to_completion(engine_setup):
         out = eng.serve(_reqs(3))
     camp.verify_accounted()
     assert camp.summary()["resolutions"] == {"backpressure": 3}
+    assert eng.stats.backpressure == 3
     for c, f in zip(clean, out):
         assert f.finish_reason == c.finish_reason
         assert np.array_equal(f.out_tokens, c.out_tokens)

@@ -344,3 +344,83 @@ def test_slot_cache_ops_roundtrip():
     pool = T.reset_cache_slot(pool, 1)
     for leaf in jax.tree.leaves(pool):
         np.testing.assert_array_equal(np.asarray(leaf), 0.0)
+
+
+def _three_requests():
+    return [Request(prompt=np.arange(1, 6, dtype=np.int32), max_new_tokens=4),
+            Request(prompt=np.arange(1, 21, dtype=np.int32), max_new_tokens=2),
+            Request(prompt=np.arange(1, 4, dtype=np.int32), max_new_tokens=3)]
+
+
+def test_serve_stats_by_hand():
+    """Three slots, blocks and prefill buckets of 16, greedy requests A
+    (prompt 5, 4 tokens), B (20, 2) and C (3, 3), all admitted in the first
+    round. Prefill: real 5 + 20 + 3 = 28, padded 16 + 32 + 16 = 64. Each
+    admission yields the first token; decode rounds then run with cache
+    depths (pos) of
+      step 1: A 5, B 20, C 3 -> widest table 20 // 16 + 1 = 2 blocks; the
+              kernel streams 3 * 2 * 16 = 96 positions, the rows attend to
+              6 + 21 + 4 = 31; B has its 2 tokens and finishes;
+      step 2: A 6, C 4 -> 1 block, 3 * 16 = 48 streamed, 7 + 5 = 12 live;
+              C has its 3 tokens;
+      step 3: A 7 -> 48 streamed, 8 live; A has its 4.
+    So 3 steps, 3 + 2 + 1 = 6 of 9 row-steps active, kv_read 192, kv_live
+    51."""
+    params, cfg = _params_and_cfg("stablelm_1_6b")
+    eng = Engine(cfg, params, max_len=64, batch_size=3, paged=True)
+    assert (eng.block_size, eng.prefill_bucket) == (16, 16)
+    eng.serve(_three_requests())
+    s = eng.stats
+    assert (s.steps, s.row_steps_active, s.row_steps_total) == (3, 6, 9)
+    assert (s.kv_read, s.kv_live) == (192, 51)
+    assert (s.prefill_real, s.prefill_padded) == (28, 64)
+    assert (s.admissions, s.backpressure, s.admit_retries,
+            s.numeric_retries, dict(s.failures)) == (3, 0, 0, 0, {})
+    eng.serve(_three_requests()[:1])  # the counters cover one call
+    assert (eng.stats.steps, eng.stats.admissions) == (3, 1)
+
+
+def test_request_timestamps_are_ordered():
+    params, cfg = _params_and_cfg("stablelm_1_6b")
+    reqs = _three_requests()
+    Engine(cfg, params, max_len=64, batch_size=2, paged=True).serve(reqs)
+    for r in reqs:
+        assert 0.0 <= r.t_admit <= r.t_first <= r.t_done
+    # C waits for B's slot, so it is admitted after B is done
+    assert reqs[2].t_admit >= reqs[1].t_done
+
+
+def test_serve_spans_land_in_a_profiler_trace(tmp_path):
+    """A CPU profile of one tiny job holds the engine's spans: one
+    ``serve/admit`` per request (``rid`` its index) with its prefill,
+    insert and first-token children, one ``serve/step`` per decode round
+    with its decode, sample, read and record children."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    params, cfg = _params_and_cfg("stablelm_1_6b")
+    eng = Engine(cfg, params, max_len=64, batch_size=3, paged=True)
+    eng.serve(_three_requests())  # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.serve(_three_requests())
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("serve/")]
+    count = lambda name: sum(ev.name == name for ev in events)
+    for name in ("serve/admit", "serve/prefill", "serve/insert",
+                 "serve/first_token"):
+        assert count(name) == 3, name
+    for name in ("serve/step", "serve/decode", "serve/sample", "serve/read",
+                 "serve/record"):
+        assert count(name) == eng.stats.steps == 3, name
+    rids = sorted(dict(ev.stats)["rid"] for ev in events
+                  if ev.name == "serve/admit")
+    assert rids == [0, 1, 2]

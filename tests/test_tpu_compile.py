@@ -91,6 +91,7 @@ def test_pallas_conv_compiles(compile_for_chip, layer):
     hlo = compile_for_chip(functools.partial(
         ops.conv2d, stride=(s.sh, s.sw), ctx=_ctx("pallas")), x, w)
     assert "tpu_custom_call" in hlo
+    assert "%conv2d_lp." in hlo  # the kernel's name, as a trace shows it
 
 
 def test_im2col_gemm_compiles_conv3_x(compile_for_chip):
@@ -118,6 +119,7 @@ def test_flash_prefill_compiles_qwen_widths(compile_for_chip):
         ((1, QWEN.n_kv_heads, 512, hd), BF16),
         ((1, QWEN.n_kv_heads, 512, hd), BF16))
     assert "tpu_custom_call" in hlo
+    assert "%flash_attention." in hlo
 
 
 def test_paged_decode_compiles_qwen_widths(compile_for_chip):
@@ -129,3 +131,4 @@ def test_paged_decode_compiles_qwen_widths(compile_for_chip):
         ((B, QWEN.n_heads, 1, hd), BF16), pool, pool,
         ((B, width), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in hlo
+    assert "%paged_decode_attention." in hlo
