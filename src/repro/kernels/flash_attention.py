@@ -257,48 +257,68 @@ def flash_attention(
 # Paged decode: block-table-gathering attention over the serving KV pool.
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *,
-                         scale: float, block_size: int, n_blk: int):
-    """One (batch row, kv head) pair streams its block-table chain: grid step
-    j fetches physical block ``tables[b, j]`` straight from the pool via the
-    index_map (no gather materialized in HBM), masks positions past the row's
-    cache length, and folds into the online softmax. Dead/padded table slots
-    point at reserved block 0, whose garbage keys are masked by the length."""
+def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, *, scale: float):
+    """One batch row, all its kv heads: the row's ``n = ceil(len / bs)`` live
+    pages are DMA'd by hand in table order into a two-slot VMEM buffer (page
+    i + 1 lands while page i folds) and each (kv head, page) folds into that
+    head's online softmax. Table slots past ``n`` are never read, so dead
+    slots pointing at reserved block 0 move nothing; a row of length 0 moves
+    nothing and stores zeros. Positions past the length inside the last live
+    page are masked."""
+    _, n_kv, g, hd = q_ref.shape
+    block_size, w = k_buf.shape[2], tables_ref.shape[1]
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    length = lens_ref[b]
+    n = jnp.minimum((length + block_size - 1) // block_size, w)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def stream(slot, i):
+        page = tables_ref[b, i]
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot],
+                                      sems.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot],
+                                      sems.at[slot, 1]))
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (g, hd): this kv head's q group
-    k = k_ref[0, 0].astype(jnp.float32)  # (bs, hd)
-    v = v_ref[0, 0].astype(jnp.float32)  # (bs, hd)
+    @pl.when(n > 0)
+    def _warmup():
+        for cp in stream(0, 0):
+            cp.start()
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (g, bs)
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < lens_ref[b], s, NEG_INF)
+    def fold(i, carry):
+        slot = i % 2
 
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+        @pl.when(i + 1 < n)
+        def _prefetch():  # overlap the next page's DMA with this page's fold
+            for cp in stream(1 - slot, i + 1):
+                cp.start()
 
-    @pl.when(j == n_blk - 1)
-    def _store():
-        l = l_ref[...]
+        for cp in stream(slot, i):
+            cp.wait()
+        out = []
+        for h, (m_prev, l_prev, acc) in enumerate(carry):
+            q = q_ref[0, h].astype(jnp.float32)  # (g, hd)
+            k = k_buf[slot, h].astype(jnp.float32)  # (bs, hd)
+            v = v_buf[slot, h].astype(jnp.float32)  # (bs, hd)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            kpos = i * block_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(kpos < length, s, NEG_INF)
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.dot(p, v,
+                                        preferred_element_type=jnp.float32)
+            out.append((m_new, l_new, acc))
+        return tuple(out)
+
+    init = tuple((jnp.full((g, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((g, 1), jnp.float32),
+                  jnp.zeros((g, hd), jnp.float32)) for _ in range(n_kv))
+    for h, (_, l, acc) in enumerate(jax.lax.fori_loop(0, n, fold, init)):
         l = jnp.where(l == 0.0, 1.0, l)  # rows with length 0 -> zeros
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, h] = (acc / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -312,41 +332,38 @@ def paged_decode_attention(
     """Decode attention that reads K/V directly out of the paged pool.
 
     The communication-optimal property the paper's decode bound asks for:
-    each sequence moves exactly its own ``w * block_size`` cached keys/values
-    once — no repeat-materialized GQA heads, no gather copy of the table into
-    a contiguous buffer first. Query heads are grouped per kv head
-    (h = kv * g + i, matching the registry's GQA fold), so the q block a grid
-    row loads is the (g, hd) group that shares its kv head."""
+    each sequence moves only its own live pages, ``ceil(len / block_size)``
+    of them, once — no repeat-materialized GQA heads, no gather copy of the
+    table into a contiguous buffer first, nothing past the row's length.
+    The grid is one step per batch row; query heads are grouped per kv head
+    (h = kv * g + i, matching the registry's GQA fold), so a row's q block
+    is its (KV, g, hd) groups and each fetched page (KV, block_size, hd)
+    serves every group."""
     B, H, Lq, hd = q.shape
     if Lq != 1:
         raise ValueError(f"paged decode takes one query per row, got Lq={Lq}")
     KV, block_size = kp.shape[1], kp.shape[2]
-    w = tables.shape[1]
     g = H // KV
     params = pltpu.CompilerParams(vmem_limit_bytes=(
         target if target is not None else default_target()).vmem_limit_bytes)
     scale = 1.0 / (hd ** 0.5)
     qf = q.reshape(B, KV, g, hd)
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, block_size=block_size, n_blk=w)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, KV, w),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda b, h, j, t, l: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_size, hd),
-                             lambda b, h, j, t, l: (t[b, j], h, 0, 0)),
-                pl.BlockSpec((1, 1, block_size, hd),
-                             lambda b, h, j, t, l: (t[b, j], h, 0, 0)),
+                pl.BlockSpec((1, KV, g, hd), lambda b, t, l: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, g, hd), lambda b, h, j, t, l: (b, h, 0, 0)),
+                (1, KV, g, hd), lambda b, t, l: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((2, KV, block_size, hd), kp.dtype),
+                pltpu.VMEM((2, KV, block_size, hd), vp.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
@@ -439,61 +456,69 @@ def paged_decode_access_plan(B: int, KV: int, g: int, w: int,
                              p_o: float = 1.0, tables=None,
                              op: str = "attention_decode"):
     """The :class:`repro.verify.access.KernelAccessPlan` of one paged decode
-    launch. ``tables`` defaults to a synthetic table with all-distinct
-    consecutive physical blocks — the allocator's normal output — which is
-    the traffic-maximal case ``paged_decode_hbm_words`` charges (a table
-    that happens to repeat a block in consecutive slots would move less:
-    the index map (t[b, j], h) elides the re-fetch)."""
+    launch: grid ``(B,)``, each row's q groups in and out as blocks, and its
+    page stream as ``w`` manual copies of ``(KV, block_size, hd)`` per row
+    into the two-slot buffer, whose ping-pong ``dma`` declares. The stream
+    is declared at full width, every row walking all ``w`` table slots: an
+    upper bound, since a row reads only its ``ceil(len / block_size)`` live
+    pages, and the words ``paged_decode_hbm_words`` charges. Manual copies
+    elide no repeat, so any table moves these words; ``tables`` defaults to
+    the allocator's normal all-distinct consecutive blocks."""
     import numpy as np
 
     from repro.verify.access import (BlockAccess, FlatAccess,
-                                     KernelAccessPlan, ScratchAlloc)
+                                     KernelAccessPlan, ScratchAlloc,
+                                     WindowAccess)
+    from repro.verify.hazards import double_buffered_schedule
 
     if tables is None:
         tables = (np.arange(B * w, dtype=np.int64).reshape(B, w)
                   % max(num_blocks, 1))
-        if num_blocks < 2 and w > 1:
-            raise ValueError("paged pool with < 2 blocks cannot have "
-                             "all-distinct consecutive table entries")
     t = np.asarray(tables, dtype=np.int64)
+    pool = (num_blocks, KV, block_size, hd)
+
+    def page(b, i):  # the copy of table slot i: the row's i-th page, whole
+        return ((t[b, i], 1), (0, KV), (0, block_size), (0, hd))
+
+    def needs(b, i):  # positions i*bs .. (i+1)*bs of row b live in t[b, i]
+        return ((t[b, i], t[b, i] + 1), (0, KV), (0, block_size), (0, hd))
+
     accesses = (
-        BlockAccess(name="q", kind="load", block_shape=(1, 1, g, hd),
+        BlockAccess(name="q", kind="load", block_shape=(1, KV, g, hd),
                     array_shape=(B, KV, g, hd), word_size=p_q,
-                    index_map=lambda b, h, j: (b, h, 0, 0)),
-        BlockAccess(name="k_pool", kind="load",
-                    block_shape=(1, 1, block_size, hd),
-                    array_shape=(num_blocks, KV, block_size, hd),
-                    word_size=p_kv,
-                    index_map=lambda b, h, j: (t[b, j], h, 0, 0)),
-        BlockAccess(name="v_pool", kind="load",
-                    block_shape=(1, 1, block_size, hd),
-                    array_shape=(num_blocks, KV, block_size, hd),
-                    word_size=p_kv,
-                    index_map=lambda b, h, j: (t[b, j], h, 0, 0)),
-        BlockAccess(name="out", kind="store", block_shape=(1, 1, g, hd),
+                    index_map=lambda b: (b, 0, 0, 0)),
+        WindowAccess(name="k_pool", kind="load", window=page, requires=needs,
+                     array_shape=pool, word_size=p_kv, copies=w),
+        WindowAccess(name="v_pool", kind="load", window=page, requires=needs,
+                     array_shape=pool, word_size=p_kv, copies=w),
+        BlockAccess(name="out", kind="store", block_shape=(1, KV, g, hd),
                     array_shape=(B, KV, g, hd), word_size=p_o,
-                    index_map=lambda b, h, j: (b, h, 0, 0)),
+                    index_map=lambda b: (b, 0, 0, 0)),
         FlatAccess(name="tables", kind="load", words=float(B * w),
                    note="int32 scalar prefetch, charged by words_fn"),
         FlatAccess(name="lengths", kind="load", words=float(B),
                    note="int32 scalar prefetch, charged by words_fn"),
     )
     scratch = (
-        ScratchAlloc("m/l/acc_f32", float(g + g + g * hd)),
-        ScratchAlloc("q_pipeline[2]", 2 * g * hd * p_q),
-        ScratchAlloc("kv_pipeline[2x2]", 4 * block_size * hd * p_kv),
-        ScratchAlloc("out_pipeline[2]", 2 * g * hd * p_o),
+        ScratchAlloc("m/l/acc_f32", float(KV * (g + g + g * hd))),
+        ScratchAlloc("q_pipeline[2]", 2 * KV * g * hd * p_q),
+        ScratchAlloc("kv_pages[2x2]", 4 * KV * block_size * hd * p_kv),
+        ScratchAlloc("out_pipeline[2]", 2 * KV * g * hd * p_o),
     )
-    return KernelAccessPlan(op=op, grid=(B, KV, w), accesses=accesses,
-                            scratch=scratch)
+    return KernelAccessPlan(
+        op=op, grid=(B,), accesses=accesses, scratch=scratch,
+        dma=double_buffered_schedule(w, n_slots=2, name="k/v page stream"),
+        note="page schedule repeats per row, cut at the row's live pages")
 
 
 def paged_decode_hbm_words(B: int, KV: int, g: int, w: int, block_size: int,
                            hd: int, p_q: float = 1.0, p_kv: float = 1.0,
                            p_o: float = 1.0) -> float:
-    """Words one paged decode step moves: each (row, kv head) loads its
-    (g, hd) query group, streams w blocks of k and v once, stores the group —
-    plus the int32 block tables and lengths (1 word each)."""
+    """Words one paged decode step moves at most: each row loads its
+    (KV, g, hd) query groups, streams w pages of k and v once, stores the
+    groups — plus the int32 block tables and lengths (1 word each). An upper
+    bound: a row reads only its ``ceil(len / block_size)`` live pages, which
+    lengths known only on the device decide."""
     return (p_q * B * KV * g * hd
             + 2.0 * p_kv * B * KV * w * block_size * hd
             + p_o * B * KV * g * hd

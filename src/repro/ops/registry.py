@@ -657,7 +657,7 @@ def _pallas_attention_decode_access(ctx, plan, q, kp, vp, tables, lengths,
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
     # concrete table values are used when available (explain() passes
     # ShapeDtypeStructs and jit passes tracers; the builder then synthesizes
-    # an all-distinct table, the allocator's normal traffic-maximal case)
+    # the allocator's all-distinct table: any table moves the same words)
     try:
         t_np = np.asarray(tables, dtype=np.int64)
         if t_np.ndim != 2:

@@ -138,8 +138,9 @@ class ServeStats:
     Decode: ``steps`` rounds; ``row_steps_active`` and ``row_steps_total``
     sum the occupied slots and the pool size ``B`` over them. For each
     dispatch of the paged decode program, ``kv_read`` adds the KV positions
-    its attention kernel streams (``B * w * block_size``: every row walks
-    the table width ``w`` of the deepest active row) and ``kv_live`` the
+    its attention kernel streams (each of the ``B`` rows reads its live
+    pages, ``ceil((idx + 1) / block_size) * block_size`` positions; free
+    rows ride at ``idx`` 0 and read one page) and ``kv_live`` the
     positions the active rows attend to (each row's masked length,
     ``pos + 1``). Prefill, admissions and rebuilds alike: ``prefill_real``
     sums the real tokens, ``prefill_padded`` the bucket lengths dispatched.
@@ -795,7 +796,8 @@ class Engine:
                 for attempt in range(self.numeric_retries + 1):
                     with jax.profiler.TraceAnnotation("serve/decode"):
                         if self.paged:
-                            stats.kv_read += B * w * bs
+                            stats.kv_read += sum(
+                                -(-(int(i) + 1) // bs) * bs for i in idx)
                             stats.kv_live += sum(int(pos[s]) + 1
                                                  for s in active)
                             if w not in tables_dev:

@@ -47,11 +47,13 @@ class WindowAccess:
 
     ``window`` maps grid indices to one ``(start, size)`` pair per array
     dimension (vectorizable; ``size`` is static per plan). The copy issues
-    every grid step — no revisit elision — so words = n_steps x window
-    words. ``requires`` independently derives the element range op
+    every grid step — no revisit elision — so words = n_steps x copies x
+    window words. ``requires`` independently derives the element range op
     semantics need at that step; the auditor checks requires ⊆ window,
     which is what catches an off-by-one halo index map even when the word
-    *totals* stay unchanged.
+    *totals* stay unchanged. ``copies``, where set, declares that many
+    copies per grid step (the paged decode's page loop): ``window`` and
+    ``requires`` then take one more trailing argument, the copy's index.
     """
 
     name: str
@@ -62,6 +64,7 @@ class WindowAccess:
     requires: Optional[Callable] = None  # (*grid_axes) -> ((lo, hi), ...)
     counted: bool = True
     note: str = ""
+    copies: Optional[int] = None  # copies per grid step; None: one
 
 
 @dataclasses.dataclass(frozen=True)

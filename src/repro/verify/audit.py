@@ -8,11 +8,11 @@ words the launch moves:
   * BlockSpec operands move their block only when the mapped index *changes*
     between consecutive steps (the Pallas revisit elision), so words =
     transition count x block words.
-  * Manual-DMA window operands copy every step: words = n_steps x window
-    words. Their windows are bounds-checked against the padded array extent
-    and checked to *cover* the independently-derived ``requires`` region —
-    the check with teeth against off-by-one halo index maps, whose word
-    totals are unchanged.
+  * Manual-DMA window operands copy every step: words = n_steps x copies
+    per step x window words. Their windows are bounds-checked against the
+    padded array extent and checked to *cover* the independently-derived
+    ``requires`` region — the check with teeth against off-by-one halo
+    index maps, whose word totals are unchanged.
   * Flat (scalar-prefetch / one-shot) operands contribute their words as-is.
 
 ``audit_decision`` then holds a ``DispatchDecision`` to account: the counted
@@ -115,6 +115,10 @@ def _audit_block(acc: BlockAccess, axes: List[np.ndarray], n_steps: int,
 
 def _audit_window(acc: WindowAccess, axes: List[np.ndarray], n_steps: int,
                   problems: List[str]) -> float:
+    if acc.copies is not None:  # one more, innermost axis: the copy
+        axes = [np.repeat(a, acc.copies) for a in axes] + [
+            np.tile(np.arange(acc.copies, dtype=np.int64), n_steps)]
+        n_steps *= acc.copies
     win = acc.window(*axes)
     if len(win) != len(acc.array_shape):
         problems.append(f"{acc.name}: window yields {len(win)} dims for a "

@@ -1,10 +1,12 @@
 """DMA-hazard detector for double-buffered copy schedules.
 
-The manual ``make_async_copy`` pipelines in ``kernels/conv2d.py`` and
-``kernels/matmul.py`` follow one shape: per reduction step ``ci`` warm up
-slot 0 on the first step, prefetch step ``ci+1`` into the other slot, wait
-on ``ci``'s slot, then read it. :func:`double_buffered_schedule` emits that
-event stream; :func:`check_schedule` simulates it and reports every hazard:
+The manual ``make_async_copy`` pipelines in ``kernels/conv2d.py``,
+``kernels/matmul.py`` and the paged decode's page loop
+(``kernels/flash_attention.py``) follow one shape: per reduction step
+``ci`` warm up slot 0 on the first step, prefetch step ``ci+1`` into the
+other slot, wait on ``ci``'s slot, then read it.
+:func:`double_buffered_schedule` emits that event stream;
+:func:`check_schedule` simulates it and reports every hazard:
 
   H1 read-before-wait      a step reads slot data it never waited for
   H2 double-start          two in-flight copies target one slot

@@ -358,13 +358,13 @@ def test_serve_stats_by_hand():
     round. Prefill: real 5 + 20 + 3 = 28, padded 16 + 32 + 16 = 64. Each
     admission yields the first token; decode rounds then run with cache
     depths (pos) of
-      step 1: A 5, B 20, C 3 -> widest table 20 // 16 + 1 = 2 blocks; the
-              kernel streams 3 * 2 * 16 = 96 positions, the rows attend to
+      step 1: A 5, B 20, C 3 -> the kernel streams each row's live pages,
+              1 + 2 + 1 = 4 of 16 positions, 64; the rows attend to
               6 + 21 + 4 = 31; B has its 2 tokens and finishes;
-      step 2: A 6, C 4 -> 1 block, 3 * 16 = 48 streamed, 7 + 5 = 12 live;
-              C has its 3 tokens;
-      step 3: A 7 -> 48 streamed, 8 live; A has its 4.
-    So 3 steps, 3 + 2 + 1 = 6 of 9 row-steps active, kv_read 192, kv_live
+      step 2: A 6, C 4, free row B at idx 0 -> one page a row, 48
+              streamed, 7 + 5 = 12 live; C has its 3 tokens;
+      step 3: A 7, free rows at idx 0 -> 48 streamed, 8 live; A has its 4.
+    So 3 steps, 3 + 2 + 1 = 6 of 9 row-steps active, kv_read 160, kv_live
     51."""
     params, cfg = _params_and_cfg("stablelm_1_6b")
     eng = Engine(cfg, params, max_len=64, batch_size=3, paged=True)
@@ -372,7 +372,7 @@ def test_serve_stats_by_hand():
     eng.serve(_three_requests())
     s = eng.stats
     assert (s.steps, s.row_steps_active, s.row_steps_total) == (3, 6, 9)
-    assert (s.kv_read, s.kv_live) == (192, 51)
+    assert (s.kv_read, s.kv_live) == (160, 51)
     assert (s.prefill_real, s.prefill_padded) == (28, 64)
     assert (s.admissions, s.backpressure, s.admit_retries,
             s.numeric_retries, dict(s.failures)) == (3, 0, 0, 0, {})

@@ -132,3 +132,17 @@ def test_paged_decode_compiles_qwen_widths(compile_for_chip):
         ((B, width), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_decode_attention." in hlo
+
+
+def test_paged_decode_compiles_decode_cell_shape(compile_for_chip):
+    """The benchmark's decode cell: 64 slots, its 1,537-block pool and its
+    widest table, 24 blocks."""
+    hd = QWEN.d_model // QWEN.n_heads
+    B, blocks, bs, width = 64, 1537, 16, 24
+    pool = ((blocks, QWEN.n_kv_heads, bs, hd), BF16)
+    hlo = compile_for_chip(
+        functools.partial(ops.attention_decode, ctx=_ctx("pallas")),
+        ((B, QWEN.n_heads, 1, hd), BF16), pool, pool,
+        ((B, width), jnp.int32), ((B,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    assert "%paged_decode_attention." in hlo

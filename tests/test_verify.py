@@ -86,6 +86,33 @@ def test_attention_decode_paged_audit():
     _assert_exact(d)
 
 
+def test_paged_decode_page_stream_audit():
+    """The page stream as manual copies: a concrete table that shares pages
+    between rows (a prefix hit) moves the words_fn's words, since copies
+    elide no repeat; a copy one block off the table misses the page the
+    row needs."""
+    import numpy as np
+
+    from repro.kernels.flash_attention import (paged_decode_access_plan,
+                                               paged_decode_hbm_words)
+
+    B, KV, g, w, bs, hd, nb = 3, 2, 4, 3, 16, 32, 12
+    tables = np.array([[1, 2, 3], [1, 2, 4], [5, 0, 0]])
+    ap = paged_decode_access_plan(B, KV, g, w, bs, hd, nb, tables=tables)
+    report = audit_access_plan(ap)
+    assert report.ok, report.problems
+    assert report.counted_words == paged_decode_hbm_words(B, KV, g, w, bs,
+                                                          hd)
+    assert report.per_access["k_pool"] == B * w * KV * bs * hd
+    off = [dataclasses.replace(
+        a, window=lambda b, i: ((tables[b, i] + 1, 1), (0, KV), (0, bs),
+                                (0, hd)))
+        if a.name == "k_pool" else a for a in ap.accesses]
+    bad = audit_access_plan(dataclasses.replace(ap, accesses=tuple(off)))
+    assert any("k_pool" in p and "misses the required" in p
+               for p in bad.problems)
+
+
 def test_audit_decision_flags_wrong_measured_words():
     x = jax.ShapeDtypeStruct((2, 8, 12, 12), jnp.float32)
     w = jax.ShapeDtypeStruct((16, 8, 3, 3), jnp.float32)
