@@ -3,8 +3,8 @@
 For each op x shape in the sweep (the five ResNet-50 conv shapes from
 ``configs/resnet50_convs.py`` on both conv backends — plus their int8
 ``conv2d_q``/``matmul_q`` quantized forms, whose folded scale vector is an
-audited operand of its own — the GEMM / conv1d / attention shapes the
-tier-1 suite exercises, and the serving decode snapshots from
+audited operand of its own — the GEMM / conv1d / grouped-matmul / attention
+shapes the tier-1 suite exercises, and the serving decode snapshots from
 ``benchmarks/serving_bench``), dispatch through
 ``ops.explain(audit=True)``: the ``repro.verify`` auditor abstractly
 interprets the kernel's access plan and the dispatch fails unless the
@@ -84,6 +84,18 @@ def sweep_gemm_conv1d(dtype=jnp.bfloat16):
         rows.append(_row(
             f"conv1d_causal/B{B}_L{L}_D{D}_K{K}",
             ops.explain("conv1d_causal", PALLAS, spec_args=(x, w),
+                        audit=True)))
+    # the dropless expert layer's gate/up and down at one chip's 8 experts
+    # (LFM2-8B-A1B: 256 decode rows -> 1,280 grouped rows, a 19-token
+    # prefill -> 352)
+    for m, k, n in ((1280, 2048, 1792), (1280, 1792, 2048),
+                    (352, 2048, 1792)):
+        x = jax.ShapeDtypeStruct((m, k), dtype)
+        w = jax.ShapeDtypeStruct((8, k, n), dtype)
+        g = jax.ShapeDtypeStruct((8,), jnp.int32)
+        rows.append(_row(
+            f"grouped_matmul/{m}x{k}x{n}_G8",
+            ops.explain("grouped_matmul", PALLAS, spec_args=(x, w, g),
                         audit=True)))
     return rows
 

@@ -328,6 +328,7 @@ def paged_decode_attention(
     tables: jax.Array,   # (B, w) int32 - physical block ids per sequence
     lengths: jax.Array,  # (B,) int32 - valid cache length per sequence
     target: Optional[HardwareTarget] = None,
+    scale: Optional[float] = None,  # default 1/sqrt(hd)
 ) -> jax.Array:
     """Decode attention that reads K/V directly out of the paged pool.
 
@@ -346,7 +347,7 @@ def paged_decode_attention(
     g = H // KV
     params = pltpu.CompilerParams(vmem_limit_bytes=(
         target if target is not None else default_target()).vmem_limit_bytes)
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
     qf = q.reshape(B, KV, g, hd)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale),

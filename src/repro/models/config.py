@@ -4,7 +4,10 @@ A model is a repeated *pattern unit* of blocks (e.g. ("attn",) for dense LMs,
 ("mamba",)*7 + ("attn",) for jamba's 1:7 interleave, ("mlstm", "slstm") for
 xLSTM). Parameters are stacked over pattern repeats and the forward pass is a
 lax.scan over repeats — the HLO stays one-unit sized no matter how deep the
-model, which keeps 512-device dry-run compiles tractable.
+model, which keeps 512-device dry-run compiles tractable. A model whose
+layer kinds follow no period (LFM2's conv/attention order) is one unit of
+all its layers, run once: ``layer_types`` reads the kinds in layer order
+either way.
 """
 
 from __future__ import annotations
@@ -27,13 +30,27 @@ class ModelConfig:
     pattern: Tuple[str, ...] = ("attn",)  # block kinds in one pattern unit
     head_dim: Optional[int] = None
     qkv_bias: bool = False
+    qk_norm: bool = False  # RMSNorm over head_dim on q and k, before RoPE
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0  # the router's width: every expert of the layer
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     moe_every: int = 1  # apply MoE FFN on every k-th pattern position
+    moe_d_ff: int = 0  # one expert's width; 0: d_ff
+    n_dense_layers: int = 0  # leading layers with a dense FFN (one unit only)
+    # "softmax" (top-k of softmax, renormalised) or "sigmoid_bias" (DeepSeek-
+    # V3 / LFM2 with a routed scaling of 1: top-k of sigmoid + expert bias,
+    # weighted by the unbiased sigmoid renormalised)
+    router: str = "softmax"
+    # dropless expert layer (serving): no capacity, the held experts' rows
+    # sorted and run through ops.grouped_matmul; else GShard capacity dispatch
+    moe_dropless: bool = False
+    # expert parallelism: the layer's experts split into ``expert_shards``
+    # equal shares, of which this device holds share ``expert_shard``
+    expert_shards: int = 1
+    expert_shard: int = 0
     # SSM / recurrent
     ssm_state_dim: int = 16
     ssm_expand: int = 2
@@ -55,6 +72,22 @@ class ModelConfig:
             f"{self.name}: n_layers={self.n_layers} not divisible by pattern "
             f"unit {len(self.pattern)}")
         assert self.n_heads % max(self.n_kv_heads, 1) == 0
+        assert self.n_dense_layers == 0 or self.repeats == 1, (
+            f"{self.name}: leading dense layers need a single pattern unit")
+        assert self.router in ("softmax", "sigmoid_bias"), self.router
+        assert 0 <= self.expert_shard < self.expert_shards
+        assert self.n_experts % self.expert_shards == 0
+
+    @property
+    def layer_types(self) -> Tuple[str, ...]:
+        """Block kind of every layer, in layer order."""
+        return self.pattern * self.repeats
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this device computes."""
+        n = self.n_experts // self.expert_shards
+        return self.expert_shard * n, n
 
     @property
     def repeats(self) -> int:
@@ -93,6 +126,9 @@ class ModelConfig:
                 per_unit += di * (2 * self.ssm_state_dim + 1) + di  # B,C,dt,A
                 per_unit += di * D  # out proj
                 per_unit += self._ffn_params(i)
+            elif kind == "conv":  # gated short conv: in (B, C, x), out
+                per_unit += 3 * D * D + self.conv_kernel * D + D * D
+                per_unit += self._ffn_params(i)
             elif kind == "mlstm":
                 di = self.d_inner
                 per_unit += D * (4 * di) + di * D  # qkv+gates, out
@@ -106,9 +142,20 @@ class ModelConfig:
         if F == 0:
             return 0
         dense = 3 * D * F  # SwiGLU
-        if self.n_experts and pos % self.moe_every == 0:
-            return self.n_experts * dense + D * self.n_experts  # + router
+        if self.is_moe_position(pos):
+            return (self.n_experts * 3 * D * self.expert_d_ff
+                    + D * self.n_experts)  # + router
         return dense
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    def is_moe_position(self, pos: int) -> bool:
+        """Whether pattern position ``pos`` has an expert FFN (given that it
+        has an FFN at all)."""
+        return (self.n_experts > 0 and pos % self.moe_every == 0
+                and pos >= self.n_dense_layers)
 
     def active_param_count(self) -> int:
         """Parameters touched per token (MoE: only routed experts)."""
@@ -117,9 +164,10 @@ class ModelConfig:
         full = self.param_count()
         D, F = self.d_model, self.d_ff
         moe_positions = sum(1 for i in range(len(self.pattern))
-                            if self.pattern[i] in ("attn", "mamba", "slstm")
-                            and i % self.moe_every == 0 and F > 0)
-        dense = 3 * D * F
+                            if self.pattern[i] in ("attn", "mamba", "slstm",
+                                                   "conv")
+                            and self.is_moe_position(i) and F > 0)
+        dense = 3 * D * self.expert_d_ff
         inactive = (self.n_experts - self.experts_per_token) * dense
         return full - inactive * moe_positions * self.repeats
 
@@ -162,6 +210,7 @@ def reduced(cfg: ModelConfig, layers: Optional[int] = None) -> ModelConfig:
         n_kv_heads=kv,
         head_dim=16,
         d_ff=cfg.d_ff and 128,
+        moe_d_ff=cfg.moe_d_ff and 64,
         vocab_size=min(cfg.vocab_size, 256),
         n_experts=min(cfg.n_experts, 4),
         experts_per_token=min(cfg.experts_per_token, 2),

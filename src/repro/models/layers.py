@@ -100,6 +100,9 @@ def init_attention(key, cfg) -> Params:
         p["bq"] = jnp.zeros((H * hd,), dtype)
         p["bk"] = jnp.zeros((KV * hd,), dtype)
         p["bv"] = jnp.zeros((KV * hd,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((hd,), dtype)
+        p["k_norm"] = jnp.ones((hd,), dtype)
     return p
 
 
@@ -146,8 +149,13 @@ def attention_block(
         q = q + p["bq"].astype(cd)
         k = k + p["bk"].astype(cd)
         v = v + p["bv"].astype(cd)
-    q = q.reshape(B, L, H, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)
+    q = q.reshape(B, L, H, hd)
+    k = k.reshape(B, L, KV, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q = q.transpose(0, 2, 1, 3)
+    k = k.transpose(0, 2, 1, 3)
     v = v.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
@@ -180,6 +188,10 @@ def attention_block(
             out = jnp.einsum("blh,hd->bld", o,
                              p["wo"].astype(cd)).astype(x.dtype)
             return out, (kp, ks, vp, vs)
+        hp = kp.shape[-1]  # the pool's heads may be zero-padded past hd
+        if hp != hd:
+            k, v = (jnp.pad(a, ((0, 0),) * 3 + ((0, hp - hd),))
+                    for a in (k, v))
         kp = kp.at[blk, :, off].set(k[:, :, 0, :].astype(kp.dtype))
         vp = vp.at[blk, :, off].set(v[:, :, 0, :].astype(vp.dtype))
         o = ops.attention_decode(q.astype(cd), kp, vp, block_tables, pos + 1,
@@ -262,6 +274,61 @@ def _expand_key_mask(attn_mask, idx, L: int, Lk: int, cached: bool):
     in_window = (pos >= idx) & (pos < idx + L)
     return jnp.where(in_window, jnp.take_along_axis(attn_mask, col, axis=1),
                      True)
+
+
+# ---------------------------------------------------------------------------
+# Gated short convolution (LFM2)
+# ---------------------------------------------------------------------------
+
+def init_short_conv(key, cfg) -> Params:
+    D, K = cfg.d_model, cfg.conv_kernel
+    dtype = jnp.dtype(cfg.param_dtype)
+    ks = jax.random.split(key, 3)
+    return {
+        "w_in": truncated_normal(ks[0], (D, 3 * D), D ** -0.5, dtype),
+        "conv_w": truncated_normal(ks[1], (K, D), K ** -0.5, dtype),
+        "w_out": truncated_normal(ks[2], (D, D), D ** -0.5, dtype),
+    }
+
+
+def short_conv_block(
+    p: Params,
+    x: jax.Array,  # (B, L, D)
+    cfg,
+    state: Optional[jax.Array] = None,  # (B, K-1, D) inputs before x
+    attn_mask: Optional[jax.Array] = None,  # (B, L) True = real token
+    ctx: Optional[ExecutionContext] = None,
+) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """LFM2's gated short convolution: ``b, c, h = split(x @ w_in, 3)``,
+    then ``(c * conv(b * h)) @ w_out`` with a causal depthwise conv of ``K``
+    taps through ``ops.conv1d_causal``.
+
+    ``state`` holds the ``K - 1`` conv inputs that precede ``x`` (zeros at a
+    sequence's start), so one path serves prefill and decode. The returned
+    state holds the last ``K - 1`` inputs at each row's true length: ``L``,
+    or the count of real tokens in ``attn_mask``. The conv is causal, so a
+    right-padded prompt (a prefill bucket) is exact at its real positions
+    and its state is read at the true length; pad tokens never enter it."""
+    B, L, D = x.shape
+    cd = jnp.dtype(cfg.compute_dtype)
+    w = p["conv_w"].astype(cd)
+    K = w.shape[0]
+    bch = jnp.einsum("bld,de->ble", x.astype(cd), p["w_in"].astype(cd))
+    b, c, h = jnp.split(bch, 3, axis=-1)
+    prev = (jnp.zeros((B, K - 1, D), cd) if state is None
+            else state.astype(cd))
+    xp = jnp.concatenate([prev, b * h], axis=1)  # (B, K-1+L, D)
+    conv = ops.conv1d_causal(xp, w, ctx=ctx)[:, K - 1:]
+    out = jnp.einsum("bld,de->ble", c * conv,
+                     p["w_out"].astype(cd)).astype(x.dtype)
+    if state is None:
+        return out, None
+    # original positions n-K+1 .. n-1 sit at xp[n : n+K-1]
+    n = (jnp.full((B,), L, jnp.int32) if attn_mask is None
+         else jnp.sum(attn_mask, axis=1, dtype=jnp.int32))
+    new = jax.vmap(lambda r, i: jax.lax.dynamic_slice_in_dim(
+        r, i, K - 1, axis=0))(xp, n)
+    return out, new.astype(state.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,9 @@ pass lax.scans over repeats so the lowered HLO contains a single unit
 regardless of depth. KV caches / recurrent states are stacked the same way
 and scanned alongside.
 
-Block kinds: attn | mamba | mlstm | slstm. FFN (SwiGLU or MoE per
-cfg.n_experts/moe_every) follows attn/mamba/slstm positions when d_ff > 0.
+Block kinds: attn | conv (LFM2's gated short conv) | mamba | mlstm | slstm.
+FFN (SwiGLU, or MoE per cfg.n_experts/moe_every/n_dense_layers) follows
+attn/conv/mamba/slstm positions when d_ff > 0.
 """
 
 from __future__ import annotations
@@ -26,7 +27,8 @@ from . import moe as moe_lib
 from . import ssm, xlstm
 from .config import ModelConfig
 from .layers import (attention_block, embed, init_attention, init_embed,
-                     init_mlp, lm_logits, mlp, rms_norm, truncated_normal)
+                     init_mlp, init_short_conv, lm_logits, mlp, rms_norm,
+                     short_conv_block, truncated_normal)
 
 PyTree = Any
 
@@ -37,11 +39,19 @@ PyTree = Any
 
 def _has_ffn(cfg: ModelConfig, pos: int) -> bool:
     kind = cfg.pattern[pos]
-    return cfg.d_ff > 0 and kind in ("attn", "mamba", "slstm")
+    return cfg.d_ff > 0 and kind in ("attn", "conv", "mamba", "slstm")
 
 
 def _is_moe(cfg: ModelConfig, pos: int) -> bool:
-    return _has_ffn(cfg, pos) and cfg.n_experts > 0 and pos % cfg.moe_every == 0
+    return _has_ffn(cfg, pos) and cfg.is_moe_position(pos)
+
+
+def _aux_zero(cfg: ModelConfig) -> jax.Array:
+    """The forward's third output starts here: the load-balancing loss, or
+    a dropless expert layer's counters (``moe.moe_dropless_block``)."""
+    if cfg.moe_dropless:
+        return jnp.zeros((4,), jnp.int32)
+    return jnp.zeros((), jnp.float32)
 
 
 def init_unit(key, cfg: ModelConfig) -> Dict[str, PyTree]:
@@ -54,6 +64,8 @@ def init_unit(key, cfg: ModelConfig) -> Dict[str, PyTree]:
         blk: Dict[str, PyTree] = {"norm1": jnp.ones((cfg.d_model,), dtype)}
         if kind == "attn":
             blk["core"] = init_attention(k_core, cfg)
+        elif kind == "conv":
+            blk["core"] = init_short_conv(k_core, cfg)
         elif kind == "mamba":
             blk["core"] = ssm.init_mamba(k_core, cfg)
         elif kind == "mlstm":
@@ -88,6 +100,11 @@ def init_params(key, cfg: ModelConfig) -> PyTree:
 # Caches / recurrent state
 # ---------------------------------------------------------------------------
 
+# block kinds the paged pool serves: attention's KV in blocks, a short
+# conv's state per slot
+PAGED_KINDS = frozenset({"attn", "conv"})
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> PyTree:
     """Stacked (repeats-leading) cache pytree for decode."""
@@ -102,6 +119,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                     shape = (batch, cfg.n_kv_heads, max_len, cfg.hd)
                     c[f"b{i}"] = {"k": jnp.zeros(shape, dtype),
                                   "v": jnp.zeros(shape, dtype)}
+            elif kind == "conv":
+                c[f"b{i}"] = {"conv": _conv_state(cfg, batch, dtype)}
             elif kind == "mamba":
                 h, tail = ssm.init_mamba_state(cfg, batch, jnp.float32)
                 c[f"b{i}"] = {"h": h, "tail": tail}
@@ -118,14 +137,24 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         lambda a: jnp.broadcast_to(a[None], (cfg.repeats,) + a.shape), one)
 
 
+def _conv_state(cfg: ModelConfig, batch: int, dtype) -> jax.Array:
+    """A gated short conv's state: its last ``K - 1`` inputs per channel."""
+    return jnp.zeros((batch, cfg.conv_kernel - 1, cfg.d_model), dtype)
+
+
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16, quantized: bool = False) -> PyTree:
+                     dtype=jnp.bfloat16, quantized: bool = False,
+                     batch: int = 0, head_dim: Optional[int] = None) -> PyTree:
     """Stacked paged KV pool: every attention layer gets a
     ``(num_blocks, KV, block_size, hd)`` key pool and value pool (stacked to
     (repeats, ...) like ``init_cache``). Physical block 0 is the reserved
     garbage block (``serving.kv.GARBAGE_BLOCK``): dead batch rows point their
-    tables at it. Attention-only patterns — recurrent blocks carry O(1)
-    state and gain nothing from paging.
+    tables at it. Every gated short conv layer gets its per-slot state
+    beside the pool, ``(batch, K - 1, D)``: a conv's state is O(1) a
+    sequence and is not paged; an insert overwrites a slot's state whole,
+    so a freed slot needs no reset. Other recurrent blocks (mamba, xLSTM) have
+    no paged layout. ``head_dim`` is the pool's (``ops.
+    pool_head_dim``): past the model's, heads are zero-padded.
 
     ``quantized=True`` stores the pools as int8 plus per-(block, kv_head,
     position) f32 scale leaves ``ks``/``vs`` of shape (num_blocks, KV,
@@ -133,20 +162,22 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     0.5, which is what roughly doubles ``serving.kv.plan_pool_blocks``'s
     block capacity from the same HBM budget. Scales initialize to 1.0
     (matching the all-zero-row convention of ``quantize_symmetric``)."""
-    if set(cfg.pattern) != {"attn"}:
-        raise ValueError(
-            f"paged cache requires a pure-attention pattern, got {cfg.pattern}")
-    shape = (num_blocks, cfg.n_kv_heads, block_size, cfg.hd)
-    if quantized:
-        one = {f"b{i}": {"kp": jnp.zeros(shape, jnp.int8),
-                         "ks": jnp.ones(shape[:3], jnp.float32),
-                         "vp": jnp.zeros(shape, jnp.int8),
-                         "vs": jnp.ones(shape[:3], jnp.float32)}
-               for i in range(len(cfg.pattern))}
-    else:
-        one = {f"b{i}": {"kp": jnp.zeros(shape, dtype),
-                         "vp": jnp.zeros(shape, dtype)}
-               for i in range(len(cfg.pattern))}
+    if not set(cfg.pattern) <= PAGED_KINDS:
+        raise ValueError(f"paged cache serves {sorted(PAGED_KINDS)} blocks, "
+                         f"got {cfg.pattern}")
+    shape = (num_blocks, cfg.n_kv_heads, block_size, head_dim or cfg.hd)
+    one = {}
+    for i, kind in enumerate(cfg.pattern):
+        if kind == "conv":
+            one[f"b{i}"] = {"conv": _conv_state(cfg, batch, dtype)}
+        elif quantized:
+            one[f"b{i}"] = {"kp": jnp.zeros(shape, jnp.int8),
+                            "ks": jnp.ones(shape[:3], jnp.float32),
+                            "vp": jnp.zeros(shape, jnp.int8),
+                            "vs": jnp.ones(shape[:3], jnp.float32)}
+        else:
+            one[f"b{i}"] = {"kp": jnp.zeros(shape, dtype),
+                            "vp": jnp.zeros(shape, dtype)}
     return jax.tree.map(
         lambda a: jnp.broadcast_to(a[None], (cfg.repeats,) + a.shape), one)
 
@@ -196,9 +227,11 @@ def cache_footprint_words(cfg: ModelConfig, max_len: int,
 
 def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
                   cache_index, n_groups: int, ctx: Optional[ExecutionContext],
-                  decode: bool, attn_mask=None, block_tables=None):
-    """One pattern unit; returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+                  decode: bool, attn_mask=None, block_tables=None,
+                  token_mask=None):
+    """One pattern unit; returns (x, new_cache, aux). ``token_mask`` marks
+    the real tokens for the short conv's state and the dropless experts."""
+    aux = _aux_zero(cfg)
     new_cache: Dict[str, PyTree] = {}
     for i, kind in enumerate(cfg.pattern):
         blk = unit_params[f"b{i}"]
@@ -231,6 +264,12 @@ def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
                 else:
                     new_cache[f"b{i}"] = ({"kv": upd[0]} if cfg.fused_kv_cache
                                           else {"k": upd[0], "v": upd[1]})
+        elif kind == "conv":
+            out, upd = short_conv_block(
+                blk["core"], h, cfg, state=None if bc is None else bc["conv"],
+                attn_mask=token_mask, ctx=ctx)
+            if upd is not None:
+                new_cache[f"b{i}"] = {"conv": upd}
         elif kind == "mamba":
             state = (bc["h"], bc["tail"]) if bc is not None else None
             if decode:
@@ -264,9 +303,14 @@ def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
         x = x + out
         if _has_ffn(cfg, i):
             h = rms_norm(x, blk["norm2"], cfg.norm_eps)
-            if _is_moe(cfg, i):
+            if _is_moe(cfg, i) and cfg.moe_dropless:
+                f, a = moe_lib.moe_dropless_block(blk["ffn"], h, cfg,
+                                                  token_mask=token_mask,
+                                                  ctx=ctx)
+                aux = aux + a
+            elif _is_moe(cfg, i):
                 f, a = moe_lib.moe_block(blk["ffn"], h, cfg,
-                                         n_groups=n_groups, ctx=ctx)
+                                         n_groups=n_groups)
                 aux = aux + a
             else:
                 f = mlp(blk["ffn"], h, jnp.dtype(cfg.compute_dtype))
@@ -291,6 +335,9 @@ def hidden_forward(
     block_tables: Optional[jax.Array] = None,  # (B, w) paged-cache tables
 ) -> Tuple[jax.Array, Optional[PyTree], jax.Array]:
     """Backbone only: returns (final-norm hidden states, new_cache, aux).
+    ``aux`` is the load-balancing loss, or with a dropless expert layer its
+    counters summed over the layers (int32 (4,): pairs routed, pairs on
+    held experts, grouped-matmul rows, held experts with rows).
 
     A ``block_tables`` array marks ``cache`` as a paged pool
     (``init_paged_cache`` layout): decode-only, one query per row, keys
@@ -331,10 +378,14 @@ def hidden_forward(
         return a
 
     x = constrain(x)
+    token_mask = attn_mask
+    if token_mask is None and block_tables is not None:
+        # a free slot's table is all reserved block 0 (serving.kv)
+        token_mask = block_tables[:, :1] != 0
     body_fn = functools.partial(
         _unit_forward, cfg=cfg, positions=positions, cache_index=cache_index,
         n_groups=n_groups, ctx=ctx, decode=decode, attn_mask=attn_mask,
-        block_tables=block_tables)
+        block_tables=block_tables, token_mask=token_mask)
 
     def scan_body(carry, xs):
         x, aux = carry
@@ -348,7 +399,7 @@ def hidden_forward(
             scan_body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
     (x, aux), new_cache = _scan(
-        scan_fn, (x, jnp.zeros((), jnp.float32)),
+        scan_fn, (x, _aux_zero(cfg)),
         (params["layers"], cache) if cache is not None else (params["layers"],
                                                              _none_tree(cfg)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

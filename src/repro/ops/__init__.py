@@ -61,8 +61,10 @@ from .dispatch import (  # noqa: F401
     conv2d_q,
     dispatch_call,
     explain,
+    grouped_matmul,
     matmul,
     matmul_q,
+    pool_head_dim,
     quarantined,
     record_dispatch,
     resolve,

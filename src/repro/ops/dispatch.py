@@ -515,6 +515,19 @@ def conv1d_causal(x, w, ctx: Optional[ExecutionContext] = None):
     return dispatch_call("conv1d_causal", ctx, str(x.dtype), (), (x, w))
 
 
+def grouped_matmul(x, w, group_sizes, ctx: Optional[ExecutionContext] = None):
+    """``out[r] = x[r] @ w[g]`` for the rows ``r`` of group ``g``: ``x`` is
+    (M, K) with rows sorted by group, ``w`` (G, K, N), ``group_sizes`` (G,)
+    int32, each a multiple of ``kernels.grouped_matmul.ROW_TILE``. Rows past
+    ``sum(group_sizes)`` are unspecified; the result is in ``x``'s type,
+    accumulated in float32. The pallas entry streams each group's weights
+    once and skips groups with no rows; the xla entry is
+    ``jax.lax.ragged_dot``."""
+    ctx = default_context() if ctx is None else ctx
+    return dispatch_call("grouped_matmul", ctx, str(x.dtype), (),
+                         (x, w, group_sizes))
+
+
 def attention_needs(q_offset=0, key_mask=None) -> Tuple[str, ...]:
     """Required capability flags of one attention call (shared with tests)."""
     needs = []
@@ -553,10 +566,27 @@ def attention_decode(q, kp, vp, tables, lengths,
 
     The pallas entry follows the tables inside the kernel's index_map (no
     gather copy); the xla entry gathers to a contiguous view first — the
-    measured-words gap between them is the point of the paged subsystem."""
+    measured-words gap between them is the point of the paged subsystem.
+    The pools' heads may be zero-padded past q's ``hd``
+    (:func:`pool_head_dim`); the result and the scores' 1/sqrt(hd) keep
+    q's."""
     ctx = default_context() if ctx is None else ctx
     return dispatch_call("attention_decode", ctx, str(q.dtype), (),
                          (q, kp, vp, tables, lengths))
+
+
+def pool_head_dim(op: str, hd: int,
+                  ctx: Optional[ExecutionContext] = None) -> int:
+    """The head dim of a paged K/V pool that ``op`` reads under ``ctx``:
+    ``hd``, or ``hd`` rounded up to the target's lanes where the chosen
+    entry copies whole pages (``OpEntry.lane_pages``). Heads past ``hd``
+    are zeros."""
+    ctx = default_context() if ctx is None else ctx
+    entry, _ = _resolve_entry(op, ctx, None, ())
+    if not entry.lane_pages:
+        return hd
+    lanes = ctx.target.align_lane
+    return -(-hd // lanes) * lanes
 
 
 def attention_decode_quant(q, kp, ks, vp, vs, tables, lengths,

@@ -58,6 +58,9 @@ from repro.kernels.flash_attention import (attention_blocks,
                                            paged_decode_access_plan,
                                            paged_decode_attention,
                                            paged_decode_hbm_words)
+from repro.kernels.grouped_matmul import (grouped_matmul as _gmm_pallas,
+                                          grouped_matmul_access_plan,
+                                          grouped_matmul_hbm_words)
 from repro.kernels.im2col import (conv2d_im2col, im2col_access_plan,
                                   im2col_hbm_words)
 from repro.kernels.matmul import (_matmul_spec, matmul as _matmul_pallas,
@@ -128,6 +131,11 @@ class OpEntry:
     # (conv2d pallas -> im2col) keeps the degraded decision priced —
     # measured_words/bound_ratio show what the demotion costs.
     degrade_to: Optional[str] = None
+    # paged-pool entries that copy whole pages: each page's last axis (the
+    # head dim) must fill whole lane tiles (Mosaic copies no part of one),
+    # so the pool's heads are zero-padded to the target's lanes
+    # (``ops.pool_head_dim``) and the entry pads q to match
+    lane_pages: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,6 +196,12 @@ def _xla_conv1d(ctx, plan, x, w):
     return ref.conv1d_causal_ref(x, w)
 
 
+def _xla_grouped_matmul(ctx, plan, x, w, group_sizes):
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
 def xla_attention(q, k, v, causal: bool = True, q_offset=0,
                   key_mask=None) -> jax.Array:
     """jnp GQA attention with the grouping kept factored (no KV repeat in HBM).
@@ -234,7 +248,8 @@ def xla_attention_decode(q, kp, vp, tables, lengths) -> jax.Array:
     layout. The gather materializes a copy of the live cache in HBM — exactly
     the traffic the Pallas entry's table-following index_map avoids — but the
     einsums keep the (w, bs) block axes factored, so no transpose/reshape
-    copies follow it."""
+    copies follow it. A pool whose heads are zero-padded past q's head dim
+    is read up to that dim."""
     B, H, Lq, hd = q.shape
     if Lq != 1:
         raise ValueError(f"paged decode expects Lq == 1, got {Lq}")
@@ -242,8 +257,8 @@ def xla_attention_decode(q, kp, vp, tables, lengths) -> jax.Array:
     w = tables.shape[1]
     g = H // KV
     qg = q.reshape(B, KV, g, hd).astype(jnp.float32)
-    kb = kp[tables]  # (B, w, KV, bs, hd)
-    vb = vp[tables]
+    kb = kp[tables][..., :hd]  # (B, w, KV, bs, hd)
+    vb = vp[tables][..., :hd]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     logits = jnp.einsum("bkgd,bwksd->bkgws", qg,
                         kb.astype(jnp.float32)) * scale
@@ -399,6 +414,8 @@ register_backend(Backend(
         "matmul": OpEntry(_xla_matmul, OpCapabilities(dtypes=("*",))),
         "conv2d": OpEntry(_xla_conv2d, OpCapabilities(dtypes=("*",))),
         "conv1d_causal": OpEntry(_xla_conv1d, OpCapabilities(dtypes=("*",))),
+        "grouped_matmul": OpEntry(_xla_grouped_matmul,
+                                  OpCapabilities(dtypes=("*",))),
         "attention": OpEntry(
             _xla_attention_entry,
             OpCapabilities(dtypes=("*",), flags=frozenset(ATTN_FLAGS))),
@@ -534,13 +551,40 @@ def _pallas_attention(ctx, plan, q, k, v, causal=True, q_offset=0,
 def _pallas_attention_decode(ctx, plan, q, kp, vp, tables, lengths):
     """Paged decode on the block-table-gathering kernel; backward recomputes
     through the XLA gather reference (tables/lengths are int32 operands, so
-    their cotangents are float0)."""
+    their cotangents are float0). A pool whose heads are lane-padded past
+    q's (``lane_pages``) runs the kernel at its width, q zero-padded and the
+    scores scaled by q's 1/sqrt(hd)."""
+    hd, hp = q.shape[-1], kp.shape[-1]
+    pad = ((0, 0),) * 3 + ((0, hp - hd),)
+    scale = None if hp == hd else hd ** -0.5
+
     def fwd(q, kp, vp, tables, lengths):
         return paged_decode_attention(q, kp, vp, tables, lengths,
-                                      target=ctx.target)
+                                      target=ctx.target, scale=scale)
 
-    return _with_xla_vjp(fwd, xla_attention_decode, q, kp, vp,
-                         tables, lengths)
+    def xla_fn(q, kp, vp, tables, lengths):
+        return jnp.pad(xla_attention_decode(q[..., :hd], kp, vp, tables,
+                                            lengths), pad)
+
+    if hp == hd:
+        return _with_xla_vjp(fwd, xla_fn, q, kp, vp, tables, lengths)
+    out = _with_xla_vjp(fwd, xla_fn, jnp.pad(q, pad), kp, vp, tables,
+                        lengths)
+    return out[..., :hd]
+
+
+def _pallas_grouped_matmul(ctx, plan, x, w, group_sizes):
+    """No custom_vjp wrapper: the dropless expert layer is the serving path,
+    and nothing differentiates through it."""
+    return _gmm_pallas(x, w, group_sizes, target=ctx.target)
+
+
+def _pallas_grouped_matmul_words(ctx, plan, x, w, group_sizes, **kw):
+    return grouped_matmul_hbm_words(x, w, target=ctx.target)
+
+
+def _pallas_grouped_matmul_access(ctx, plan, x, w, group_sizes, **kw):
+    return grouped_matmul_access_plan(x, w, target=ctx.target)
 
 
 def _pallas_conv2d_q(ctx, plan, x, w, scale, stride=(1, 1),
@@ -605,7 +649,8 @@ def _pallas_attention_words(ctx, plan, q, k, v, **kw):
 
 def _pallas_attention_decode_words(ctx, plan, q, kp, vp, tables, lengths,
                                    **kw):
-    B, H, _, hd = q.shape
+    B, H, _, _ = q.shape
+    hd = kp.shape[-1]  # the kernel runs at the pool's (lane_pages) width
     KV, bs = kp.shape[1], kp.shape[2]
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
@@ -651,7 +696,8 @@ def _pallas_attention_access(ctx, plan, q, k, v, **kw):
 
 def _pallas_attention_decode_access(ctx, plan, q, kp, vp, tables, lengths,
                                     **kw):
-    B, H, _, hd = q.shape
+    B, H, _, _ = q.shape
+    hd = kp.shape[-1]  # the kernel runs at the pool's (lane_pages) width
     KV, bs = kp.shape[1], kp.shape[2]
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
@@ -700,6 +746,12 @@ register_backend(Backend(
         "conv1d_causal": OpEntry(_pallas_conv1d,
                                  words_fn=_pallas_conv1d_words,
                                  access_plan_fn=_pallas_conv1d_access),
+        # rows sorted by expert, groups padded to the row tile: one weight
+        # stream per group with rows (the dropless expert layer)
+        "grouped_matmul": OpEntry(
+            _pallas_grouped_matmul,
+            words_fn=_pallas_grouped_matmul_words,
+            access_plan_fn=_pallas_grouped_matmul_access),
         # flash kernel: dynamic (traced scalar or per-row) q_offset rides the
         # scalar-prefetch path; only key_mask still falls back to masked xla
         # (padded batched prefill), so the decode hot path never leaves pallas.
@@ -714,7 +766,8 @@ register_backend(Backend(
             _pallas_attention_decode,
             spec_fn=_attention_decode_plan_spec,
             words_fn=_pallas_attention_decode_words,
-            access_plan_fn=_pallas_attention_decode_access),
+            access_plan_fn=_pallas_attention_decode_access,
+            lane_pages=True),
         "conv2d_dist": OpEntry(_dist_entry("pallas"),
                                spec_fn=_conv2d_plan_spec,
                                words_fn=_conv2d_dist_words),

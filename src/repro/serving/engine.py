@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import ops
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.ops import ExecutionContext
@@ -149,7 +150,16 @@ class ServeStats:
     ``admit_retries`` and ``numeric_retries`` the prefill and decode
     re-dispatches after a transient fault, and ``failures`` the requests
     that ended early, by cause (``admit``: retries exhausted; ``numeric``:
-    persistent non-finite logits; ``timeout``)."""
+    persistent non-finite logits; ``timeout``).
+
+    A dropless expert layer (``ModelConfig.moe_dropless``) counts its work,
+    summed over its layers, under ``"prefill"`` and ``"decode"``:
+    ``moe_routed`` the (token, expert) pairs chosen over all experts,
+    ``moe_held`` those on held experts, ``moe_rows`` the rows the grouped
+    matmul computes (padding to its row tile included) and ``moe_groups``
+    the held experts with rows, summed over calls (each streams its weights
+    once). Free decode rows and bucket padding route nowhere. The device's
+    counts are read once, when ``serve`` returns."""
 
     steps: int = 0
     row_steps_active: int = 0
@@ -164,6 +174,10 @@ class ServeStats:
     numeric_retries: int = 0
     failures: Dict[str, int] = dataclasses.field(
         default_factory=collections.Counter)
+    moe_routed: Dict[str, int] = dataclasses.field(default_factory=dict)
+    moe_held: Dict[str, int] = dataclasses.field(default_factory=dict)
+    moe_rows: Dict[str, int] = dataclasses.field(default_factory=dict)
+    moe_groups: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -206,18 +220,23 @@ def _make_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext):
         token's logits. Pad junk written into the cache tail is hidden by
         per-row causal masking until decode overwrites it in place."""
         cache = T.init_cache(cfg, 1, max_len)
-        logits, cache, _ = T.forward(params, cfg, tokens=tokens, cache=cache,
-                                     cache_index=jnp.zeros((), jnp.int32),
-                                     attn_mask=attn_mask, ctx=ctx)
-        return jax.lax.dynamic_index_in_dim(logits, last, axis=1,
-                                            keepdims=False), cache
+        logits, cache, aux = T.forward(params, cfg, tokens=tokens,
+                                       cache=cache,
+                                       cache_index=jnp.zeros((), jnp.int32),
+                                       attn_mask=attn_mask, ctx=ctx)
+        logits = jax.lax.dynamic_index_in_dim(logits, last, axis=1,
+                                              keepdims=False)
+        # a dropless expert layer's counters ride along (ServeStats.moe_*)
+        return (logits, cache, aux) if cfg.moe_dropless else (logits, cache)
 
     def insert(pool, row, slot):
         return T.insert_cache_slot(pool, row, slot)
 
     def decode(params, cache, token, index):  # token (B, 1), index (B,)
-        logits, cache, _ = T.forward(params, cfg, tokens=token, cache=cache,
-                                     cache_index=index, decode=True, ctx=ctx)
+        logits, cache, aux = T.forward(params, cfg, tokens=token, cache=cache,
+                                       cache_index=index, decode=True, ctx=ctx)
+        if cfg.moe_dropless:
+            return logits[:, -1], cache, aux
         return logits[:, -1], cache
 
     def sample(logits, base_key, seeds, steps, temps):
@@ -257,29 +276,37 @@ def _make_paged_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext,
     only its landing in the pool and the decode step are paged.
 
     ``insert`` retraces per distinct block count (<= max_len/block_size
-    variants, the same ladder as the bucketed prefills); ``decode`` retraces
-    per distinct table width w (ditto) — positions and table *contents* are
-    data, never trace constants.
+    variants, the same ladder as the bucketed prefills); ``decode`` takes
+    the whole (B, max_len/block_size) tables, so it traces once — positions
+    and table *contents* are data, never trace constants. The Pallas kernel
+    streams each row's live pages alone, whatever the width; the xla
+    gathers read every table slot.
 
     ``quantized`` targets the int8 pool layout: the scatter quantizes each
     (kv_head, position) row symmetrically over hd (matching the decode
     step's ``layers._quantize_kv_row`` write path) and lands the int8 codes
     plus the f32 scales on the pool's kp/ks/vp/vs leaves."""
 
-    def insert(pool, row, blocks):  # row: batch-1 contiguous cache; (nt,) ids
+    def insert(pool, row, blocks, slot=None):
+        """row: batch-1 contiguous cache; blocks: (nt,) ids; slot: the row's
+        slot, for the per-slot states beside the pool (short convs)."""
         nt = blocks.shape[0]
 
-        def block_rows(r):  # r (R, 1, KV, max_len, hd) -> (R, nt, KV, bs, hd)
+        def block_rows(r, hp=None):
+            """r (R, 1, KV, max_len, hd) -> (R, nt, KV, bs, hp), heads
+            zero-padded to the pool's ``hp``."""
             R, _, KV, L, hd = r.shape
+            hp = hp or hd
             rb = r[:, 0, :, :min(nt * block_size, L), :]
-            if nt * block_size > L:  # max_len below a whole block: zero-pad
-                rb = jnp.pad(rb, ((0, 0), (0, 0),
-                                  (0, nt * block_size - L), (0, 0)))
-            return rb.reshape(R, KV, nt, block_size, hd).transpose(
+            # max_len below a whole block, or a lane-padded pool: zero-pad
+            rb = jnp.pad(rb, ((0, 0), (0, 0),
+                              (0, max(0, nt * block_size - L)), (0, hp - hd)))
+            return rb.reshape(R, KV, nt, block_size, hp).transpose(
                 0, 2, 1, 3, 4)
 
-        def scatter(p, r):  # p (R, nb, KV, bs, hd)
-            return p.at[:, blocks].set(block_rows(r).astype(p.dtype))
+        def scatter(p, r):  # p (R, nb, KV, bs, hp)
+            return p.at[:, blocks].set(
+                block_rows(r, p.shape[-1]).astype(p.dtype))
 
         def scatter_q(p, s, r):  # + s (R, nb, KV, bs): per-row f32 scales
             rb = block_rows(r).astype(jnp.float32)
@@ -289,21 +316,26 @@ def _make_paged_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext,
                          127.0).astype(jnp.int8)
             return p.at[:, blocks].set(q), s.at[:, blocks].set(scale)
 
-        if quantized:
-            out = {}
-            for u, leaves in pool.items():
+        out = {}
+        for u, leaves in pool.items():
+            if "conv" in leaves:
+                out[u] = {"conv": leaves["conv"].at[:, slot].set(
+                    row[u]["conv"][:, 0].astype(leaves["conv"].dtype))}
+            elif quantized:
                 kp, ks = scatter_q(leaves["kp"], leaves["ks"], row[u]["k"])
                 vp, vs = scatter_q(leaves["vp"], leaves["vs"], row[u]["v"])
                 out[u] = {"kp": kp, "ks": ks, "vp": vp, "vs": vs}
-            return out
-        return {u: {"kp": scatter(leaves["kp"], row[u]["k"]),
-                    "vp": scatter(leaves["vp"], row[u]["v"])}
-                for u, leaves in pool.items()}
+            else:
+                out[u] = {"kp": scatter(leaves["kp"], row[u]["k"]),
+                          "vp": scatter(leaves["vp"], row[u]["v"])}
+        return out
 
     def decode(params, pool, token, index, tables):  # token (B,1), index (B,)
-        logits, pool, _ = T.forward(params, cfg, tokens=token, cache=pool,
-                                    cache_index=index, decode=True, ctx=ctx,
-                                    block_tables=tables)
+        logits, pool, aux = T.forward(params, cfg, tokens=token, cache=pool,
+                                      cache_index=index, decode=True, ctx=ctx,
+                                      block_tables=tables)
+        if cfg.moe_dropless:
+            return logits[:, -1], pool, aux
         return logits[:, -1], pool
 
     return (jax.jit(insert, donate_argnums=(0,)),
@@ -317,11 +349,13 @@ class Engine:
     model (``plan_batch_size``); ``ctx=None`` builds the execution context
     from ``target`` (backend per the ``repro.ops`` resolution order).
 
-    ``paged`` (default: on for pure-attention models) replaces the per-slot
-    contiguous KV assumption with the ``repro.serving.kv`` block pool:
-    admission *reserves* a request's whole block budget up front (shared
-    prompt-prefix blocks counted once, refcounted), turns pool exhaustion
-    into backpressure (the request waits in queue) instead of an overcommit,
+    ``paged`` (default: on for models of attention and short-conv blocks,
+    ``transformer.PAGED_KINDS``; a short conv's state lives per slot beside
+    the pool) replaces the per-slot contiguous KV assumption with the
+    ``repro.serving.kv`` block pool: admission *reserves* a request's whole
+    block budget up front (shared prompt-prefix blocks counted once,
+    refcounted), turns pool exhaustion into backpressure (the request waits
+    in queue) instead of an overcommit,
     and the decode step reads K/V straight out of the pool through per-row
     block tables (``ops.attention_decode`` — Pallas end-to-end, no
     capability fallback). ``num_blocks=None`` sizes the pool for every slot
@@ -361,12 +395,12 @@ class Engine:
         if ctx is None:
             ctx = ExecutionContext(target=self.target)
         self.ctx = ctx.resolved()
-        attn_only = set(cfg.pattern) == {"attn"}
+        pageable = set(cfg.pattern) <= T.PAGED_KINDS
         if paged is None:
-            paged = attn_only and not cfg.fused_kv_cache
-        elif paged and not attn_only:
+            paged = pageable and not cfg.fused_kv_cache
+        elif paged and not pageable:
             raise ValueError(
-                "paged KV requires a pure-attention pattern; recurrent "
+                f"paged KV serves {sorted(T.PAGED_KINDS)} blocks; recurrent "
                 f"blocks carry O(1) state (pattern={cfg.pattern})")
         elif paged and cfg.fused_kv_cache:
             raise ValueError("paged KV uses split k/v pools; "
@@ -386,10 +420,13 @@ class Engine:
                 block_size=block_size if paged else None)
         self.batch_size = batch_size
         if paged:
+            self.pool_head_dim = ops.pool_head_dim(
+                "attention_decode_quant" if self.kv_quant
+                else "attention_decode", cfg.hd, self.ctx)
             if num_blocks is None:
                 num_blocks = kv.plan_pool_blocks(
                     cfg, max_len, batch_size, block_size, target=self.target,
-                    quantized=self.kv_quant)
+                    quantized=self.kv_quant, head_dim=self.pool_head_dim)
             self.num_blocks = num_blocks
             self._paged_insert, self._paged_decode = _make_paged_steps(
                 cfg, max_len, self.ctx, block_size,
@@ -397,14 +434,16 @@ class Engine:
         if prefill_bucket is None:
             # ragged prompts each jit a prefill per distinct length; rounding
             # lengths up to a bucket bounds that to max_len/bucket traces.
-            # Masked padded prefill is exact only for attention blocks —
+            # Masked padded prefill is exact for attention and short convs
+            # (causal; the conv state is read at the true length) — other
             # recurrent state consumes every position, so those archs stay
             # at exact lengths (one trace per distinct length).
-            prefill_bucket = 16 if set(cfg.pattern) == {"attn"} else 1
-        elif prefill_bucket > 1 and set(cfg.pattern) != {"attn"}:
+            prefill_bucket = 16 if pageable else 1
+        elif prefill_bucket > 1 and not pageable:
             raise ValueError(
-                "prefill_bucket > 1 requires a pure-attention pattern: "
-                "recurrent blocks fold pad tokens into their state")
+                "prefill_bucket > 1 requires attention and short-conv "
+                "blocks: other recurrent blocks fold pad tokens into their "
+                "state")
         self.prefill_bucket = max(1, prefill_bucket)
         (self._prefill, self._insert, self._decode, self._sample) = \
             _make_steps(cfg, max_len, self.ctx)
@@ -437,15 +476,46 @@ class Engine:
         queue: Deque[Tuple[int, Request]] = collections.deque(
             enumerate(requests))
         bs = self.block_size
+        # a dropless expert layer's counters, summed on the device and read
+        # when serve ends
+        moe_counts: Dict[str, jax.Array] = {}
+
+        def counted(out, kind: str):
+            """The step's outputs without the expert counters it returns
+            (dropless MoE), which are added up for ``ServeStats.moe_*``."""
+            if not self.cfg.moe_dropless:
+                return out
+            # the first sum adds device zeros like the counters, so every
+            # sum is the one add program a single request already traces
+            prev = moe_counts.get(kind)
+            moe_counts[kind] = out[-1] + (
+                jnp.zeros_like(out[-1]) if prev is None else prev)
+            return out[:-1]
+
+        def prefill(tokens, mask, last):
+            return counted(self._prefill(self.params, jnp.asarray(tokens),
+                                         jnp.asarray(mask),
+                                         jnp.asarray(last, jnp.int32)),
+                           "prefill")
+
+        def paged_insert(cache, row, blocks, s):
+            # per-slot states beside the pool need the slot; a pure
+            # attention pool does not, and its program takes none
+            slot = (jnp.asarray(s, jnp.int32) if "conv" in self.cfg.pattern
+                    else None)
+            return self._paged_insert(cache, row,
+                                      jnp.asarray(blocks, jnp.int32), slot)
+
         if self.paged:
             cache = T.init_paged_cache(self.cfg, self.num_blocks, bs,
-                                       quantized=self.kv_quant)
+                                       quantized=self.kv_quant, batch=B,
+                                       head_dim=self.pool_head_dim)
             alloc = kv.BlockAllocator(self.num_blocks)
             tables = np.zeros((B, -(-self.max_len // bs)), np.int32)
             slot_blocks: List[List[int]] = [[] for _ in range(B)]
-            # device-side table cache: tables only change at admission/finish,
-            # so most decode steps skip the host->device upload
-            tables_dev: Dict[int, jax.Array] = {}  # width -> device slice
+            # device-side copy of the tables: they only change at
+            # admission/finish, so most decode steps skip the upload
+            tables_dev: List[jax.Array] = []
         else:
             cache = T.init_cache(self.cfg, B, self.max_len)
         slots: List[Optional[_Slot]] = [None] * B
@@ -528,7 +598,8 @@ class Engine:
             bit over every read-visible position."""
             new_alloc = kv.BlockAllocator(self.num_blocks)
             new_cache = T.init_paged_cache(self.cfg, self.num_blocks, bs,
-                                           quantized=self.kv_quant)
+                                           quantized=self.kv_quant, batch=B,
+                                           head_dim=self.pool_head_dim)
             tables_dev.clear()
             for s in range(B):
                 slot = slots[s]
@@ -568,12 +639,9 @@ class Engine:
                 mask[0, :written] = True
                 stats.prefill_real += written
                 stats.prefill_padded += lp
-                _, row = self._prefill(
-                    self.params, jnp.asarray(tokens), jnp.asarray(mask),
-                    jnp.asarray(written - 1, jnp.int32))
+                _, row = prefill(tokens, mask, written - 1)
                 nt = -(-written // bs)
-                new_cache = self._paged_insert(
-                    new_cache, row, jnp.asarray(blocks[:nt], jnp.int32))
+                new_cache = paged_insert(new_cache, row, blocks[:nt], s)
             return new_alloc, new_cache
 
         def admit_prefill(plen: int, prompt: np.ndarray):
@@ -602,10 +670,7 @@ class Engine:
                                                      backend=self.ctx.backend)
                         stats.prefill_real += plen
                         stats.prefill_padded += lp
-                        logits1, row = self._prefill(
-                            self.params, jnp.asarray(tokens),
-                            jnp.asarray(mask),
-                            jnp.asarray(plen - 1, jnp.int32))
+                        logits1, row = prefill(tokens, mask, plen - 1)
                         if camp is not None:
                             num_inj = camp.draw("admit/numeric",
                                                 kinds=("numeric",),
@@ -746,9 +811,8 @@ class Engine:
                                 # K/V: same tokens, positions, params; RoPE
                                 # is applied pre-cache)
                                 nt = -(-plen // bs)
-                                cache = self._paged_insert(
-                                    cache, row, jnp.asarray(
-                                        slot_blocks[s][:nt], jnp.int32))
+                                cache = paged_insert(
+                                    cache, row, slot_blocks[s][:nt], s)
                             else:
                                 cache = self._insert(cache, row, s)
                         with jax.profiler.TraceAnnotation("serve/first_token"):
@@ -782,10 +846,6 @@ class Engine:
             # the same cache positions with the same values, so the numeric
             # retry below can simply re-run it.
             with jax.profiler.TraceAnnotation("serve/step"):
-                if self.paged:
-                    # table width follows the deepest active row; dead rows
-                    # are all-zero (garbage) tables. Shape-driven retrace.
-                    w = max(int(pos[s]) // bs + 1 for s in active)
                 stats.steps += 1
                 stats.row_steps_active += len(active)
                 stats.row_steps_total += B
@@ -800,15 +860,16 @@ class Engine:
                                 -(-(int(i) + 1) // bs) * bs for i in idx)
                             stats.kv_live += sum(int(pos[s]) + 1
                                                  for s in active)
-                            if w not in tables_dev:
-                                tables_dev[w] = jnp.asarray(tables[:, :w])
-                            logits, cache = self._paged_decode(
+                            if not tables_dev:  # dead rows: all block 0
+                                tables_dev.append(jnp.asarray(tables))
+                            logits, cache = counted(self._paged_decode(
                                 self.params, cache, jnp.asarray(tok)[:, None],
-                                jnp.asarray(idx, jnp.int32), tables_dev[w])
+                                jnp.asarray(idx, jnp.int32), tables_dev[0]),
+                                "decode")
                         else:
-                            logits, cache = self._decode(
+                            logits, cache = counted(self._decode(
                                 self.params, cache, jnp.asarray(tok)[:, None],
-                                jnp.asarray(idx, jnp.int32))
+                                jnp.asarray(idx, jnp.int32)), "decode")
                     num_inj = None
                     if camp is not None:
                         num_inj = camp.draw("decode/numeric",
@@ -854,6 +915,10 @@ class Engine:
             # last-round finish is discarded wholesale — the degenerate
             # rebuild — so the accounting never shows a swallowed fault
             camp.resolve_kind("pool", "rebuilt")
+        for kind, tot in moe_counts.items():
+            stats.moe_routed[kind], stats.moe_held[kind], \
+                stats.moe_rows[kind], stats.moe_groups[kind] = \
+                (int(v) for v in np.asarray(tot))
         return requests
 
 

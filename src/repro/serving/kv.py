@@ -213,24 +213,28 @@ class BlockAllocator:
 # ---------------------------------------------------------------------------
 
 def block_words(cfg, block_size: int, dtype_itemsize: int = 2,
-                quantized: bool = False) -> float:
+                quantized: bool = False, head_dim: Optional[int] = None
+                ) -> float:
     """32-bit words one physical block occupies across all attention layers
     (K and V, un-repeated GQA heads). ``quantized`` switches to the int8
     pool layout: one byte per element plus one f32 scale per (kv_head,
     position) row — (1 + 4/hd) bytes per element, vs bf16's 2 — so a
     quantized pool packs ~2x the blocks into the same budget (the
-    ``capacity_gain`` gate in benchmarks/quant_bench.py)."""
+    ``capacity_gain`` gate in benchmarks/quant_bench.py). ``head_dim``: the
+    pool's (``ops.pool_head_dim``), default the model's."""
+    hd = head_dim or cfg.hd
     n_attn = cfg.repeats * sum(1 for kind in cfg.pattern if kind == "attn")
-    elems = n_attn * 2 * cfg.n_kv_heads * block_size * cfg.hd
+    elems = n_attn * 2 * cfg.n_kv_heads * block_size * hd
     if quantized:
-        return elems * (1.0 + 4.0 / cfg.hd) / 4.0
+        return elems * (1.0 + 4.0 / hd) / 4.0
     return elems * dtype_itemsize / 4.0
 
 
 def plan_pool_blocks(cfg, max_len: int, batch_size: int,
                      block_size: int = DEFAULT_BLOCK_SIZE,
                      target=None, hbm_fraction: float = 0.25,
-                     dtype_itemsize: int = 2, quantized: bool = False) -> int:
+                     dtype_itemsize: int = 2, quantized: bool = False,
+                     head_dim: Optional[int] = None) -> int:
     """Pool size in blocks: enough for every slot to hold ``max_len`` tokens
     (plus the reserved garbage block), clamped to ``hbm_fraction`` of the
     target's HBM — but never below one full sequence, mirroring
@@ -242,6 +246,7 @@ def plan_pool_blocks(cfg, max_len: int, batch_size: int,
         return want
     budget = hbm_fraction * target.hbm_words
     cap = 1 + int(budget // max(
-        block_words(cfg, block_size, dtype_itemsize, quantized=quantized),
+        block_words(cfg, block_size, dtype_itemsize, quantized=quantized,
+                    head_dim=head_dim),
         1.0))
     return max(min(want, cap), 1 + per_seq)

@@ -61,6 +61,10 @@ def _op_case(op: str):
                 jax.random.normal(K3, (7, 2, 16, 16)),
                 jnp.asarray([[1, 3, 0], [4, 2, 6]], jnp.int32),
                 jnp.asarray([20, 45], jnp.int32)), {}
+    if op == "grouped_matmul":  # rows sorted by group, every row placed
+        return (jax.random.normal(KEY, (160, 64)),
+                jax.random.normal(K2, (4, 64, 128)),
+                jnp.asarray([32, 0, 64, 64], jnp.int32)), {}
     if op == "matmul_q":  # int8 streams + folded per-column scale
         from repro.quant import quantize_matmul_operands
 
@@ -112,7 +116,8 @@ def test_every_registered_op_is_swept():
     assert set(ops.backends()) == {"xla", "pallas", "im2col"}
     assert set(ops.registered_ops()) == {
         "matmul", "conv2d", "conv1d_causal", "attention", "attention_decode",
-        "attention_decode_quant", "conv2d_q", "matmul_q", "conv2d_dist"}
+        "attention_decode_quant", "conv2d_q", "matmul_q", "conv2d_dist",
+        "grouped_matmul"}
     for op in ops.registered_ops():
         _op_case(op)  # raises if an op was registered without a sweep case
 

@@ -199,7 +199,7 @@ def test_prefill_bucket_exactness_and_guard():
     np.testing.assert_array_equal(exact.out_tokens, bucketed.out_tokens)
 
     _, hybrid = _params_and_cfg("jamba_1_5_large")
-    with pytest.raises(ValueError, match="pure-attention"):
+    with pytest.raises(ValueError, match="attention and short-conv"):
         Engine(hybrid, params, max_len=32, batch_size=1, prefill_bucket=8)
 
 
@@ -258,7 +258,10 @@ def test_paged_matches_contiguous_outputs():
     Engine(cfg, params, max_len=64, batch_size=3, paged=False).serve(a)
     eng = Engine(cfg, params, max_len=64, batch_size=3, paged=True)
     assert eng.paged and eng.num_blocks >= 1 + 64 // eng.block_size
+    traced = eng._paged_decode._cache_size()
     eng.serve(b)
+    # rows of every depth share one decode program: the whole tables
+    assert eng._paged_decode._cache_size() <= traced + 1
     for ra, rb in zip(a, b):
         np.testing.assert_array_equal(ra.out_tokens, rb.out_tokens)
         assert ra.finish_reason == rb.finish_reason
@@ -269,7 +272,7 @@ def test_paged_is_default_only_for_pure_attention():
     assert Engine(cfg, params, max_len=32, batch_size=1).paged
     hp, hybrid = _params_and_cfg("jamba_1_5_large")
     assert not Engine(hybrid, hp, max_len=32, batch_size=1).paged
-    with pytest.raises(ValueError, match="pure-attention"):
+    with pytest.raises(ValueError, match="paged KV serves"):
         Engine(hybrid, hp, max_len=32, batch_size=1, paged=True)
     fused = dataclasses.replace(cfg, fused_kv_cache=True)
     with pytest.raises(ValueError, match="fused"):

@@ -10,6 +10,7 @@ fixture, so collecting this file touches no TPU library; the tests skip
 where no v5e can be described.
 """
 
+import dataclasses
 import functools
 import os
 import sys
@@ -20,12 +21,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import ops
+from repro.configs.lfm2_8b_a1b import CONFIG as LFM2
 from repro.configs.qwen2_5_3b import CONFIG as QWEN
 from repro.configs.resnet50_convs import RESNET50
 from repro.plan import TPU_V5E
 
 # kernel modules that ask repro.plan.pallas_interpret where they run
-KERNEL_MODULES = ("conv2d", "matmul", "flash_attention")
+KERNEL_MODULES = ("conv2d", "matmul", "flash_attention", "conv1d",
+                  "grouped_matmul")
 BF16 = jnp.bfloat16
 
 
@@ -146,3 +149,53 @@ def test_paged_decode_compiles_decode_cell_shape(compile_for_chip):
         ((B, width), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_decode_attention." in hlo
+
+
+# LFM2-8B-A1B as the benchmark serves it: one chip's 8 of 32 experts, 256
+# decode slots, prefills of about 19 tokens, the paged pool's 6,145 blocks.
+LFM2_SHARE = dataclasses.replace(LFM2, expert_shards=4)
+
+
+@pytest.mark.parametrize("tokens,proj", [(256, "gate"), (256, "down"),
+                                         (19, "gate"), (19, "down")])
+def test_grouped_matmul_compiles_lfm2_widths(compile_for_chip, tokens, proj):
+    from repro.models.moe import dropless_rows
+
+    D, F = LFM2.d_model, LFM2.expert_d_ff
+    K, N = (D, F) if proj == "gate" else (F, D)
+    held = LFM2_SHARE.experts_held[1]
+    hlo = compile_for_chip(
+        functools.partial(ops.grouped_matmul, ctx=_ctx("pallas")),
+        ((dropless_rows(tokens, LFM2_SHARE), K), BF16), ((held, K, N), BF16),
+        ((held,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    assert "%grouped_matmul." in hlo
+
+
+def test_paged_decode_compiles_lfm2_widths(compile_for_chip):
+    """8 kv heads of 64, which the pool pads to the 128 lanes a page's DMA
+    needs (``ops.pool_head_dim``); q, the result and the scores'
+    1/sqrt(64) keep the model's head dim."""
+    ctx = _ctx("pallas")
+    hp = ops.pool_head_dim("attention_decode", LFM2.hd, ctx)
+    assert hp == 128
+    B, blocks, bs, width = 256, 6145, 16, 24
+    pool = ((blocks, LFM2.n_kv_heads, bs, hp), BF16)
+    hlo = compile_for_chip(
+        functools.partial(ops.attention_decode, ctx=ctx),
+        ((B, LFM2.n_heads, 1, LFM2.hd), BF16), pool, pool,
+        ((B, width), jnp.int32), ((B,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    assert "%paged_decode_attention." in hlo
+
+
+@pytest.mark.parametrize("rows,length", [(256, 3), (1, 130)],
+                         ids=["decode", "prefill"])
+def test_short_conv_compiles_lfm2_widths(compile_for_chip, rows, length):
+    """The gated short conv's depthwise conv over its state and the new
+    tokens: a decode step (2 + 1 positions) and the longest prefill."""
+    hlo = compile_for_chip(
+        functools.partial(ops.conv1d_causal, ctx=_ctx("pallas")),
+        ((rows, length, LFM2.d_model), BF16),
+        ((LFM2.conv_kernel, LFM2.d_model), BF16))
+    assert "tpu_custom_call" in hlo
