@@ -92,13 +92,15 @@ def sweep(dtype=jnp.bfloat16):
     for name, B, live in SNAPSHOTS:
         w = -(-live // BLOCK)
         q = jax.ShapeDtypeStruct((B, H, 1, hd), dtype)
+        pool = jax.ShapeDtypeStruct((cfg.repeats, num_blocks, KV, BLOCK, hd),
+                                    dtype)
+        row = jax.ShapeDtypeStruct((B, KV, hd), dtype)
         paged = ops.explain(
             "attention_decode", PALLAS,
-            spec_args=(q,
-                       jax.ShapeDtypeStruct((num_blocks, KV, BLOCK, hd), dtype),
-                       jax.ShapeDtypeStruct((num_blocks, KV, BLOCK, hd), dtype),
+            spec_args=(q, pool, pool,
                        jax.ShapeDtypeStruct((B, w), jnp.int32),
-                       jax.ShapeDtypeStruct((B,), jnp.int32)))
+                       jax.ShapeDtypeStruct((B,), jnp.int32),
+                       jax.ShapeDtypeStruct((), jnp.int32), row, row))
         # the contiguous engine streams the whole max_len cache window each
         # step (per-row offsets, pallas-native since this PR)
         contig = ops.explain(

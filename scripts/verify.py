@@ -147,13 +147,15 @@ def sweep_attention(dtype=jnp.bfloat16):
     for name, B, live in SNAPSHOTS:
         w = -(-live // BLOCK)
         qd = jax.ShapeDtypeStruct((B, H, 1, hd), dtype)
+        pool = jax.ShapeDtypeStruct((cfg.repeats, num_blocks, KV, BLOCK, hd),
+                                    dtype)
+        row = jax.ShapeDtypeStruct((B, KV, hd), dtype)
         rows.append(_row(f"attention_decode/{name}", ops.explain(
             "attention_decode", PALLAS,
-            spec_args=(qd,
-                       jax.ShapeDtypeStruct((num_blocks, KV, BLOCK, hd), dtype),
-                       jax.ShapeDtypeStruct((num_blocks, KV, BLOCK, hd), dtype),
+            spec_args=(qd, pool, pool,
                        jax.ShapeDtypeStruct((B, w), jnp.int32),
-                       jax.ShapeDtypeStruct((B,), jnp.int32)),
+                       jax.ShapeDtypeStruct((B,), jnp.int32),
+                       jax.ShapeDtypeStruct((), jnp.int32), row, row),
             audit=True)))
         rows.append(_row(f"attention_contig/{name}", ops.explain(
             "attention", PALLAS,

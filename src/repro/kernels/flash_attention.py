@@ -14,7 +14,7 @@ the wrapper (kv heads are gathered, never materialized repeated in HBM).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -257,27 +257,40 @@ def flash_attention(
 # Paged decode: block-table-gathering attention over the serving KV pool.
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, kn_ref,
+                         vn_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
                          k_buf, v_buf, sems, *, scale: float):
     """One batch row, all its kv heads: the row's ``n = ceil(len / bs)`` live
-    pages are DMA'd by hand in table order into a two-slot VMEM buffer (page
-    i + 1 lands while page i folds) and each (kv head, page) folds into that
-    head's online softmax. Table slots past ``n`` are never read, so dead
-    slots pointing at reserved block 0 move nothing; a row of length 0 moves
-    nothing and stores zeros. Positions past the length inside the last live
-    page are masked."""
+    pages of layer ``layer`` are DMA'd by hand in table order into a
+    two-slot VMEM buffer (page i + 1 lands while page i folds) and each
+    (kv head, page) folds into that head's online softmax. The page that
+    holds position ``len - 1`` takes the step's new k/v row there once it
+    has landed, before it folds, and is copied back whole to its block:
+    the pool is updated in its own buffer. Table slots past ``n`` are never
+    read, so dead slots pointing at reserved block 0 move nothing; a row of
+    length 0 moves and writes nothing and stores zeros. Positions past the
+    length inside the last live page are masked."""
     _, n_kv, g, hd = q_ref.shape
     block_size, w = k_buf.shape[2], tables_ref.shape[1]
-    b = pl.program_id(0)
+    b, layer = pl.program_id(0), layer_ref[0]
     length = lens_ref[b]
     n = jnp.minimum((length + block_size - 1) // block_size, w)
+    last = jnp.where(length > 0, (length - 1) // block_size, -1)
+    off = (length - 1) % block_size
 
     def stream(slot, i):
         page = tables_ref[b, i]
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot],
+        return (pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[slot],
                                       sems.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[slot],
                                       sems.at[slot, 1]))
+
+    def write_back(slot, i):
+        page = tables_ref[b, i]
+        return (pltpu.make_async_copy(k_buf.at[slot], k_out.at[layer, page],
+                                      sems.at[slot, 2]),
+                pltpu.make_async_copy(v_buf.at[slot], v_out.at[layer, page],
+                                      sems.at[slot, 3]))
 
     @pl.when(n > 0)
     def _warmup():
@@ -294,6 +307,15 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for cp in stream(slot, i):
             cp.wait()
+
+        @pl.when(i == last)
+        def _merge():  # the new row into its page, and the page back
+            at = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 1) == off
+            k_buf[slot] = jnp.where(at, kn_ref[0], k_buf[slot])
+            v_buf[slot] = jnp.where(at, vn_ref[0], v_buf[slot])
+            for cp in write_back(slot, i):
+                cp.start()
+
         out = []
         for h, (m_prev, l_prev, acc) in enumerate(carry):
             q = q_ref[0, h].astype(jnp.float32)  # (g, hd)
@@ -311,6 +333,12 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             acc = acc * alpha + jnp.dot(p, v,
                                         preferred_element_type=jnp.float32)
             out.append((m_new, l_new, acc))
+
+        @pl.when(i == last)
+        def _drain():  # the page's buffer is refilled by the next row
+            for cp in write_back(slot, i):
+                cp.wait()
+
         return tuple(out)
 
     init = tuple((jnp.full((g, 1), NEG_INF, jnp.float32),
@@ -323,56 +351,70 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_decode_attention(
     q: jax.Array,        # (B, H, 1, hd) - one new query per sequence
-    kp: jax.Array,       # (num_blocks, KV, block_size, hd) - the key pool
-    vp: jax.Array,       # (num_blocks, KV, block_size, hd) - the value pool
+    kp: jax.Array,       # (layers, num_blocks, KV, block_size, hd) - keys
+    vp: jax.Array,       # (layers, num_blocks, KV, block_size, hd) - values
     tables: jax.Array,   # (B, w) int32 - physical block ids per sequence
     lengths: jax.Array,  # (B,) int32 - valid cache length per sequence
+    layer,               # int32 scalar - the pools' layer this step reads
+    k_new: jax.Array,    # (B, KV, hd) - the step's key row per sequence
+    v_new: jax.Array,    # (B, KV, hd) - the step's value row per sequence
     target: Optional[HardwareTarget] = None,
     scale: Optional[float] = None,  # default 1/sqrt(hd)
-) -> jax.Array:
-    """Decode attention that reads K/V directly out of the paged pool.
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode step against the paged pool of every layer, read and
+    written in place: row b's new k/v land at position ``lengths[b] - 1``
+    of layer ``layer``, and row b then attends over its ``lengths[b]``
+    positions. Returns ``(out, kp, vp)``, the pools aliased to the inputs'
+    buffers, so under a donating jit nothing pool-sized is copied.
 
     The communication-optimal property the paper's decode bound asks for:
     each sequence moves only its own live pages, ``ceil(len / block_size)``
     of them, once — no repeat-materialized GQA heads, no gather copy of the
-    table into a contiguous buffer first, nothing past the row's length.
-    The grid is one step per batch row; query heads are grouped per kv head
-    (h = kv * g + i, matching the registry's GQA fold), so a row's q block
-    is its (KV, g, hd) groups and each fetched page (KV, block_size, hd)
-    serves every group."""
+    table into a contiguous buffer first, nothing past the row's length —
+    plus the one page it writes back. The grid is one step per batch row;
+    query heads are grouped per kv head (h = kv * g + i, matching the
+    registry's GQA fold), so a row's q block is its (KV, g, hd) groups and
+    each fetched page (KV, block_size, hd) serves every group. Live rows
+    write distinct blocks (a shared prefix block is full, so never
+    written); free rows, whose tables point at reserved block 0, all write
+    there."""
     B, H, Lq, hd = q.shape
     if Lq != 1:
         raise ValueError(f"paged decode takes one query per row, got Lq={Lq}")
-    KV, block_size = kp.shape[1], kp.shape[2]
+    KV, block_size = kp.shape[2], kp.shape[3]
     g = H // KV
     params = pltpu.CompilerParams(vmem_limit_bytes=(
         target if target is not None else default_target()).vmem_limit_bytes)
     scale = 1.0 / (hd ** 0.5) if scale is None else scale
-    qf = q.reshape(B, KV, g, hd)
-    out = pl.pallas_call(
+    row = pl.BlockSpec((1, KV, 1, hd), lambda b, *_: (b, 0, 0, 0))
+    group = pl.BlockSpec((1, KV, g, hd), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, kp, vp = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, KV, g, hd), lambda b, t, l: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, KV, g, hd), lambda b, t, l: (b, 0, 0, 0)),
+            in_specs=[group, row, row, hbm, hbm],
+            out_specs=[group, hbm, hbm],
             scratch_shapes=[
                 pltpu.VMEM((2, KV, block_size, hd), kp.dtype),
                 pltpu.VMEM((2, KV, block_size, hd), vp.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2, 4)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
+                   jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
+        # operands: tables, lengths, layer, q, k_new, v_new, kp, vp
+        input_output_aliases={6: 1, 7: 2},
         compiler_params=params,
         interpret=pallas_interpret(),
         name="paged_decode_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, kp, vp)
-    return out.reshape(B, H, 1, hd)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q.reshape(B, KV, g, hd),
+      k_new.reshape(B, KV, 1, hd).astype(kp.dtype),
+      v_new.reshape(B, KV, 1, hd).astype(vp.dtype), kp, vp)
+    return out.reshape(B, H, 1, hd), kp, vp
 
 
 # ---------------------------------------------------------------------------
@@ -453,18 +495,22 @@ def flash_attention_access_plan(BH: int, Lq: int, Lk: int, dh: int,
 
 def paged_decode_access_plan(B: int, KV: int, g: int, w: int,
                              block_size: int, hd: int, num_blocks: int,
-                             p_q: float = 1.0, p_kv: float = 1.0,
-                             p_o: float = 1.0, tables=None,
-                             op: str = "attention_decode"):
+                             layers: int = 1, p_q: float = 1.0,
+                             p_kv: float = 1.0, p_o: float = 1.0,
+                             tables=None, op: str = "attention_decode"):
     """The :class:`repro.verify.access.KernelAccessPlan` of one paged decode
-    launch: grid ``(B,)``, each row's q groups in and out as blocks, and its
-    page stream as ``w`` manual copies of ``(KV, block_size, hd)`` per row
-    into the two-slot buffer, whose ping-pong ``dma`` declares. The stream
-    is declared at full width, every row walking all ``w`` table slots: an
-    upper bound, since a row reads only its ``ceil(len / block_size)`` live
-    pages, and the words ``paged_decode_hbm_words`` charges. Manual copies
-    elide no repeat, so any table moves these words; ``tables`` defaults to
-    the allocator's normal all-distinct consecutive blocks."""
+    launch: grid ``(B,)``, each row's q groups and new k/v rows in and its
+    out groups out as blocks, its page stream as ``w`` manual copies of
+    ``(KV, block_size, hd)`` per row into the two-slot buffer, and the one
+    page per pool that takes the new row and is copied back, which the
+    ``dma`` schedule declares after that page's wait. The stream is declared
+    at full width, every row walking all ``w`` table slots and writing the
+    last: an upper bound, since a row reads only its ``ceil(len /
+    block_size)`` live pages, and the words ``paged_decode_hbm_words``
+    charges. Any layer of the stacked pools moves the same words; layer 0
+    is declared. Manual copies elide no repeat, so any table moves these
+    words; ``tables`` defaults to the allocator's normal all-distinct
+    consecutive blocks."""
     import numpy as np
 
     from repro.verify.access import (BlockAccess, FlatAccess,
@@ -476,39 +522,58 @@ def paged_decode_access_plan(B: int, KV: int, g: int, w: int,
         tables = (np.arange(B * w, dtype=np.int64).reshape(B, w)
                   % max(num_blocks, 1))
     t = np.asarray(tables, dtype=np.int64)
-    pool = (num_blocks, KV, block_size, hd)
+    pool = (layers, num_blocks, KV, block_size, hd)
 
     def page(b, i):  # the copy of table slot i: the row's i-th page, whole
-        return ((t[b, i], 1), (0, KV), (0, block_size), (0, hd))
+        return ((0, 1), (t[b, i], 1), (0, KV), (0, block_size), (0, hd))
 
     def needs(b, i):  # positions i*bs .. (i+1)*bs of row b live in t[b, i]
-        return ((t[b, i], t[b, i] + 1), (0, KV), (0, block_size), (0, hd))
+        return ((0, 1), (t[b, i], t[b, i] + 1), (0, KV), (0, block_size),
+                (0, hd))
+
+    def back(b):  # the page holding the full-width row's last position
+        return page(b, w - 1)
+
+    def written(b):  # that position, w * bs - 1, whose page is t[b, w - 1]
+        return ((0, 1), (t[b, w - 1], t[b, w - 1] + 1), (0, KV),
+                (block_size - 1, block_size), (0, hd))
+
+    def rows(name, kind, shape, word):
+        return BlockAccess(name=name, kind=kind, block_shape=(1,) + shape[1:],
+                           array_shape=shape, word_size=word,
+                           index_map=lambda b: (b, 0, 0, 0))
 
     accesses = (
-        BlockAccess(name="q", kind="load", block_shape=(1, KV, g, hd),
-                    array_shape=(B, KV, g, hd), word_size=p_q,
-                    index_map=lambda b: (b, 0, 0, 0)),
+        rows("q", "load", (B, KV, g, hd), p_q),
+        rows("k_new", "load", (B, KV, 1, hd), p_kv),
+        rows("v_new", "load", (B, KV, 1, hd), p_kv),
         WindowAccess(name="k_pool", kind="load", window=page, requires=needs,
                      array_shape=pool, word_size=p_kv, copies=w),
         WindowAccess(name="v_pool", kind="load", window=page, requires=needs,
                      array_shape=pool, word_size=p_kv, copies=w),
-        BlockAccess(name="out", kind="store", block_shape=(1, KV, g, hd),
-                    array_shape=(B, KV, g, hd), word_size=p_o,
-                    index_map=lambda b: (b, 0, 0, 0)),
+        WindowAccess(name="k_page_back", kind="store", window=back,
+                     requires=written, array_shape=pool, word_size=p_kv),
+        WindowAccess(name="v_page_back", kind="store", window=back,
+                     requires=written, array_shape=pool, word_size=p_kv),
+        rows("out", "store", (B, KV, g, hd), p_o),
         FlatAccess(name="tables", kind="load", words=float(B * w),
                    note="int32 scalar prefetch, charged by words_fn"),
         FlatAccess(name="lengths", kind="load", words=float(B),
+                   note="int32 scalar prefetch, charged by words_fn"),
+        FlatAccess(name="layer", kind="load", words=1.0,
                    note="int32 scalar prefetch, charged by words_fn"),
     )
     scratch = (
         ScratchAlloc("m/l/acc_f32", float(KV * (g + g + g * hd))),
         ScratchAlloc("q_pipeline[2]", 2 * KV * g * hd * p_q),
+        ScratchAlloc("kv_row_pipeline[2x2]", 4 * KV * hd * p_kv),
         ScratchAlloc("kv_pages[2x2]", 4 * KV * block_size * hd * p_kv),
         ScratchAlloc("out_pipeline[2]", 2 * KV * g * hd * p_o),
     )
     return KernelAccessPlan(
         op=op, grid=(B,), accesses=accesses, scratch=scratch,
-        dma=double_buffered_schedule(w, n_slots=2, name="k/v page stream"),
+        dma=double_buffered_schedule(w, n_slots=2, name="k/v page stream",
+                                     write_back=True),
         note="page schedule repeats per row, cut at the row's live pages")
 
 
@@ -516,11 +581,15 @@ def paged_decode_hbm_words(B: int, KV: int, g: int, w: int, block_size: int,
                            hd: int, p_q: float = 1.0, p_kv: float = 1.0,
                            p_o: float = 1.0) -> float:
     """Words one paged decode step moves at most: each row loads its
-    (KV, g, hd) query groups, streams w pages of k and v once, stores the
-    groups — plus the int32 block tables and lengths (1 word each). An upper
-    bound: a row reads only its ``ceil(len / block_size)`` live pages, which
-    lengths known only on the device decide."""
+    (KV, g, hd) query groups and its new k and v rows, streams w pages of k
+    and v once, copies the page that took the new rows back to each pool,
+    stores the groups — plus the int32 block tables, lengths and layer
+    (1 word each). An upper bound: a row reads only its ``ceil(len /
+    block_size)`` live pages, which lengths known only on the device
+    decide."""
     return (p_q * B * KV * g * hd
+            + 2.0 * p_kv * B * KV * hd
             + 2.0 * p_kv * B * KV * w * block_size * hd
+            + 2.0 * p_kv * B * KV * block_size * hd
             + p_o * B * KV * g * hd
-            + B * w + B)
+            + B * w + B + 1)

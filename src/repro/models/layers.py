@@ -116,15 +116,18 @@ def attention_block(
     ctx: Optional[ExecutionContext] = None,
     attn_mask: Optional[jax.Array] = None,  # (B, L) True = real token
     block_tables: Optional[jax.Array] = None,  # (B, w): paged-pool tables
+    layer: Optional[jax.Array] = None,  # the paged pools' layer index
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     """Returns (out, updated_cache). With a cache, keys/values are written at
     cache_index and attention runs over the full cache (decode/prefill).
 
-    ``block_tables`` switches the cache to the paged layout: ``cache`` is the
-    shared ``(num_blocks, KV, block_size, hd)`` k/v pool, row i's keys live in
-    blocks ``block_tables[i]``, and the step is decode-only (L == 1). The new
-    K/V land in physical block ``tables[i, pos // bs]`` at offset ``pos % bs``;
-    dead rows (tables all zero) write reserved garbage block 0.
+    ``block_tables`` switches the cache to the paged layout: ``cache`` holds
+    every layer's shared ``(layers, num_blocks, KV, block_size, hd)`` k/v
+    pools and this block reads ``layer``; row i's keys live in blocks
+    ``block_tables[i]``, and the step is decode-only (L == 1). The decode op
+    writes the new K/V into physical block ``tables[i, pos // bs]`` at offset
+    ``pos % bs`` in place and returns the pools; dead rows (tables all zero)
+    write reserved garbage block 0.
 
     A scalar ``cache_index`` writes all rows at one offset (lockstep prefill /
     wave decode); a ``(B,)`` vector writes row i at ``cache_index[i]`` and
@@ -165,40 +168,15 @@ def attention_block(
     if block_tables is not None:
         if L != 1:
             raise ValueError(f"paged attention is decode-only (L == 1), got L={L}")
-        quantized = len(cache) == 4  # (kp, ks, vp, vs): int8 pool + scales
-        kp, vp = (cache[0], cache[2]) if quantized else cache
-        bs = kp.shape[2]
+        # (kp, vp), or (kp, ks, vp, vs): an int8 pool and its scales
+        decode = (ops.attention_decode_quant if len(cache) == 4
+                  else ops.attention_decode)
         pos = jnp.broadcast_to(idx, (B,))  # per-row depth = write position
-        blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None],
-                                  axis=1)[:, 0]  # (B,) physical block
-        off = pos % bs
-        if quantized:
-            # per-(row, kv_head) symmetric int8 over hd; the scale rides the
-            # pool's (num_blocks, KV, bs) companion leaves
-            ks, vs = cache[1], cache[3]
-            k_new, ks_new = _quantize_kv_row(k[:, :, 0, :])
-            v_new, vs_new = _quantize_kv_row(v[:, :, 0, :])
-            kp = kp.at[blk, :, off].set(k_new)
-            ks = ks.at[blk, :, off].set(ks_new)
-            vp = vp.at[blk, :, off].set(v_new)
-            vs = vs.at[blk, :, off].set(vs_new)
-            o = ops.attention_decode_quant(q.astype(cd), kp, ks, vp, vs,
-                                           block_tables, pos + 1, ctx=ctx)
-            o = o.transpose(0, 2, 1, 3).reshape(B, L, H * hd)
-            out = jnp.einsum("blh,hd->bld", o,
-                             p["wo"].astype(cd)).astype(x.dtype)
-            return out, (kp, ks, vp, vs)
-        hp = kp.shape[-1]  # the pool's heads may be zero-padded past hd
-        if hp != hd:
-            k, v = (jnp.pad(a, ((0, 0),) * 3 + ((0, hp - hd),))
-                    for a in (k, v))
-        kp = kp.at[blk, :, off].set(k[:, :, 0, :].astype(kp.dtype))
-        vp = vp.at[blk, :, off].set(v[:, :, 0, :].astype(vp.dtype))
-        o = ops.attention_decode(q.astype(cd), kp, vp, block_tables, pos + 1,
-                                 ctx=ctx)
+        o, *pool = decode(q.astype(cd), *cache, block_tables, pos + 1, layer,
+                          k[:, :, 0, :], v[:, :, 0, :], ctx=ctx)
         o = o.transpose(0, 2, 1, 3).reshape(B, L, H * hd)
         out = jnp.einsum("blh,hd->bld", o, p["wo"].astype(cd)).astype(x.dtype)
-        return out, (kp, vp)
+        return out, tuple(pool)
     if cache is not None and len(cache) == 1:
         # fused layout: one (B, KV, L, 2, hd) tensor -> a single
         # dynamic-update-slice per step instead of two (§Perf decode variant)
@@ -237,20 +215,6 @@ def attention_block(
     o = o.transpose(0, 2, 1, 3).reshape(B, L, H * hd)
     out = jnp.einsum("blh,hd->bld", o, p["wo"].astype(cd)).astype(x.dtype)
     return out, new_cache
-
-
-def _quantize_kv_row(r: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric int8 quantization of one decode step's (B, KV, hd) k or v
-    row, one scale per (row, kv_head) reduced over hd — the granularity the
-    quantized pool's (num_blocks, KV, bs) scale leaves store. All-zero rows
-    get scale 1.0 (quantize to 0) so dequantization never divides by zero."""
-    qmax = 127.0
-    rf = r.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(rf), axis=-1)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    q = jnp.clip(jnp.round(rf / scale[..., None]), -qmax,
-                 qmax).astype(jnp.int8)
-    return q, scale.astype(jnp.float32)
 
 
 def _expand_key_mask(attn_mask, idx, L: int, Lk: int, cached: bool):

@@ -228,9 +228,11 @@ def cache_footprint_words(cfg: ModelConfig, max_len: int,
 def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
                   cache_index, n_groups: int, ctx: Optional[ExecutionContext],
                   decode: bool, attn_mask=None, block_tables=None,
-                  token_mask=None):
+                  token_mask=None, layer=None):
     """One pattern unit; returns (x, new_cache, aux). ``token_mask`` marks
-    the real tokens for the short conv's state and the dropless experts."""
+    the real tokens for the short conv's state and the dropless experts.
+    With ``block_tables``, ``unit_cache`` is the paged pool of every layer,
+    stacked, and the unit reads and updates its slice ``layer``."""
     aux = _aux_zero(cfg)
     new_cache: Dict[str, PyTree] = {}
     for i, kind in enumerate(cfg.pattern):
@@ -254,7 +256,8 @@ def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
                                        cache=cache, cache_index=cache_index,
                                        ctx=ctx, attn_mask=attn_mask,
                                        block_tables=(block_tables if paged
-                                                     else None))
+                                                     else None),
+                                       layer=layer)
             if upd is not None:
                 if quant_paged:
                     new_cache[f"b{i}"] = {"kp": upd[0], "ks": upd[1],
@@ -265,11 +268,14 @@ def _unit_forward(unit_params, x, cfg: ModelConfig, positions, unit_cache,
                     new_cache[f"b{i}"] = ({"kv": upd[0]} if cfg.fused_kv_cache
                                           else {"k": upd[0], "v": upd[1]})
         elif kind == "conv":
+            state = None if bc is None else bc["conv"]
+            stacked = state is not None and block_tables is not None
             out, upd = short_conv_block(
-                blk["core"], h, cfg, state=None if bc is None else bc["conv"],
+                blk["core"], h, cfg, state=state[layer] if stacked else state,
                 attn_mask=token_mask, ctx=ctx)
             if upd is not None:
-                new_cache[f"b{i}"] = {"conv": upd}
+                new_cache[f"b{i}"] = {"conv": state.at[layer].set(upd)
+                                      if stacked else upd}
         elif kind == "mamba":
             state = (bc["h"], bc["tail"]) if bc is not None else None
             if decode:
@@ -341,7 +347,9 @@ def hidden_forward(
 
     A ``block_tables`` array marks ``cache`` as a paged pool
     (``init_paged_cache`` layout): decode-only, one query per row, keys
-    gathered through the table by the paged attention kernel.
+    gathered through the table by the paged attention kernel. The scan
+    carries that pool whole, with the layer index as its ``xs``, so each
+    layer's step writes the pool in its own buffer.
 
     ``ctx`` is the execution policy (``repro.ops.ExecutionContext``): which
     backend serves each kernel call, planned against which HardwareTarget,
@@ -393,15 +401,29 @@ def hidden_forward(
         x, new_cache, a = body_fn(unit_params, x, unit_cache=unit_cache)
         return (constrain(x), aux + a), new_cache
 
-    scan_fn = scan_body
+    def paged_body(carry, xs):
+        # the pool rides the carry whole: each layer's decode writes its own
+        # slice in place, and no layer's pool is cut out or stacked anew
+        x, aux, pool = carry
+        unit_params, layer = xs
+        x, pool, a = body_fn(unit_params, x, unit_cache=pool, layer=layer)
+        return (constrain(x), aux + a, pool), None
+
+    paged = cache is not None and block_tables is not None
+    scan_fn = paged_body if paged else scan_body
     if remat:
         scan_fn = jax.checkpoint(
-            scan_body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            scan_fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
-    (x, aux), new_cache = _scan(
-        scan_fn, (x, _aux_zero(cfg)),
-        (params["layers"], cache) if cache is not None else (params["layers"],
-                                                             _none_tree(cfg)))
+    if paged:
+        (x, aux, new_cache), _ = _scan(
+            scan_fn, (x, _aux_zero(cfg), cache),
+            (params["layers"], jnp.arange(cfg.repeats, dtype=jnp.int32)))
+    else:
+        (x, aux), new_cache = _scan(
+            scan_fn, (x, _aux_zero(cfg)),
+            (params["layers"], cache) if cache is not None
+            else (params["layers"], _none_tree(cfg)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, (new_cache if cache is not None else None), aux
 

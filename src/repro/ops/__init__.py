@@ -15,7 +15,8 @@ therefore the optimal execution strategy.
     y = ops.attention(q, k, v, ctx=ctx)              # flash kernel, LP blocks
     y = ops.attention(q, k, v, q_offset=idx, ctx=ctx)  # still pallas: traced
                                                        # offsets scalar-prefetch
-    y = ops.attention_decode(q, kp, vp, tables, lens, ctx=ctx)  # paged decode
+    y, kp, vp = ops.attention_decode(q, kp, vp, tables, lens, layer,
+                                     k_new, v_new, ctx=ctx)  # paged decode
     ops.explain("attention", ctx, needs=("key_mask",)).chosen  # -> "xla"
                                                      # (fallback *by capability*)
 

@@ -557,22 +557,26 @@ def attention(q, k, v, causal: bool = True, q_offset=0, key_mask=None,
                                   "key_mask": key_mask})
 
 
-def attention_decode(q, kp, vp, tables, lengths,
+def attention_decode(q, kp, vp, tables, lengths, layer, k_new, v_new,
                      ctx: Optional[ExecutionContext] = None):
-    """One paged decode step: ``q`` is (B, H, 1, hd), ``kp``/``vp`` the
-    shared (num_blocks, KV, block_size, hd) pools, ``tables`` the (B, w)
-    int32 physical-block ids backing each row's logical positions, and
-    ``lengths`` the (B,) valid cache lengths (current token included).
+    """One paged decode step of one layer, updating the pools in place:
+    ``q`` is (B, H, 1, hd); ``kp``/``vp`` the stacked (layers, num_blocks,
+    KV, block_size, hp) pools of every layer; ``tables`` the (B, w) int32
+    physical-block ids backing each row's logical positions; ``lengths``
+    the (B,) valid cache lengths, the current token included; ``layer`` the
+    int32 layer index; ``k_new``/``v_new`` the step's (B, KV, hd) rows.
+    Row b's rows land in ``layer`` at position ``lengths[b] - 1``, then the
+    row attends over its positions. Returns ``(out, kp, vp)``.
 
-    The pallas entry follows the tables inside the kernel's index_map (no
-    gather copy); the xla entry gathers to a contiguous view first — the
-    measured-words gap between them is the point of the paged subsystem.
-    The pools' heads may be zero-padded past q's ``hd``
-    (:func:`pool_head_dim`); the result and the scores' 1/sqrt(hd) keep
-    q's."""
+    The pallas entry streams each row's live pages and copies back only the
+    page it wrote, the pools aliased in place; the xla entry scatters and
+    gathers to a contiguous view first — the measured-words gap between
+    them is the point of the paged subsystem. The pools' heads may be
+    zero-padded past q's ``hd`` (:func:`pool_head_dim`); the result and the
+    scores' 1/sqrt(hd) keep q's."""
     ctx = default_context() if ctx is None else ctx
     return dispatch_call("attention_decode", ctx, str(q.dtype), (),
-                         (q, kp, vp, tables, lengths))
+                         (q, kp, vp, tables, lengths, layer, k_new, v_new))
 
 
 def pool_head_dim(op: str, hd: int,
@@ -589,17 +593,22 @@ def pool_head_dim(op: str, hd: int,
     return -(-hd // lanes) * lanes
 
 
-def attention_decode_quant(q, kp, ks, vp, vs, tables, lengths,
+def attention_decode_quant(q, kp, ks, vp, vs, tables, lengths, layer,
+                           k_new, v_new,
                            ctx: Optional[ExecutionContext] = None):
-    """One paged decode step against an int8-quantized KV pool.
+    """One paged decode step of one layer against int8-quantized pools,
+    updating them as :func:`attention_decode` does.
 
-    ``kp``/``vp`` are the (num_blocks, KV, block_size, hd) int8 pools and
-    ``ks``/``vs`` their (num_blocks, KV, block_size) f32 per-(block, head,
-    position) scales (written together by the engine's quantizing insert).
+    ``kp``/``vp`` are the stacked (layers, num_blocks, KV, block_size, hd)
+    int8 pools and ``ks``/``vs`` their (layers, num_blocks, KV, block_size)
+    f32 per-(block, head, position) scales (written together by the
+    engine's quantizing insert); the step's float rows are quantized per
+    (row, kv head) as they land. Returns ``(out, kp, ks, vp, vs)``.
     Registered on the xla backend only — any requested backend reaches it
     through the fallback chain — since the interesting quantity here is the
     *pool's* halved stream width (the plan's p_F ~ 0.25 + 1/hd), not the
     gather kernel."""
     ctx = default_context() if ctx is None else ctx
     return dispatch_call("attention_decode_quant", ctx, str(q.dtype), (),
-                         (q, kp, ks, vp, vs, tables, lengths))
+                         (q, kp, ks, vp, vs, tables, lengths, layer, k_new,
+                          v_new))

@@ -243,13 +243,13 @@ def _xla_attention_entry(ctx, plan, q, k, v, causal=True, q_offset=0,
                         key_mask=key_mask)
 
 
-def xla_attention_decode(q, kp, vp, tables, lengths) -> jax.Array:
-    """Reference paged decode: gather each row's blocks and attend in block
-    layout. The gather materializes a copy of the live cache in HBM — exactly
-    the traffic the Pallas entry's table-following index_map avoids — but the
-    einsums keep the (w, bs) block axes factored, so no transpose/reshape
-    copies follow it. A pool whose heads are zero-padded past q's head dim
-    is read up to that dim."""
+def _gather_attend(q, kp, vp, tables, lengths) -> jax.Array:
+    """Attend over one layer's (num_blocks, KV, bs, hd) pools: gather each
+    row's blocks and attend in block layout. The gather materializes a copy
+    of the live cache in HBM — exactly the traffic the Pallas kernel's page
+    stream avoids — but the einsums keep the (w, bs) block axes factored,
+    so no transpose/reshape copies follow it. A pool whose heads are
+    zero-padded past q's head dim is read up to that dim."""
     B, H, Lq, hd = q.shape
     if Lq != 1:
         raise ValueError(f"paged decode expects Lq == 1, got {Lq}")
@@ -271,8 +271,44 @@ def xla_attention_decode(q, kp, vp, tables, lengths) -> jax.Array:
     return o.reshape(B, H, 1, hd).astype(q.dtype)
 
 
-def _xla_attention_decode_entry(ctx, plan, q, kp, vp, tables, lengths):
-    return xla_attention_decode(q, kp, vp, tables, lengths)
+def _row_slots(tables, lengths, num_blocks: int, block_size: int):
+    """(block, offset) of each row's position ``lengths - 1``: where the
+    step's new k/v row lands. A row of length 0, or one past its table,
+    gets block ``num_blocks``, which a ``mode="drop"`` scatter skips."""
+    pos = lengths.astype(jnp.int32) - 1
+    page = pos // block_size
+    w = tables.shape[1]
+    blk = jnp.take_along_axis(tables, jnp.clip(page, 0, w - 1)[:, None],
+                              axis=1)[:, 0]
+    return (jnp.where((pos >= 0) & (page < w), blk, num_blocks),
+            pos % block_size)
+
+
+def _put_rows(pool, layer, slots, rows):
+    """``rows`` (B, KV, ...) into ``pool[layer]`` at each row's slot."""
+    blk, off = slots
+    return pool.at[layer, blk, :, off].set(rows.astype(pool.dtype),
+                                           mode="drop")
+
+
+def xla_attention_decode(q, kp, vp, tables, lengths, layer, k_new, v_new
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Reference paged decode step, the Pallas entry's contract: the
+    (B, KV, hd) rows ``k_new``/``v_new`` land in layer ``layer`` of the
+    stacked (layers, num_blocks, KV, bs, hp) pools at each row's position
+    ``lengths - 1``, then each row attends over its ``lengths`` positions
+    of that layer. Returns ``(out, kp, vp)``. The rows are zero-padded to
+    a lane-padded pool's ``hp``."""
+    hp = kp.shape[-1]
+    pad = ((0, 0), (0, 0), (0, hp - k_new.shape[-1]))
+    slots = _row_slots(tables, lengths, kp.shape[1], kp.shape[3])
+    kp = _put_rows(kp, layer, slots, jnp.pad(k_new, pad))
+    vp = _put_rows(vp, layer, slots, jnp.pad(v_new, pad))
+    return _gather_attend(q, kp[layer], vp[layer], tables, lengths), kp, vp
+
+
+def _xla_attention_decode_entry(ctx, plan, *args):
+    return xla_attention_decode(*args)
 
 
 # -- quantized references: integer-exact math in f32, scale applied once ----
@@ -290,24 +326,46 @@ def _xla_matmul_q(ctx, plan, a, b, scale, out_dtype=jnp.bfloat16):
     return (out * scale).astype(out_dtype)
 
 
-def xla_attention_decode_quant(q, kp, ks, vp, vs, tables, lengths
-                               ) -> jax.Array:
-    """Paged decode against an int8-quantized pool: ``kp``/``vp`` are
-    (num_blocks, KV, bs, hd) int8 and ``ks``/``vs`` the matching
-    (num_blocks, KV, bs) f32 per-(block, head, position) scales written by
-    the engine's quantizing insert. Dequantization happens in f32 on the
-    gathered view; the attention math is then exactly
-    :func:`xla_attention_decode`. xla-only: every backend's fallback chain
-    reaches it, and it keeps the quantized pool off the VJP path (decode is
-    inference)."""
-    kf = kp.astype(jnp.float32) * ks[..., None]
-    vf = vp.astype(jnp.float32) * vs[..., None]
-    return xla_attention_decode(q, kf, vf, tables, lengths)
+def _quantize_kv_row(r: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Symmetric int8 quantization of one decode step's (B, KV, hd) k or v
+    row, one scale per (row, kv_head) reduced over hd — the granularity the
+    quantized pool's (num_blocks, KV, bs) scale leaves store. All-zero rows
+    get scale 1.0 (quantize to 0) so dequantization never divides by zero."""
+    qmax = 127.0
+    rf = r.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(rf), axis=-1)
+    scale = jnp.where(amax > 0, amax / qmax, 1.0)
+    q = jnp.clip(jnp.round(rf / scale[..., None]), -qmax,
+                 qmax).astype(jnp.int8)
+    return q, scale.astype(jnp.float32)
 
 
-def _xla_attention_decode_quant_entry(ctx, plan, q, kp, ks, vp, vs, tables,
-                                      lengths):
-    return xla_attention_decode_quant(q, kp, ks, vp, vs, tables, lengths)
+def xla_attention_decode_quant(q, kp, ks, vp, vs, tables, lengths, layer,
+                               k_new, v_new):
+    """Paged decode step against an int8-quantized pool: ``kp``/``vp`` are
+    stacked (layers, num_blocks, KV, bs, hd) int8 and ``ks``/``vs`` the
+    matching (layers, num_blocks, KV, bs) f32 per-(block, head, position)
+    scales, as the engine's quantizing insert writes them. The step's rows
+    are quantized per (row, kv head) over hd and land with their scales at
+    each row's position ``lengths - 1`` of layer ``layer``; dequantization
+    happens in f32 on that layer's view, and the attention math is then
+    exactly :func:`xla_attention_decode`'s. Returns ``(out, kp, ks, vp,
+    vs)``. xla-only: every backend's fallback chain reaches it, and it
+    keeps the quantized pool off the VJP path (decode is inference)."""
+    slots = _row_slots(tables, lengths, kp.shape[1], kp.shape[3])
+    kq, kscale = _quantize_kv_row(k_new)
+    vq, vscale = _quantize_kv_row(v_new)
+    kp = _put_rows(kp, layer, slots, kq)
+    ks = _put_rows(ks, layer, slots, kscale)
+    vp = _put_rows(vp, layer, slots, vq)
+    vs = _put_rows(vs, layer, slots, vscale)
+    kf = kp[layer].astype(jnp.float32) * ks[layer][..., None]
+    vf = vp[layer].astype(jnp.float32) * vs[layer][..., None]
+    return _gather_attend(q, kf, vf, tables, lengths), kp, ks, vp, vs
+
+
+def _xla_attention_decode_quant_entry(ctx, plan, *args):
+    return xla_attention_decode_quant(*args)
 
 
 # -- plan-spec builders (shared by every backend's instrumented entries) ----
@@ -343,12 +401,12 @@ def _matmul_q_plan_spec(a, b, scale=None, out_dtype=jnp.bfloat16, **kw):
 
 
 def _attention_decode_quant_plan_spec(q, kp, ks, vp, vs, tables, lengths,
-                                      **kw):
+                                      *step, **kw):
     """The quantized pool stream priced at its stored width: p_F counts the
     int8 block bytes plus the f32 scale per (head, position) row —
     (0.25 * hd + 1) / hd words per cached element."""
     B, H, _, hd = q.shape
-    KV, bs = kp.shape[1], kp.shape[2]
+    KV, bs = kp.shape[-3], kp.shape[-2]
     w = tables.shape[1]
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = (jnp.dtype(kp.dtype).itemsize / 4.0) + 1.0 / hd
@@ -365,11 +423,11 @@ def _attention_plan_spec(q, k, v, **kw):
                          prec=Precision(p_I=p_io, p_F=p_kv, p_O=p_io))
 
 
-def _attention_decode_plan_spec(q, kp, vp, tables, lengths, **kw):
+def _attention_decode_plan_spec(q, kp, vp, tables, lengths, *step, **kw):
     """Paged decode as an AttentionSpec: Lq = 1, Lk = the table window's
     token capacity (w * block_size) — the keys one decode step streams."""
     B, H, _, hd = q.shape
-    KV, bs = kp.shape[1], kp.shape[2]
+    KV, bs = kp.shape[-3], kp.shape[-2]
     w = tables.shape[1]
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
@@ -548,29 +606,26 @@ def _pallas_attention(ctx, plan, q, k, v, causal=True, q_offset=0,
     return _with_xla_vjp(fwd, xla_fn, q, k, v, row_offs)
 
 
-def _pallas_attention_decode(ctx, plan, q, kp, vp, tables, lengths):
-    """Paged decode on the block-table-gathering kernel; backward recomputes
-    through the XLA gather reference (tables/lengths are int32 operands, so
-    their cotangents are float0). A pool whose heads are lane-padded past
-    q's (``lane_pages``) runs the kernel at its width, q zero-padded and the
-    scores scaled by q's 1/sqrt(hd)."""
+def _pallas_attention_decode(ctx, plan, q, kp, vp, tables, lengths, layer,
+                             k_new, v_new):
+    """Paged decode on the page-streaming kernel, which writes the step's
+    k/v rows into the stacked pools in place. No custom_vjp wrapper: decode
+    is the serving path, and nothing differentiates through a pool update.
+    A pool whose heads are lane-padded past q's (``lane_pages``) runs the
+    kernel at its width, q and the rows zero-padded and the scores scaled
+    by q's 1/sqrt(hd)."""
     hd, hp = q.shape[-1], kp.shape[-1]
-    pad = ((0, 0),) * 3 + ((0, hp - hd),)
-    scale = None if hp == hd else hd ** -0.5
-
-    def fwd(q, kp, vp, tables, lengths):
-        return paged_decode_attention(q, kp, vp, tables, lengths,
-                                      target=ctx.target, scale=scale)
-
-    def xla_fn(q, kp, vp, tables, lengths):
-        return jnp.pad(xla_attention_decode(q[..., :hd], kp, vp, tables,
-                                            lengths), pad)
-
     if hp == hd:
-        return _with_xla_vjp(fwd, xla_fn, q, kp, vp, tables, lengths)
-    out = _with_xla_vjp(fwd, xla_fn, jnp.pad(q, pad), kp, vp, tables,
-                        lengths)
-    return out[..., :hd]
+        return paged_decode_attention(q, kp, vp, tables, lengths, layer,
+                                      k_new, v_new, target=ctx.target)
+
+    def pad(a):
+        return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, hp - hd),))
+
+    out, kp, vp = paged_decode_attention(
+        pad(q), kp, vp, tables, lengths, layer, pad(k_new), pad(v_new),
+        target=ctx.target, scale=hd ** -0.5)
+    return out[..., :hd], kp, vp
 
 
 def _pallas_grouped_matmul(ctx, plan, x, w, group_sizes):
@@ -648,10 +703,10 @@ def _pallas_attention_words(ctx, plan, q, k, v, **kw):
 
 
 def _pallas_attention_decode_words(ctx, plan, q, kp, vp, tables, lengths,
-                                   **kw):
+                                   *step, **kw):
     B, H, _, _ = q.shape
-    hd = kp.shape[-1]  # the kernel runs at the pool's (lane_pages) width
-    KV, bs = kp.shape[1], kp.shape[2]
+    # the kernel runs at the pool's (lane_pages) width
+    KV, bs, hd = kp.shape[-3:]
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
     return paged_decode_hbm_words(B, KV, H // KV, tables.shape[1], bs, hd,
@@ -695,10 +750,10 @@ def _pallas_attention_access(ctx, plan, q, k, v, **kw):
 
 
 def _pallas_attention_decode_access(ctx, plan, q, kp, vp, tables, lengths,
-                                    **kw):
+                                    *step, **kw):
     B, H, _, _ = q.shape
-    hd = kp.shape[-1]  # the kernel runs at the pool's (lane_pages) width
-    KV, bs = kp.shape[1], kp.shape[2]
+    # the kernel runs at the pool's (lane_pages) width
+    layers, num_blocks, KV, bs, hd = kp.shape
     p_io = jnp.dtype(q.dtype).itemsize / 4.0
     p_kv = jnp.dtype(kp.dtype).itemsize / 4.0
     # concrete table values are used when available (explain() passes
@@ -711,8 +766,8 @@ def _pallas_attention_decode_access(ctx, plan, q, kp, vp, tables, lengths,
     except Exception:
         t_np = None
     return paged_decode_access_plan(
-        B, KV, H // KV, tables.shape[1], bs, hd, num_blocks=kp.shape[0],
-        p_q=p_io, p_kv=p_kv, p_o=p_io, tables=t_np)
+        B, KV, H // KV, tables.shape[1], bs, hd, num_blocks=num_blocks,
+        layers=layers, p_q=p_io, p_kv=p_kv, p_o=p_io, tables=t_np)
 
 
 register_backend(Backend(

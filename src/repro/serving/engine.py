@@ -283,9 +283,12 @@ def _make_paged_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext,
     gathers read every table slot.
 
     ``quantized`` targets the int8 pool layout: the scatter quantizes each
-    (kv_head, position) row symmetrically over hd (matching the decode
-    step's ``layers._quantize_kv_row`` write path) and lands the int8 codes
-    plus the f32 scales on the pool's kp/ks/vp/vs leaves."""
+    (kv_head, position) row symmetrically over hd (matching the write of
+    ``ops.attention_decode_quant``) and lands the int8 codes plus the f32
+    scales on the pool's kp/ks/vp/vs leaves.
+
+    ``decode`` donates the pool, and the decode op updates it in place
+    layer by layer: the step copies nothing pool-sized."""
 
     def insert(pool, row, blocks, slot=None):
         """row: batch-1 contiguous cache; blocks: (nt,) ids; slot: the row's

@@ -4,7 +4,9 @@ The manual ``make_async_copy`` pipelines in ``kernels/conv2d.py``,
 ``kernels/matmul.py`` and the paged decode's page loop
 (``kernels/flash_attention.py``) follow one shape: per reduction step
 ``ci`` warm up slot 0 on the first step, prefetch step ``ci+1`` into the
-other slot, wait on ``ci``'s slot, then read it.
+other slot, wait on ``ci``'s slot, then read it. The paged decode also
+writes one landed page back to HBM, the one that takes the step's new
+k/v row, and drains that copy before the slot is refilled.
 :func:`double_buffered_schedule` emits that event stream;
 :func:`check_schedule` simulates it and reports every hazard:
 
@@ -15,8 +17,13 @@ other slot, wait on ``ci``'s slot, then read it.
                            compute still consuming it)
   H4 inflight-read         a copy is in flight into a slot the current
                            grid step reads
-  H5 dangling-start        an in-flight copy is never waited before the
-                           schedule ends
+  H5 dangling-start        an in-flight copy (in or back out) is never
+                           waited before the schedule ends
+  H6 write-back-unlanded   a page is copied back out of a slot before its
+                           own copy in was waited, or with a drain that
+                           matches no write-back
+  H7 refill-under-store    a slot is refilled while its write-back still
+                           reads it
 """
 
 from __future__ import annotations
@@ -25,11 +32,12 @@ import dataclasses
 from typing import List, Optional, Tuple
 
 START, WAIT, READ = "start", "wait", "read"
+STORE, DRAIN = "store", "drain"  # a landed slot's copy back out, its wait
 
 
 @dataclasses.dataclass(frozen=True)
 class DmaEvent:
-    kind: str  # start | wait | read
+    kind: str  # start | wait | read | store | drain
     slot: int
     step: int  # reduction-step payload the event moves/consumes
 
@@ -46,7 +54,7 @@ class DmaSchedule:
 
 @dataclasses.dataclass(frozen=True)
 class Hazard:
-    code: str  # H1..H5
+    code: str  # H1..H7
     event_index: int  # -1 for end-of-schedule hazards
     message: str
 
@@ -55,8 +63,12 @@ class Hazard:
 
 
 def double_buffered_schedule(n_steps: int, n_slots: int = 2,
-                             name: str = "stream") -> DmaSchedule:
-    """The schedule the PR-4 kernels issue across the reduction axis."""
+                             name: str = "stream",
+                             write_back: bool = False) -> DmaSchedule:
+    """The schedule the conv and matmul kernels issue across the reduction
+    axis. ``write_back``: the last step's page, once landed, takes the new
+    row and is copied back out (store), read, and the copy drained — the
+    paged decode's page that holds the row's newest position."""
     ev: List[DmaEvent] = []
     for ci in range(n_steps):
         slot = ci % n_slots
@@ -65,7 +77,12 @@ def double_buffered_schedule(n_steps: int, n_slots: int = 2,
         if ci + 1 < n_steps:
             ev.append(DmaEvent(START, (ci + 1) % n_slots, ci + 1))
         ev.append(DmaEvent(WAIT, slot, ci))
+        back = write_back and ci == n_steps - 1
+        if back:
+            ev.append(DmaEvent(STORE, slot, ci))
         ev.append(DmaEvent(READ, slot, ci))
+        if back:
+            ev.append(DmaEvent(DRAIN, slot, ci))
     return DmaSchedule(n_slots=n_slots, n_steps=n_steps, events=tuple(ev),
                        name=name)
 
@@ -77,6 +94,7 @@ def check_schedule(sched: DmaSchedule) -> List[Hazard]:
     ready: List[Optional[int]] = [None] * n  # step landed in slot
     unread: List[bool] = [False] * n  # landed but not yet consumed
     last_fill: List[Optional[int]] = [None] * n  # step of previous fill
+    storing: List[Optional[int]] = [None] * n  # step being copied back out
     hazards: List[Hazard] = []
 
     def bad(code: str, i: int, msg: str) -> None:
@@ -100,6 +118,10 @@ def check_schedule(sched: DmaSchedule) -> List[Hazard]:
                 bad("H3", i, f"slot {ev.slot} reused after "
                              f"{ev.step - last_fill[ev.slot]} steps "
                              f"(< {n} buffers)")
+            if storing[ev.slot] is not None:
+                bad("H7", i, f"start(step {ev.step}) refills slot {ev.slot} "
+                             f"while step {storing[ev.slot]} is still being "
+                             f"copied back out of it")
             inflight[ev.slot] = ev.step
             last_fill[ev.slot] = ev.step
         elif ev.kind == WAIT:
@@ -122,6 +144,16 @@ def check_schedule(sched: DmaSchedule) -> List[Hazard]:
                              f"{'nothing' if ready[ev.slot] is None else f'step {ready[ev.slot]}'}"
                              f" (missing wait)")
             unread[ev.slot] = False
+        elif ev.kind == STORE:
+            if inflight[ev.slot] is not None or ready[ev.slot] != ev.step:
+                bad("H6", i, f"step {ev.step} is copied back out of slot "
+                             f"{ev.slot} before it landed there")
+            storing[ev.slot] = ev.step
+        elif ev.kind == DRAIN:
+            if storing[ev.slot] != ev.step:
+                bad("H6", i, f"drain(step {ev.step}, slot {ev.slot}) "
+                             f"without a matching write-back")
+            storing[ev.slot] = None
         else:  # pragma: no cover - malformed schedule
             bad("H1", i, f"unknown event kind {ev.kind!r}")
     for slot, step in enumerate(inflight):
@@ -129,4 +161,9 @@ def check_schedule(sched: DmaSchedule) -> List[Hazard]:
             hazards.append(Hazard(
                 "H5", -1, f"[{sched.name}] copy of step {step} into slot "
                           f"{slot} never waited before schedule end"))
+    for slot, step in enumerate(storing):
+        if step is not None:
+            hazards.append(Hazard(
+                "H5", -1, f"[{sched.name}] write-back of step {step} out of "
+                          f"slot {slot} never drained before schedule end"))
     return hazards

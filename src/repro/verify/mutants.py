@@ -11,6 +11,9 @@ metadata and asserts the static checks catch it:
   * ``same_slot_prefetch`` prefetches step ci+1 into the slot step ci is
     about to consume — the overlap bug double buffering exists to prevent
     (H2/H3).
+  * ``write_back_before_wait`` copies the paged decode's written page back
+    to HBM before its own copy in has landed — the page would go back
+    stale, the new row over an old page (H6).
   * ``scale_applied_twice`` makes the quantized conv's folded scale vector
     re-fetch on every reduction step instead of once — the classic
     dequantize-in-the-loop bug. Totals move (counted > words_fn), so the
@@ -92,6 +95,18 @@ def same_slot_prefetch() -> Tuple[bool, str]:
     return caught, "; ".join(str(h) for h in found[:2]) or "not detected"
 
 
+def write_back_before_wait() -> Tuple[bool, str]:
+    """Start the paged decode's page write-back ahead of the page's wait."""
+    sched = hz.double_buffered_schedule(4, name="mutant:early-store",
+                                        write_back=True)
+    ev = list(sched.events)
+    store = next(i for i, e in enumerate(ev) if e.kind == hz.STORE)
+    ev.insert(store - 1, ev.pop(store))  # before the WAIT that precedes it
+    found = hz.check_schedule(dataclasses.replace(sched, events=tuple(ev)))
+    caught = any(h.code == "H6" for h in found)
+    return caught, "; ".join(str(h) for h in found[:2]) or "not detected"
+
+
 def scale_applied_twice() -> Tuple[bool, str]:
     """The dequantize-at-every-application bug: the kernel fetches the
     folded scale vector once per application site (per-tap AND at the final
@@ -148,6 +163,7 @@ MUTANTS: Tuple[Tuple[str, Callable[[], Tuple[bool, str]]], ...] = (
     ("halo_off_by_one", halo_off_by_one),
     ("dropped_dma_wait", dropped_dma_wait),
     ("same_slot_prefetch", same_slot_prefetch),
+    ("write_back_before_wait", write_back_before_wait),
     ("scale_applied_twice", scale_applied_twice),
     ("fault_swallowed", fault_swallowed),
 )

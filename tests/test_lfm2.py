@@ -230,21 +230,26 @@ def test_pool_head_dim_pads_to_lanes_for_the_kernel():
 def test_decode_on_a_lane_padded_pool_matches_the_unpadded_pool(backend):
     """A pool whose heads are zero-padded past q's (as the Pallas entry
     needs on a TPU) gives what the unpadded pool gives, on either entry:
-    same scores' scale, result at q's head dim."""
+    same scores' scale, result at q's head dim, the new rows written at
+    the model's head dim with zeros past it."""
     B, H, KV, hd, hp, bs, nb, w = 3, 4, 2, 16, 128, 16, 7, 3
-    ks = jax.random.split(KEY, 3)
+    ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (B, H, 1, hd))
-    kp, vp = (jax.random.normal(k, (nb, KV, bs, hd)) for k in ks[1:])
+    kp, vp = (jax.random.normal(k, (2, nb, KV, bs, hd)) for k in ks[1:3])
+    k_new, v_new = (jax.random.normal(k, (B, KV, hd)) for k in ks[3:])
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [6, 0, 0]], jnp.int32)
     lengths = jnp.asarray([40, 17, 1], jnp.int32)
-    want = ops.attention_decode(q, kp, vp, tables, lengths,
+    step = (tables, lengths, jnp.int32(1), k_new, v_new)
+    want = ops.attention_decode(q, kp, vp, *step,
                                 ctx=ops.ExecutionContext(backend="xla"))
-    pad = ((0, 0),) * 3 + ((0, hp - hd),)
-    got = ops.attention_decode(q, jnp.pad(kp, pad), jnp.pad(vp, pad),
-                               tables, lengths,
+    pad = ((0, 0),) * 4 + ((0, hp - hd),)
+    got = ops.attention_decode(q, jnp.pad(kp, pad), jnp.pad(vp, pad), *step,
                                ctx=ops.ExecutionContext(backend=backend))
-    assert got.shape == (B, H, 1, hd)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    assert got[0].shape == (B, H, 1, hd)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), **TOL)
+    for g, p in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(g[..., :hd]), np.asarray(p))
+        assert not np.asarray(g[..., hd:]).any()
 
 
 def test_one_short_request_compiles_every_program_serving_needs():

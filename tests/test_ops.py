@@ -55,12 +55,15 @@ def _op_case(op: str):
         return (jax.random.normal(KEY, (2, 8, 33, 16)) * 0.3,
                 jax.random.normal(K2, (2, 2, 33, 16)) * 0.3,
                 jax.random.normal(K3, (2, 2, 33, 16))), {"causal": True}
-    if op == "attention_decode":  # paged decode: block-table gather + lengths
+    if op == "attention_decode":  # paged decode: block-table gather +
+        # lengths, the step's rows written into layer 1 of a 2-layer stack
         return (jax.random.normal(KEY, (2, 4, 1, 16)) * 0.3,
-                jax.random.normal(K2, (7, 2, 16, 16)) * 0.3,
-                jax.random.normal(K3, (7, 2, 16, 16)),
+                jax.random.normal(K2, (2, 7, 2, 16, 16)) * 0.3,
+                jax.random.normal(K3, (2, 7, 2, 16, 16)),
                 jnp.asarray([[1, 3, 0], [4, 2, 6]], jnp.int32),
-                jnp.asarray([20, 45], jnp.int32)), {}
+                jnp.asarray([20, 45], jnp.int32), jnp.int32(1),
+                jax.random.normal(KEY, (2, 2, 16)),
+                jax.random.normal(K2, (2, 2, 16))), {}
     if op == "grouped_matmul":  # rows sorted by group, every row placed
         return (jax.random.normal(KEY, (160, 64)),
                 jax.random.normal(K2, (4, 64, 128)),
@@ -78,11 +81,13 @@ def _op_case(op: str):
         w = jax.random.normal(K2, (16, 8, 3, 3))
         return quantize_conv_operands(x, w), {"stride": (1, 1)}
     if op == "attention_decode_quant":  # int8 pools + per-position scales
-        kp, ks = _quantize_pool(jax.random.normal(K2, (7, 2, 16, 16)) * 0.3)
-        vp, vs = _quantize_pool(jax.random.normal(K3, (7, 2, 16, 16)))
+        kp, ks = _quantize_pool(jax.random.normal(K2, (2, 7, 2, 16, 16)) * 0.3)
+        vp, vs = _quantize_pool(jax.random.normal(K3, (2, 7, 2, 16, 16)))
         return (jax.random.normal(KEY, (2, 4, 1, 16)) * 0.3, kp, ks, vp, vs,
                 jnp.asarray([[1, 3, 0], [4, 2, 6]], jnp.int32),
-                jnp.asarray([20, 45], jnp.int32)), {}
+                jnp.asarray([20, 45], jnp.int32), jnp.int32(1),
+                jax.random.normal(KEY, (2, 2, 16)),
+                jax.random.normal(K2, (2, 2, 16))), {}
     if op == "conv2d_dist":  # P=1 grid: the mesh is one device, so the
         # sweep runs on any host; the real multi-device grids live in
         # tests/test_distributed.py under the CI distributed job
@@ -106,10 +111,14 @@ def test_backends_agree(op, backend):
     args, kw = _op_case(op)
     fn = getattr(ops, op)
     ctx = ops.ExecutionContext(target=TPU_V5E, backend=backend)
-    got_x = np.asarray(fn(*args, ctx=XLA, **kw))
-    got_b = np.asarray(fn(*args, ctx=ctx, **kw))
-    np.testing.assert_allclose(got_x, got_b, rtol=2e-3, atol=2e-3,
-                               err_msg=f"xla and {backend} disagree on {op}")
+    got_x = jax.tree.leaves(fn(*args, ctx=XLA, **kw))
+    got_b = jax.tree.leaves(fn(*args, ctx=ctx, **kw))
+    assert len(got_x) == len(got_b)
+    for x, b in zip(got_x, got_b):  # decode returns its updated pools too
+        np.testing.assert_allclose(np.asarray(x), np.asarray(b), rtol=2e-3,
+                                   atol=2e-3,
+                                   err_msg=f"xla and {backend} disagree on "
+                                           f"{op}")
 
 
 def test_every_registered_op_is_swept():
@@ -208,10 +217,13 @@ def test_paged_decode_explain_no_fallback_and_bound(monkeypatch):
     no fallback, measured decode words reported against the Lq=1 attention
     bound; forcing REPRO_BACKEND=xla picks xla as requested (no fallback)."""
     spec = (jax.ShapeDtypeStruct((2, 4, 1, 16), jnp.bfloat16),
-            jax.ShapeDtypeStruct((7, 2, 16, 16), jnp.bfloat16),
-            jax.ShapeDtypeStruct((7, 2, 16, 16), jnp.bfloat16),
+            jax.ShapeDtypeStruct((3, 7, 2, 16, 16), jnp.bfloat16),
+            jax.ShapeDtypeStruct((3, 7, 2, 16, 16), jnp.bfloat16),
             jax.ShapeDtypeStruct((2, 3), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.int32))
+            jax.ShapeDtypeStruct((2,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2, 2, 16), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, 2, 16), jnp.bfloat16))
     dec = ops.explain("attention_decode", PALLAS, spec_args=spec)
     assert dec.chosen == "pallas" and not dec.fell_back
     assert dec.measured_words is not None and dec.plan is not None

@@ -349,6 +349,60 @@ def test_slot_cache_ops_roundtrip():
         np.testing.assert_array_equal(np.asarray(leaf), 0.0)
 
 
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_paged_decode_step_carries_every_layer(kv_dtype):
+    """One paged decode step through the layer scan, the pool of every
+    layer riding its carry (the int8 pool with its scales too), against
+    the contiguous cache that holds the same keys: each layer reads its
+    own pool (the logits agree) and writes the step's row into its own
+    slice, at each row's position, and nowhere else."""
+    params, cfg = _params_and_cfg("qwen2_5_3b")
+    assert cfg.repeats > 1
+    B, bs, nb, w = 3, 16, 12, 3
+    R, KV, hd = cfg.repeats, cfg.n_kv_heads, cfg.hd
+    ks = jax.random.split(KEY, 3)
+    kf, vf = (jax.random.normal(k, (R, nb, KV, bs, hd)) for k in ks[:2])
+    tables = jnp.asarray([[3, 7, 1], [5, 2, 0], [9, 4, 11]], jnp.int32)
+    index = jnp.asarray([37, 16, 40], jnp.int32)  # the step's positions
+    token = jax.random.randint(ks[2], (B, 1), 0, cfg.vocab_size)
+    if kv_dtype == "int8":
+        def quant(x):
+            s = jnp.max(jnp.abs(x), axis=-1) / 127.0
+            return jnp.round(x / s[..., None]).astype(jnp.int8), s
+        (kq, kscale), (vq, vscale) = quant(kf), quant(vf)
+        pool = {"b0": {"kp": kq, "ks": kscale, "vp": vq, "vs": vscale}}
+        kf, vf = kq * kscale[..., None], vq * vscale[..., None]
+    else:
+        pool = {"b0": {"kp": kf, "vp": vf}}
+    # the contiguous cache: each row's blocks in table order
+    contig = {"b0": {n: p[:, tables].transpose(0, 1, 3, 2, 4, 5).reshape(
+        R, B, KV, w * bs, hd) for n, p in (("k", kf), ("v", vf))}}
+    want, want_cache, _ = T.forward(params, cfg, tokens=token, cache=contig,
+                                    cache_index=index, decode=True)
+    got, got_pool, _ = T.forward(params, cfg, tokens=token, cache=pool,
+                                 cache_index=index, decode=True,
+                                 block_tables=tables)
+    tol = 5e-2 if kv_dtype == "int8" else 1e-4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol,
+                               atol=tol)
+    rows = np.arange(B)
+    blk = np.asarray(tables)[rows, np.asarray(index) // bs]
+    off = np.asarray(index) % bs
+    for n in ("k", "v"):
+        p, p0 = got_pool["b0"][f"{n}p"], pool["b0"][f"{n}p"]
+        if kv_dtype == "int8":  # dequantize what the step wrote
+            p = p * got_pool["b0"][f"{n}s"][..., None]
+            p0 = p0 * pool["b0"][f"{n}s"][..., None]
+        p, p0 = np.asarray(p), np.asarray(p0)
+        new = np.asarray(want_cache["b0"][n])[:, rows, :, np.asarray(index)]
+        for layer in range(R):  # (B, KV, hd): each layer's own row
+            np.testing.assert_allclose(p[layer, blk, :, off],
+                                       new[:, layer], rtol=tol, atol=tol)
+        written = np.zeros(p.shape[:4], bool)
+        written[:, blk, :, off] = True
+        np.testing.assert_array_equal(p[~written], p0[~written])
+
+
 def _three_requests():
     return [Request(prompt=np.arange(1, 6, dtype=np.int32), max_new_tokens=4),
             Request(prompt=np.arange(1, 21, dtype=np.int32), max_new_tokens=2),

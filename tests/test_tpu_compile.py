@@ -10,9 +10,11 @@ fixture, so collecting this file touches no TPU library; the tests skip
 where no v5e can be described.
 """
 
+import collections
 import dataclasses
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -51,11 +53,13 @@ def one_chip(topo):
 
 @pytest.fixture
 def compile_for_chip(one_chip, monkeypatch):
-    """``compile_for_chip(fn, *(shape, dtype))`` -> the HLO text of ``fn``
-    compiled for one described v5e. The process runs on the CPU, where the
-    kernels would interpret; the fixture points them at Mosaic instead. The
-    persistent compile cache is off around the compiles: an executable for
-    a described device cannot be read back."""
+    """``compile_for_chip(fn, *args)`` -> the HLO text of ``fn`` compiled
+    for one described v5e, each argument a ``(shape, dtype)`` pair or a
+    pytree of ``jax.ShapeDtypeStruct``. A function jitted already is
+    compiled as it is, with its own donation. The process runs on the CPU,
+    where the kernels would interpret; the fixture points them at Mosaic
+    instead. The persistent compile cache is off around the compiles: an
+    executable for a described device cannot be read back."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     for name in KERNEL_MODULES:
@@ -65,10 +69,15 @@ def compile_for_chip(one_chip, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
 
-    def compile_(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                for s, d in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+    def place(a):
+        if isinstance(a, tuple) and len(a) == 2 and isinstance(a[0], tuple):
+            return jax.ShapeDtypeStruct(*a, sharding=one_chip)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), a)
+
+    def compile_(fn, *args):
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        return jitted.lower(*map(place, args)).compile().as_text()
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", cache_on)
@@ -125,28 +134,32 @@ def test_flash_prefill_compiles_qwen_widths(compile_for_chip):
     assert "%flash_attention." in hlo
 
 
+def _decode_shapes(B, H, KV, hd, hp, blocks, width, layers=2):
+    """The decode op's arguments: q, the stacked pools, tables, lengths,
+    the layer, the step's k/v rows."""
+    pool = ((layers, blocks, KV, 16, hp), BF16)
+    row = ((B, KV, hd), BF16)
+    return (((B, H, 1, hd), BF16), pool, pool, ((B, width), jnp.int32),
+            ((B,), jnp.int32), ((), jnp.int32), row, row)
+
+
 def test_paged_decode_compiles_qwen_widths(compile_for_chip):
     hd = QWEN.d_model // QWEN.n_heads
-    B, blocks, bs, width = 8, 512, 16, 36
-    pool = ((blocks, QWEN.n_kv_heads, bs, hd), BF16)
     hlo = compile_for_chip(
         functools.partial(ops.attention_decode, ctx=_ctx("pallas")),
-        ((B, QWEN.n_heads, 1, hd), BF16), pool, pool,
-        ((B, width), jnp.int32), ((B,), jnp.int32))
+        *_decode_shapes(8, QWEN.n_heads, QWEN.n_kv_heads, hd, hd, 512, 36))
     assert "tpu_custom_call" in hlo
     assert "%paged_decode_attention." in hlo
 
 
 def test_paged_decode_compiles_decode_cell_shape(compile_for_chip):
     """The benchmark's decode cell: 64 slots, its 1,537-block pool and its
-    widest table, 24 blocks."""
+    widest table, 24 blocks, in a stack of 36 layers."""
     hd = QWEN.d_model // QWEN.n_heads
-    B, blocks, bs, width = 64, 1537, 16, 24
-    pool = ((blocks, QWEN.n_kv_heads, bs, hd), BF16)
     hlo = compile_for_chip(
         functools.partial(ops.attention_decode, ctx=_ctx("pallas")),
-        ((B, QWEN.n_heads, 1, hd), BF16), pool, pool,
-        ((B, width), jnp.int32), ((B,), jnp.int32))
+        *_decode_shapes(64, QWEN.n_heads, QWEN.n_kv_heads, hd, hd, 1537, 24,
+                        layers=QWEN.repeats))
     assert "tpu_custom_call" in hlo
     assert "%paged_decode_attention." in hlo
 
@@ -174,19 +187,86 @@ def test_grouped_matmul_compiles_lfm2_widths(compile_for_chip, tokens, proj):
 
 def test_paged_decode_compiles_lfm2_widths(compile_for_chip):
     """8 kv heads of 64, which the pool pads to the 128 lanes a page's DMA
-    needs (``ops.pool_head_dim``); q, the result and the scores'
+    needs (``ops.pool_head_dim``); q, the rows, the result and the scores'
     1/sqrt(64) keep the model's head dim."""
     ctx = _ctx("pallas")
     hp = ops.pool_head_dim("attention_decode", LFM2.hd, ctx)
     assert hp == 128
-    B, blocks, bs, width = 256, 6145, 16, 24
-    pool = ((blocks, LFM2.n_kv_heads, bs, hp), BF16)
     hlo = compile_for_chip(
         functools.partial(ops.attention_decode, ctx=ctx),
-        ((B, LFM2.n_heads, 1, LFM2.hd), BF16), pool, pool,
-        ((B, width), jnp.int32), ((B,), jnp.int32))
+        *_decode_shapes(256, LFM2.n_heads, LFM2.n_kv_heads, LFM2.hd, hp,
+                        6145, 24, layers=1))
     assert "tpu_custom_call" in hlo
     assert "%paged_decode_attention." in hlo
+
+
+# ops that may hold a K/V pool in a decode step: the kernel that updates it
+# in place, and the plumbing that passes a buffer along without copying it
+POOL_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
+                 "custom-call", "bitcast", "opt-barrier"}
+HLO_TYPES = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def _entry_aliases(hlo):
+    """(shape -> parameter numbers of the entry computation, the parameter
+    numbers aliased to an output)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    params = {}
+    for shape, n in re.findall(r"= (\w+\[[\d,]*\])\S* parameter\((\d+)\)",
+                               entry):
+        params.setdefault(shape, set()).add(int(n))
+    header = hlo.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                          header)}
+    return params, aliased
+
+
+@pytest.mark.parametrize("model", ["qwen", "lfm2"])
+def test_paged_decode_step_updates_the_pool_in_place(compile_for_chip,
+                                                     model):
+    """The serving engine's whole decode step, at a cell's slots, pool and
+    widths: no op but the paged kernel writes a K/V pool, so nothing
+    pool-sized is copied, sliced out, relaid or stacked anew, and every
+    leaf of the cache (pools and short-conv states) is aliased from the
+    input the step donates to its output. qwen keeps 2 of its 36 layers (the scan
+    carries the stack); LFM2 keeps layers 0-6, attention at 2 and 6."""
+    from repro.models import transformer as T
+    from repro.serving.engine import _make_paged_steps
+
+    ctx = _ctx("pallas")
+    if model == "qwen":
+        cfg, B, blocks = dataclasses.replace(QWEN, n_layers=2), 64, 1537
+    else:
+        cfg, B, blocks = dataclasses.replace(
+            LFM2_SHARE, n_layers=7, pattern=LFM2.pattern[:7]), 256, 6145
+    hp = ops.pool_head_dim("attention_decode", cfg.hd, ctx)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, blocks, 16, batch=B, head_dim=hp))
+    decode = _make_paged_steps(cfg, 384, ctx, 16)[1]
+    hlo = compile_for_chip(decode, params, pool, ((B, 1), jnp.int32),
+                           ((B,), jnp.int32), ((B, 384 // 16), jnp.int32))
+    assert "%paged_decode_attention." in hlo
+
+    kv = [leaf.shape for leaf in jax.tree.leaves(pool) if leaf.ndim == 5]
+    assert len(kv) == 2 * sum(k == "attn" for k in cfg.pattern)
+    pool_dims = {f"[{','.join(map(str, s))}]" for s in kv}
+    pool_dims |= {f"[{','.join(map(str, s[1:]))}]" for s in kv}
+    writers = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\(", line)
+        if m and m.group(3) not in POOL_PLUMBING and any(
+                d in m.group(2) for d in pool_dims):
+            writers.append(f"{m.group(3)} %{m.group(1)}")
+    assert not writers, writers
+
+    params_by_shape, aliased = _entry_aliases(hlo)
+    leaves = collections.Counter(
+        f"{HLO_TYPES[leaf.dtype.name]}[{','.join(map(str, leaf.shape))}]"
+        for leaf in jax.tree.leaves(pool))
+    for shape, n in leaves.items():  # leaves of one shape: as many aliased
+        assert len(params_by_shape.get(shape, set()) & aliased) >= n, shape
 
 
 @pytest.mark.parametrize("rows,length", [(256, 3), (1, 130)],

@@ -14,7 +14,8 @@ from repro.plan import ConvSpec, MatmulSpec, Planner, TPU_V5E
 from repro.verify import (audit_access_plan, audit_decision,
                           check_schedule, double_buffered_schedule,
                           validate_execution_plan)
-from repro.verify.hazards import DmaEvent, DmaSchedule, READ, START, WAIT
+from repro.verify.hazards import (DRAIN, DmaEvent, DmaSchedule, READ, START,
+                                  STORE, WAIT)
 from repro.verify.lint import lint_file, lint_registry, run_lint
 from repro.verify.mutants import run_seeded_mutants
 
@@ -78,10 +79,13 @@ def test_attention_decode_paged_audit():
     d = ops.explain(
         "attention_decode", PALLAS,
         spec_args=(jax.ShapeDtypeStruct((B, 16, 1, hd), jnp.bfloat16),
-                   jax.ShapeDtypeStruct((nb, KV, BLOCK, hd), jnp.bfloat16),
-                   jax.ShapeDtypeStruct((nb, KV, BLOCK, hd), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((3, nb, KV, BLOCK, hd), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((3, nb, KV, BLOCK, hd), jnp.bfloat16),
                    jax.ShapeDtypeStruct((B, w), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.int32)),
+                   jax.ShapeDtypeStruct((B,), jnp.int32),
+                   jax.ShapeDtypeStruct((), jnp.int32),
+                   jax.ShapeDtypeStruct((B, KV, hd), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((B, KV, hd), jnp.bfloat16)),
         audit=True)
     _assert_exact(d)
 
@@ -90,7 +94,8 @@ def test_paged_decode_page_stream_audit():
     """The page stream as manual copies: a concrete table that shares pages
     between rows (a prefix hit) moves the words_fn's words, since copies
     elide no repeat; a copy one block off the table misses the page the
-    row needs."""
+    row needs, and a write-back one block off misses the position the row
+    writes."""
     import numpy as np
 
     from repro.kernels.flash_attention import (paged_decode_access_plan,
@@ -98,18 +103,27 @@ def test_paged_decode_page_stream_audit():
 
     B, KV, g, w, bs, hd, nb = 3, 2, 4, 3, 16, 32, 12
     tables = np.array([[1, 2, 3], [1, 2, 4], [5, 0, 0]])
-    ap = paged_decode_access_plan(B, KV, g, w, bs, hd, nb, tables=tables)
+    ap = paged_decode_access_plan(B, KV, g, w, bs, hd, nb, layers=2,
+                                  tables=tables)
     report = audit_access_plan(ap)
     assert report.ok, report.problems
     assert report.counted_words == paged_decode_hbm_words(B, KV, g, w, bs,
                                                           hd)
     assert report.per_access["k_pool"] == B * w * KV * bs * hd
+    assert report.per_access["k_page_back"] == B * KV * bs * hd
     off = [dataclasses.replace(
-        a, window=lambda b, i: ((tables[b, i] + 1, 1), (0, KV), (0, bs),
-                                (0, hd)))
+        a, window=lambda b, i: ((0, 1), (tables[b, i] + 1, 1), (0, KV),
+                                (0, bs), (0, hd)))
         if a.name == "k_pool" else a for a in ap.accesses]
     bad = audit_access_plan(dataclasses.replace(ap, accesses=tuple(off)))
     assert any("k_pool" in p and "misses the required" in p
+               for p in bad.problems)
+    off = [dataclasses.replace(
+        a, window=lambda b: ((0, 1), (tables[b, w - 2], 1), (0, KV),
+                             (0, bs), (0, hd)))
+        if a.name == "v_page_back" else a for a in ap.accesses]
+    bad = audit_access_plan(dataclasses.replace(ap, accesses=tuple(off)))
+    assert any("v_page_back" in p and "misses the required" in p
                for p in bad.problems)
 
 
@@ -153,13 +167,42 @@ def test_dangling_start_is_h5():
     assert any(h.code == "H5" for h in check_schedule(sched))
 
 
+WRITE_BACK_FAULTS = {
+    # the page copied back out before its own copy in landed
+    "store_before_wait": ((START, 0, 0), (STORE, 0, 0), (WAIT, 0, 0),
+                          (READ, 0, 0), (DRAIN, 0, 0)),
+    # the write-back never drained
+    "undrained": ((START, 0, 0), (WAIT, 0, 0), (STORE, 0, 0),
+                  (READ, 0, 0)),
+    # the slot refilled while its write-back still reads it
+    "refill_under_store": ((START, 0, 0), (WAIT, 0, 0), (STORE, 0, 0),
+                           (READ, 0, 0), (START, 0, 2), (DRAIN, 0, 0),
+                           (WAIT, 0, 2), (READ, 0, 2)),
+}
+
+
+@pytest.mark.parametrize("fault,code", [("store_before_wait", "H6"),
+                                        ("undrained", "H5"),
+                                        ("refill_under_store", "H7")])
+def test_write_back_hazards(fault, code):
+    """The paged decode's schedule, page written back after its wait and
+    drained before the slot's refill, is clean at any width; each way of
+    breaking that order is flagged."""
+    for n in (1, 2, 7):
+        assert check_schedule(double_buffered_schedule(
+            n, write_back=True)) == []
+    sched = DmaSchedule(n_slots=2, n_steps=3, name="t", events=tuple(
+        DmaEvent(*e) for e in WRITE_BACK_FAULTS[fault]))
+    assert any(h.code == code for h in check_schedule(sched))
+
+
 # ---------------------------------------------------------------------------
 # seeded mutants: the auditor's own regression harness
 # ---------------------------------------------------------------------------
 
 def test_all_seeded_mutants_are_caught():
     results = run_seeded_mutants()
-    assert len(results) == 5
+    assert len(results) == 6
     escaped = [name for name, caught, _ in results if not caught]
     assert not escaped, f"mutants escaped the auditor: {escaped}"
 
