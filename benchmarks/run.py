@@ -12,7 +12,7 @@ Prints ``name,us_per_call,derived`` CSV:
   autotune/* - measured frontier search: tuned vs analytic plan wall time
   dist/*     - measured inter-device words: halo-exchange conv vs all-gather
                vs the Thm 2.2/2.3 bound (live rows need the 8-device mesh)
-  serving/*  - continuous-batching vs wave-lockstep serving throughput
+  serving/*  - paged vs contiguous decode words, shared-prefix pool words
   roofline/* - §Roofline rows from the dry-run artifacts
 
 ``--json`` additionally writes the rows as a machine-readable list of

@@ -1,7 +1,5 @@
-"""Serving benchmark: paged KV-cache decode vs the PR-2 slot-pool engine,
-with measured decode HBM words gated against the paper's attention bound.
-
-Two kinds of rows, mirroring ``conv_bench``:
+"""Serving benchmark: paged KV-cache decode words against the contiguous
+cache's, gated against the paper's attention bound.
 
 **Shape sweep (deterministic, gated).** Decode-state snapshots dispatched
 through ``ops.explain`` with ``jax.ShapeDtypeStruct`` specs under an explicit
@@ -13,19 +11,12 @@ Lq = 1 specialization of Thm 2.1 (``core.bounds.attention_bound``), whose
 memory-independent KV-stream term dominates decode. A pool-occupancy row
 charges a shared prompt prefix once (refcounted blocks) vs per-request.
 
-**Throughput (informational + floor-gated).** The same mixed workload served
-by the wave baseline, the slot-pool engine (``paged=False``), and the paged
-engine; tok/s fields deliberately avoid the ``_words``/``_ratio`` suffixes
-so ``compare.py`` never gates wall-clock noise, but ``main`` enforces a
-paged >= 0.75x slot-pool floor.
-
 CLI (the CI serving gate):
 
     PYTHONPATH=src python -m benchmarks.serving_bench --json BENCH_serving.json
 
 exits nonzero if paged decode moves >= the contiguous words on any snapshot,
-the measured/bound ratio drifts, prefix sharing stops saving pool words, or
-paged tok/s falls below the slot-pool floor.
+the measured/bound ratio drifts, or prefix sharing stops saving pool words.
 """
 
 from __future__ import annotations
@@ -34,8 +25,6 @@ import argparse
 import dataclasses
 import json
 import sys
-import time
-from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -47,20 +36,13 @@ from repro.serving import kv
 
 PALLAS = ops.ExecutionContext(target=TPU_V5E, backend="pallas")
 
-# Two prompt-length buckets keep the prefill jit count small while still
-# exercising mixed depths; heterogeneous output budgets make wave lockstep
-# waste steps on drained rows; four requests share a full-block prefix so
-# the paged engine exercises refcounted sharing in the timed run.
-PROMPT_LENS = (4, 12)
-MAX_NEWS = (8, 56)
-N_REQUESTS = 12
+# four requests share a full-block prompt prefix in the pool-occupancy row
 N_SHARED = 4
 SHARED_PREFIX = 16
 BATCH = 4
 # The serving window: paged decode reads w live blocks per step while the
 # contiguous engine streams the whole max_len window, so the paged win grows
-# with max_len - live_tokens. 512 is past the CPU-smoke crossover (~256)
-# where block-gather graph overhead is repaid by the smaller KV stream.
+# with max_len - live_tokens.
 MAX_LEN = 512
 BLOCK = kv.DEFAULT_BLOCK_SIZE
 
@@ -157,66 +139,6 @@ def sweep(dtype=jnp.bfloat16):
     return records
 
 
-# ---------------------------------------------------------------------------
-# Throughput: wave vs slot-pool vs paged on one mixed workload
-# ---------------------------------------------------------------------------
-
-def _workload(cfg, seed: int = 0) -> List:
-    from repro.serving.engine import Request
-    rng = np.random.default_rng(seed)
-    reqs = []
-    for i in range(N_REQUESTS):
-        plen = PROMPT_LENS[i % len(PROMPT_LENS)]
-        reqs.append(Request(
-            prompt=rng.integers(0, cfg.vocab_size, size=plen,
-                                dtype=np.int64).astype(np.int32),
-            max_new_tokens=MAX_NEWS[i % len(MAX_NEWS)],
-            temperature=0.0))
-    shared = rng.integers(0, cfg.vocab_size, size=SHARED_PREFIX,
-                          dtype=np.int64).astype(np.int32)
-    for i in range(N_SHARED):
-        tail = rng.integers(0, cfg.vocab_size, size=2 + i,
-                            dtype=np.int64).astype(np.int32)
-        reqs.append(Request(prompt=np.concatenate([shared, tail]),
-                            max_new_tokens=8, temperature=0.0))
-    return reqs
-
-
-def _run(mk_engine, cfg, params, seed: int):
-    eng = mk_engine(cfg, params)
-    reqs = _workload(cfg, seed=seed)
-    t0 = time.perf_counter()
-    eng.serve(reqs)
-    dt = time.perf_counter() - t0
-    toks = sum(len(r.out_tokens) for r in reqs)
-    return toks, dt
-
-
-def throughput():
-    from repro.models import transformer as T
-    from repro.serving.engine import Engine, WaveEngine
-
-    cfg = _smoke_cfg()
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    mks = {
-        "wave": lambda c, p: WaveEngine(c, p, max_len=MAX_LEN,
-                                        batch_size=BATCH, paged=False),
-        "slotpool": lambda c, p: Engine(c, p, max_len=MAX_LEN,
-                                        batch_size=BATCH, paged=False),
-        "paged": lambda c, p: Engine(c, p, max_len=MAX_LEN,
-                                     batch_size=BATCH, paged=True),
-    }
-    out = {}
-    for name, mk in mks.items():
-        _run(mk, cfg, params, seed=1)  # warmup: jit ladder incl. table widths
-        # best-of-3: the engines run identical tokens every repeat, so min
-        # wall clock is the scheduling cost with the least OS noise
-        toks, dt = min((_run(mk, cfg, params, seed=0) for _ in range(3)),
-                       key=lambda td: td[1])
-        out[name] = (toks, dt, toks / dt)
-    return out
-
-
 def run(csv_rows: list) -> None:
     for r in sweep():
         if r["name"].startswith("decode/"):
@@ -232,21 +154,12 @@ def run(csv_rows: list) -> None:
                 f"shared={r['shared_pool_words']:.3e}w "
                 f"naive={r['naive_pool_words']:.3e}w "
                 f"({r['shared_over_naive_ratio']:.2f}x)"))
-    tp = throughput()
-    for name, (toks, dt, tps) in tp.items():
-        csv_rows.append((f"serving/{name}", f"{dt * 1e6:.0f}",
-                         f"tok_s={tps:.1f} tokens={toks}"))
-    csv_rows.append(("serving/speedup", "0",
-                     f"paged_over_slotpool={tp['paged'][2] / tp['slotpool'][2]:.2f}x "
-                     f"continuous_over_wave={tp['slotpool'][2] / tp['wave'][2]:.2f}x"))
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default="BENCH_serving.json", metavar="PATH",
-                    help="write sweep + throughput records to PATH")
-    ap.add_argument("--skip-throughput", action="store_true",
-                    help="shape sweep only (no model execution)")
+                    help="write the sweep's records to PATH")
     args = ap.parse_args(argv)
     records = sweep()
     bad = []
@@ -267,19 +180,6 @@ def main(argv=None) -> int:
                   f"naive={r['naive_pool_words']:.3e}w")
             if r["shared_pool_words"] >= r["naive_pool_words"]:
                 bad.append(f"{r['name']}: prefix sharing saves no pool words")
-    if not args.skip_throughput:
-        tp = throughput()
-        rec = {"name": "throughput/mixed"}
-        for name, (toks, dt, tps) in tp.items():
-            print(f"throughput/{name:9s} tok_s={tps:.1f} tokens={toks}")
-            rec[f"tok_s_{name}"] = tps
-            rec[f"tokens_{name}"] = toks
-        rec["paged_speedup"] = tp["paged"][2] / tp["slotpool"][2]
-        records.append(rec)
-        # a floor, not a compare.py metric: wall clock is noisy on shared CI
-        if tp["paged"][2] < 0.75 * tp["slotpool"][2]:
-            bad.append(f"throughput: paged tok/s {tp['paged'][2]:.1f} below "
-                       f"0.75x slot-pool {tp['slotpool'][2]:.1f}")
     with open(args.json, "w") as f:
         json.dump(records, f, indent=1)
     print(f"wrote {len(records)} records to {args.json}")

@@ -4,12 +4,9 @@ checkpointed) model.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2_5_3b --smoke \
         --requests 8 --prompt-len 16 --max-new 32
 
-``--engine wave`` runs the wave-lockstep baseline scheduler instead (same
-primitives, admission barriers until the whole batch drains) for A/B
-comparison. ``--batch 0`` sizes the slot pool from the hardware target's
-memory model. Reported tok/s counts only tokens that were actually
-generated (EOS / cache-limit truncation shortens ``out_tokens``; nothing is
-zero-padded).
+``--batch 0`` sizes the slot pool from the hardware target's memory model.
+Reported tok/s counts only tokens that were actually generated (EOS /
+cache-limit truncation shortens ``out_tokens``; nothing is zero-padded).
 """
 
 from __future__ import annotations
@@ -27,7 +24,7 @@ import numpy as np
 
 def build_engine(arch: str, *, smoke: bool = False,
                  ckpt_dir: Optional[str] = None, max_len: int = 256,
-                 batch: Optional[int] = 4, engine: str = "slot",
+                 batch: Optional[int] = 4,
                  target: Optional[str] = None, backend: Optional[str] = None,
                  seed: int = 0):
     """``(cfg, engine)`` for ``arch``: the smoke config, or the published
@@ -40,7 +37,7 @@ def build_engine(arch: str, *, smoke: bool = False,
     from repro.models import transformer as T
     from repro.ops import ExecutionContext
     from repro.plan import default_target, get_target
-    from repro.serving.engine import Engine, WaveEngine
+    from repro.serving.engine import Engine
     from repro.train import checkpoint as ckpt
 
     cfg = get_smoke(arch) if smoke else get_config(arch)
@@ -53,9 +50,8 @@ def build_engine(arch: str, *, smoke: bool = False,
         restored, _ = ckpt.restore(ckpt_dir, {"params": params})
         params = restored["params"]
     tgt = get_target(target) if target else default_target()
-    cls = WaveEngine if engine == "wave" else Engine
-    eng = cls(cfg, params, max_len=max_len, batch_size=batch or None,
-              target=tgt, ctx=ExecutionContext(target=tgt, backend=backend))
+    eng = Engine(cfg, params, max_len=max_len, batch_size=batch or None,
+                 target=tgt, ctx=ExecutionContext(target=tgt, backend=backend))
     return cfg, eng
 
 
@@ -116,8 +112,6 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--stop-token", type=int, default=None,
                     help="token id that ends a request (EOS)")
-    ap.add_argument("--engine", choices=("slot", "wave"), default="slot",
-                    help="continuous batching (slot) or the wave baseline")
     ap.add_argument("--target", default=None,
                     help="hardware target preset (tpu_v5e | gemmini | "
                          "cpu_interpret); sets plan/precision policy and "
@@ -137,7 +131,7 @@ def main():
     use_compile_cache()
     cfg, eng = build_engine(args.arch, smoke=args.smoke,
                             ckpt_dir=args.ckpt_dir, max_len=args.max_len,
-                            batch=args.batch, engine=args.engine,
+                            batch=args.batch,
                             target=args.target, backend=args.backend)
     stop = () if args.stop_token is None else (args.stop_token,)
     reqs = make_requests(cfg, args.requests, (2, args.prompt_len),
@@ -148,7 +142,7 @@ def main():
     dt = time.time() - t0
     total_new, reasons = summarize(reqs)
     ok = len(reqs) - reasons.get("error", 0) - reasons.get("timeout", 0)
-    print(f"[{args.engine}] served {len(reqs)} requests "
+    print(f"served {len(reqs)} requests "
           f"(batch={eng.batch_size}), {total_new} generated tokens in "
           f"{dt:.2f}s ({total_new / dt:.1f} tok/s); "
           f"completed={ok}/{len(reqs)} finish={reasons}")

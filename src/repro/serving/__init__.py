@@ -2,7 +2,6 @@ from .engine import (  # noqa: F401
     Engine,
     Request,
     ServeStats,
-    WaveEngine,
     plan_batch_size,
 )
 from .kv import (  # noqa: F401

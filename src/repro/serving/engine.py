@@ -6,7 +6,8 @@ request finishes its slot is refilled from the queue mid-flight
 (prefill-into-slot) instead of barriering until the whole batch drains.
 This is the decode-axis analogue of the paper's processor-utilization
 argument for distributed convolutions: never let a fast processor idle on
-the slowest one's critical path.
+the slowest one's critical path. The KV cache sits behind one store
+(``serving.kv``, paged or contiguous), chosen once per engine.
 
 Scheduling contract:
   * admission: a queued request is prefilled alone at its exact prompt
@@ -24,7 +25,7 @@ Scheduling contract:
     overwritten at the next admission.
   * accounting: per-request EOS/stop tokens, ``max_new_tokens``, and the
     cache-capacity budget are tracked per slot; ``out_tokens`` holds ONLY
-    tokens that were really generated (the old wave engine zero-padded).
+    tokens that were really generated.
 
 Sampling is stateless: the key for a sampled token is
 ``fold_in(fold_in(PRNGKey(engine_seed), request_seed), step)``, a pure
@@ -34,10 +35,6 @@ which other requests share the batch. Greedy rows take an argmax and touch
 no randomness. Together with exact-length prefill this makes every
 request's output batch-invariant, greedy or sampled.
 
-``WaveEngine`` keeps the old wave-lockstep *scheduling* (admission only
-when every slot is free) on top of the same corrected primitives; it exists
-as the benchmark baseline for ``benchmarks/serving_bench.py``.
-
 Graceful degradation (``repro.resilience``): transient faults at the host
 scheduling sites never poison the lockstep batch. Admission-time faults
 retry with bounded backoff and then fail only the one request
@@ -45,7 +42,7 @@ retry with bounded backoff and then fail only the one request
 only the affected rows after idempotent decode retries (a decode step
 rewrites the same cache positions with the same values, so re-running it
 is safe); an injected pool starvation rides the normal backpressure path;
-a ``kv.check()`` integrity fault triggers a full pool rebuild from
+a failed store ``check()`` triggers a full pool rebuild from
 host-side request state (prompts + accepted tokens — K/V projections are
 position-local, so re-prefilling reproduces the incrementally-written
 cache). Per-request ``deadline_s`` adds ``finish_reason="timeout"``. The
@@ -76,7 +73,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import ops
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.ops import ExecutionContext
@@ -208,9 +204,8 @@ def plan_batch_size(cfg: ModelConfig, max_len: int, target: HardwareTarget,
 
 @functools.lru_cache(maxsize=None)
 def _make_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext):
-    """Compiled (prefill, insert, decode, sample) steps, shared across every
-    engine with the same (cfg, max_len, ctx) so warm jit caches carry
-    over between engines (and between the bench's wave/continuous runs).
+    """Compiled (prefill, sample) steps, shared across every engine with the
+    same (cfg, max_len, ctx) so warm jit caches carry over between engines.
     ``ctx`` arrives backend-resolved (``ExecutionContext.resolved``) so the
     cache key cannot alias across environment changes."""
 
@@ -228,16 +223,6 @@ def _make_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext):
                                               keepdims=False)
         # a dropless expert layer's counters ride along (ServeStats.moe_*)
         return (logits, cache, aux) if cfg.moe_dropless else (logits, cache)
-
-    def insert(pool, row, slot):
-        return T.insert_cache_slot(pool, row, slot)
-
-    def decode(params, cache, token, index):  # token (B, 1), index (B,)
-        logits, cache, aux = T.forward(params, cfg, tokens=token, cache=cache,
-                                       cache_index=index, decode=True, ctx=ctx)
-        if cfg.moe_dropless:
-            return logits[:, -1], cache, aux
-        return logits[:, -1], cache
 
     def sample(logits, base_key, seeds, steps, temps):
         """Row i: greedy argmax if temps[i] == 0, else a categorical draw
@@ -262,87 +247,7 @@ def _make_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext):
         toks = jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
         return toks, bad
 
-    return (jax.jit(prefill),
-            jax.jit(insert, donate_argnums=(0,)),
-            jax.jit(decode, donate_argnums=(1,)),
-            jax.jit(sample))
-
-
-@functools.lru_cache(maxsize=None)
-def _make_paged_steps(cfg: ModelConfig, max_len: int, ctx: ExecutionContext,
-                      block_size: int, quantized: bool = False):
-    """Compiled (insert, decode) for the paged pool. Prefill and sampling are
-    shared with ``_make_steps`` — prefill still runs contiguous at batch 1;
-    only its landing in the pool and the decode step are paged.
-
-    ``insert`` retraces per distinct block count (<= max_len/block_size
-    variants, the same ladder as the bucketed prefills); ``decode`` takes
-    the whole (B, max_len/block_size) tables, so it traces once — positions
-    and table *contents* are data, never trace constants. The Pallas kernel
-    streams each row's live pages alone, whatever the width; the xla
-    gathers read every table slot.
-
-    ``quantized`` targets the int8 pool layout: the scatter quantizes each
-    (kv_head, position) row symmetrically over hd (matching the write of
-    ``ops.attention_decode_quant``) and lands the int8 codes plus the f32
-    scales on the pool's kp/ks/vp/vs leaves.
-
-    ``decode`` donates the pool, and the decode op updates it in place
-    layer by layer: the step copies nothing pool-sized."""
-
-    def insert(pool, row, blocks, slot=None):
-        """row: batch-1 contiguous cache; blocks: (nt,) ids; slot: the row's
-        slot, for the per-slot states beside the pool (short convs)."""
-        nt = blocks.shape[0]
-
-        def block_rows(r, hp=None):
-            """r (R, 1, KV, max_len, hd) -> (R, nt, KV, bs, hp), heads
-            zero-padded to the pool's ``hp``."""
-            R, _, KV, L, hd = r.shape
-            hp = hp or hd
-            rb = r[:, 0, :, :min(nt * block_size, L), :]
-            # max_len below a whole block, or a lane-padded pool: zero-pad
-            rb = jnp.pad(rb, ((0, 0), (0, 0),
-                              (0, max(0, nt * block_size - L)), (0, hp - hd)))
-            return rb.reshape(R, KV, nt, block_size, hp).transpose(
-                0, 2, 1, 3, 4)
-
-        def scatter(p, r):  # p (R, nb, KV, bs, hp)
-            return p.at[:, blocks].set(
-                block_rows(r, p.shape[-1]).astype(p.dtype))
-
-        def scatter_q(p, s, r):  # + s (R, nb, KV, bs): per-row f32 scales
-            rb = block_rows(r).astype(jnp.float32)
-            amax = jnp.max(jnp.abs(rb), axis=-1)
-            scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-            q = jnp.clip(jnp.round(rb / scale[..., None]), -127.0,
-                         127.0).astype(jnp.int8)
-            return p.at[:, blocks].set(q), s.at[:, blocks].set(scale)
-
-        out = {}
-        for u, leaves in pool.items():
-            if "conv" in leaves:
-                out[u] = {"conv": leaves["conv"].at[:, slot].set(
-                    row[u]["conv"][:, 0].astype(leaves["conv"].dtype))}
-            elif quantized:
-                kp, ks = scatter_q(leaves["kp"], leaves["ks"], row[u]["k"])
-                vp, vs = scatter_q(leaves["vp"], leaves["vs"], row[u]["v"])
-                out[u] = {"kp": kp, "ks": ks, "vp": vp, "vs": vs}
-            else:
-                out[u] = {"kp": scatter(leaves["kp"], row[u]["k"]),
-                          "vp": scatter(leaves["vp"], row[u]["v"])}
-        return out
-
-    def decode(params, pool, token, index, tables):  # token (B,1), index (B,)
-        logits, pool, aux = T.forward(params, cfg, tokens=token, cache=pool,
-                                      cache_index=index, decode=True, ctx=ctx,
-                                      block_tables=tables)
-        if cfg.moe_dropless:
-            return logits[:, -1], pool, aux
-        return logits[:, -1], pool
-
-    return (jax.jit(insert, donate_argnums=(0,)),
-            jax.jit(decode, donate_argnums=(1,)))
+    return jax.jit(prefill), jax.jit(sample)
 
 
 class Engine:
@@ -353,25 +258,20 @@ class Engine:
     from ``target`` (backend per the ``repro.ops`` resolution order).
 
     ``paged`` (default: on for models of attention and short-conv blocks,
-    ``transformer.PAGED_KINDS``; a short conv's state lives per slot beside
-    the pool) replaces the per-slot contiguous KV assumption with the
-    ``repro.serving.kv`` block pool: admission *reserves* a request's whole
-    block budget up front (shared prompt-prefix blocks counted once,
-    refcounted), turns pool exhaustion into backpressure (the request waits
-    in queue) instead of an overcommit,
-    and the decode step reads K/V straight out of the pool through per-row
-    block tables (``ops.attention_decode`` — Pallas end-to-end, no
-    capability fallback). ``num_blocks=None`` sizes the pool for every slot
-    to reach ``max_len``, capped by the target's HBM budget
-    (``kv.plan_pool_blocks``).
+    ``transformer.PAGED_KINDS``) holds the KV cache in ``kv.PagedStore``'s
+    block pool, not ``kv.SlotStore``'s contiguous rows: admission
+    *reserves* a request's whole block budget up front (shared prompt-prefix
+    blocks counted once, refcounted), pool exhaustion is backpressure (the
+    request waits in queue), and the decode step reads K/V straight out of
+    the pool through per-row block tables (``ops.attention_decode``).
+    ``num_blocks=None`` sizes the pool for every slot to reach ``max_len``,
+    capped by the target's HBM budget (``kv.plan_pool_blocks``).
 
     ``kv_dtype="int8"`` (paged only) quantizes the pool: int8 blocks plus
     per-(block, head, position) f32 scales — (0.25 + 1/hd) words per cached
     element instead of bf16's 0.5, so the same HBM budget holds ~2x the
-    blocks (``kv.plan_pool_blocks(quantized=True)``) and each decode step
-    streams about half the cache words (the Lq=1 memory-independent term of
-    ``core.bounds.mixed_precision_attention_bound``). Output quality against
-    the bf16 pool is gated in ``benchmarks/quant_bench.py``."""
+    blocks and each decode step streams about half the cache words. Output
+    quality against the bf16 pool is gated in ``benchmarks/quant_bench.py``."""
 
     def __init__(self, cfg: ModelConfig, params: PyTree, max_len: int = 512,
                  batch_size: Optional[int] = None,
@@ -416,24 +316,18 @@ class Engine:
         if kv_dtype == "int8" and not paged:
             raise ValueError("kv_dtype='int8' requires the paged KV pool "
                              "(the quantized layout lives on pool blocks)")
-        self.kv_quant = kv_dtype == "int8"
         if batch_size is None:
             batch_size = plan_batch_size(
                 cfg, max_len, self.target,
                 block_size=block_size if paged else None)
         self.batch_size = batch_size
         if paged:
-            self.pool_head_dim = ops.pool_head_dim(
-                "attention_decode_quant" if self.kv_quant
-                else "attention_decode", cfg.hd, self.ctx)
-            if num_blocks is None:
-                num_blocks = kv.plan_pool_blocks(
-                    cfg, max_len, batch_size, block_size, target=self.target,
-                    quantized=self.kv_quant, head_dim=self.pool_head_dim)
-            self.num_blocks = num_blocks
-            self._paged_insert, self._paged_decode = _make_paged_steps(
-                cfg, max_len, self.ctx, block_size,
-                quantized=self.kv_quant)
+            self.store = kv.PagedStore(
+                cfg, max_len, batch_size, self.ctx, block_size, num_blocks,
+                target=self.target, quantized=kv_dtype == "int8")
+            self.num_blocks = self.store.num_blocks
+        else:
+            self.store = kv.SlotStore(cfg, max_len, batch_size, self.ctx)
         if prefill_bucket is None:
             # ragged prompts each jit a prefill per distinct length; rounding
             # lengths up to a bucket bounds that to max_len/bucket traces.
@@ -448,17 +342,10 @@ class Engine:
                 "blocks: other recurrent blocks fold pad tokens into their "
                 "state")
         self.prefill_bucket = max(1, prefill_bucket)
-        (self._prefill, self._insert, self._decode, self._sample) = \
-            _make_steps(cfg, max_len, self.ctx)
+        self._prefill, self._sample = _make_steps(cfg, max_len, self.ctx)
         self.base_key = jax.random.PRNGKey(seed)
         self.stats = ServeStats()  # of the last serve() call
 
-    # -- scheduling policy ----------------------------------------------------
-    def _admission_open(self, slots: List[Optional[_Slot]]) -> bool:
-        """Continuous batching: any free slot may be refilled immediately."""
-        return True
-
-    # -- serving loop ---------------------------------------------------------
     def serve(self, requests: List[Request]) -> List[Request]:
         B = self.batch_size
         for r in requests:
@@ -478,7 +365,7 @@ class Engine:
         camp = fj.active_campaign()
         queue: Deque[Tuple[int, Request]] = collections.deque(
             enumerate(requests))
-        bs = self.block_size
+        store = self.store
         # a dropless expert layer's counters, summed on the device and read
         # when serve ends
         moe_counts: Dict[str, jax.Array] = {}
@@ -495,41 +382,40 @@ class Engine:
                 jnp.zeros_like(out[-1]) if prev is None else prev)
             return out[:-1]
 
-        def prefill(tokens, mask, last):
+        def prefill(seq):
+            """Batch-1 prefill of the tokens ``seq`` at their bucket length,
+            the pad tail masked: (the last token's logits, the cache row)."""
+            n = len(seq)
+            lp = min(self.max_len,
+                     -(-n // self.prefill_bucket) * self.prefill_bucket)
+            tokens = np.zeros((1, lp), np.int32)
+            tokens[0, :n] = seq
+            mask = np.zeros((1, lp), bool)
+            mask[0, :n] = True
+            stats.prefill_real += n
+            stats.prefill_padded += lp
             return counted(self._prefill(self.params, jnp.asarray(tokens),
                                          jnp.asarray(mask),
-                                         jnp.asarray(last, jnp.int32)),
+                                         jnp.asarray(n - 1, jnp.int32)),
                            "prefill")
 
-        def paged_insert(cache, row, blocks, s):
-            # per-slot states beside the pool need the slot; a pure
-            # attention pool does not, and its program takes none
-            slot = (jnp.asarray(s, jnp.int32) if "conv" in self.cfg.pattern
-                    else None)
-            return self._paged_insert(cache, row,
-                                      jnp.asarray(blocks, jnp.int32), slot)
-
-        if self.paged:
-            cache = T.init_paged_cache(self.cfg, self.num_blocks, bs,
-                                       quantized=self.kv_quant, batch=B,
-                                       head_dim=self.pool_head_dim)
-            alloc = kv.BlockAllocator(self.num_blocks)
-            tables = np.zeros((B, -(-self.max_len // bs)), np.int32)
-            slot_blocks: List[List[int]] = [[] for _ in range(B)]
-            # device-side copy of the tables: they only change at
-            # admission/finish, so most decode steps skip the upload
-            tables_dev: List[jax.Array] = []
-        else:
-            cache = T.init_cache(self.cfg, B, self.max_len)
         slots: List[Optional[_Slot]] = [None] * B
         tok = np.zeros(B, np.int32)    # last accepted token per slot
         pos = np.zeros(B, np.int32)    # cache depth: next decode write offset
         seeds = np.zeros(B, np.int32)  # per-slot sampling stream ids
         temps = np.zeros(B, np.float32)
 
+        def refill(s: int):
+            """Slot s's prompt, and its cache row as written so far — the
+            prompt and every accepted token but the pending one (``tok[s]``,
+            which the next decode step writes) — with its length."""
+            prompt, written = slots[s].request.prompt, int(pos[s])
+            seq = list(prompt) + slots[s].generated[:written - len(prompt)]
+            return prompt, prefill(seq)[1], written
+
         def finish(s: int, reason: str) -> None:
             """Close slot s with ``reason``, keeping its generated tokens,
-            and release its resources (the ``finish/*`` campaign site)."""
+            and release its cache (the ``finish/*`` campaign site)."""
             slot = slots[s]
             r = slot.request
             r.out_tokens = np.asarray(slot.generated, np.int32)
@@ -537,16 +423,8 @@ class Engine:
             r.t_done = time.perf_counter() - t_start
             slots[s] = None
             tok[s], temps[s] = 0, 0.0  # dead row decodes greedily into void
-            if self.paged:
-                if camp is not None:
-                    inj = camp.draw("finish/pool", kinds=("pool",))
-                    if inj is not None:  # repaired at the next check/rebuild
-                        camp.corrupt_allocator(alloc, inj)
-                for bid in slot_blocks[s]:
-                    alloc.free(bid)  # shared prefixes -> refcount decrements
-                slot_blocks[s] = []
-                tables[s, :] = 0  # dead row reads/writes garbage block 0
-                tables_dev.clear()
+            store.inject("finish/pool")  # repaired at the next check/rebuild
+            store.release(s)
 
         def record(s: int, t: int) -> None:
             """Account one generated token for slot s; free it when done."""
@@ -561,105 +439,13 @@ class Engine:
             else:
                 tok[s] = int(t)
 
-        def reserve(r: Request, budget: int) -> Optional[List[int]]:
-            """Reserve the request's whole block budget (prompt + decode
-            growth), sharing registered prompt-prefix blocks. None = the pool
-            cannot cover it now -> admission backpressure."""
-            plen = len(r.prompt)
-            need = -(-(plen + budget - 1) // bs)
-            chain = kv.prefix_chain(r.prompt, bs)
-            hits: List[Tuple[kv.PrefixKey, int]] = []
-            for key in chain:
-                bid = alloc.lookup(key)
-                if bid is None:
-                    break  # chained keys: later blocks cannot match either
-                hits.append((key, bid))
-            fresh = need - len(hits)
-            # an evictable hit leaves the available pool the moment we take a
-            # reference, so it cannot also satisfy a fresh allocation
-            evictable_hits = sum(1 for _, b in hits if alloc.refcount(b) == 0)
-            if alloc.available() - evictable_hits < fresh:
-                return None
-            blocks = [alloc.ref(b) for _, b in hits]
-            for key in chain[len(hits):]:
-                b = alloc.alloc()
-                alloc.register(b, key)  # a full prompt block: shareable
-                blocks.append(b)
-            while len(blocks) < need:  # partial tail + decode growth: private
-                blocks.append(alloc.alloc())
-            return blocks
-
-        def rebuild_pool():
-            """Fresh allocator + pool, rebuilt from host-side request state.
-
-            Every live slot re-reserves its block budget against the new
-            allocator (prefix sharing intact — reservation order is slot
-            order, deterministic) and re-prefills exactly the tokens already
-            written to the cache (``prompt + generated[:pos - plen]``). K/V
-            projections are position-local and RoPE is applied pre-cache, so
-            the rebuilt pool matches the incrementally-written one bit for
-            bit over every read-visible position."""
-            new_alloc = kv.BlockAllocator(self.num_blocks)
-            new_cache = T.init_paged_cache(self.cfg, self.num_blocks, bs,
-                                           quantized=self.kv_quant, batch=B,
-                                           head_dim=self.pool_head_dim)
-            tables_dev.clear()
-            for s in range(B):
-                slot = slots[s]
-                if slot is None:
-                    slot_blocks[s] = []
-                    tables[s, :] = 0
-                    continue
-                r = slot.request
-                plen = len(r.prompt)
-                need = len(slot_blocks[s])
-                chain = kv.prefix_chain(r.prompt, bs)
-                blocks: List[int] = []
-                for key in chain:
-                    bid = new_alloc.lookup(key)
-                    if bid is not None:
-                        blocks.append(new_alloc.ref(bid))
-                        continue
-                    b = new_alloc.alloc()
-                    new_alloc.register(b, key)
-                    blocks.append(b)
-                while len(blocks) < need:
-                    blocks.append(new_alloc.alloc())
-                slot_blocks[s] = blocks
-                tables[s, :] = 0
-                tables[s, :len(blocks)] = blocks
-                # the cache holds the prompt plus every already-written
-                # accepted token; the pending token (tok[s]) is rewritten by
-                # the next decode step as usual
-                written = int(pos[s])
-                lp = min(self.max_len,
-                         -(-written // self.prefill_bucket)
-                         * self.prefill_bucket)
-                tokens = np.zeros((1, lp), np.int32)
-                tokens[0, :plen] = r.prompt
-                tokens[0, plen:written] = slot.generated[:written - plen]
-                mask = np.zeros((1, lp), bool)
-                mask[0, :written] = True
-                stats.prefill_real += written
-                stats.prefill_padded += lp
-                _, row = prefill(tokens, mask, written - 1)
-                nt = -(-written // bs)
-                new_cache = paged_insert(new_cache, row, blocks[:nt], s)
-            return new_alloc, new_cache
-
-        def admit_prefill(plen: int, prompt: np.ndarray):
+        def admit_prefill(prompt: np.ndarray):
             """Batch-1 prefill with bounded retry-with-backoff around the
             transient-fault sites (``admit/launch`` raises, ``admit/numeric``
             poisons, plus the always-on finite-logits guard). Returns
             ``(logits, cache_row)``, or None once ``admission_retries``
             retries are exhausted — the caller then fails that one request,
             nobody else."""
-            lp = min(self.max_len,
-                     -(-plen // self.prefill_bucket) * self.prefill_bucket)
-            tokens = np.zeros((1, lp), np.int32)
-            tokens[0, :plen] = prompt
-            mask = np.zeros((1, lp), bool)
-            mask[0, :plen] = True
             for attempt in range(self.admission_retries + 1):
                 num_inj = None
                 try:
@@ -671,9 +457,7 @@ class Engine:
                             if inj is not None:
                                 raise camp.fault_for(inj, op="prefill",
                                                      backend=self.ctx.backend)
-                        stats.prefill_real += plen
-                        stats.prefill_padded += lp
-                        logits1, row = prefill(tokens, mask, plen - 1)
+                        logits1, row = prefill(prompt)
                         if camp is not None:
                             num_inj = camp.draw("admit/numeric",
                                                 kinds=("numeric",),
@@ -697,6 +481,7 @@ class Engine:
                 return logits1, row
             return None
 
+        store.open(stats, camp, refill)
         rounds = 0
         stall = 0  # consecutive no-slot no-admission rounds with queued work
         while queue or any(s is not None for s in slots):
@@ -710,123 +495,58 @@ class Engine:
                         and now - slot.t0 >= slot.request.deadline_s):
                     stats.failures["timeout"] += 1
                     finish(s, "timeout")
-            # -- pool integrity: check every round under a campaign (finish
+            # -- cache integrity: check every round under a campaign (finish
             # may have just corrupted the allocator), periodically otherwise;
-            # a tripped invariant rebuilds pool + allocator from host state
-            if self.paged and (camp is not None or rounds % 256 == 0):
-                if camp is not None:
-                    inj = camp.draw("decode/pool", kinds=("pool",))
-                    if inj is not None:
-                        camp.corrupt_allocator(alloc, inj)
-                try:
-                    with jax.profiler.TraceAnnotation("serve/pool_check"):
-                        alloc.check()
-                except flt.PoolIntegrityFault:
-                    with jax.profiler.TraceAnnotation("serve/rebuild"):
-                        alloc, cache = rebuild_pool()
-                    if camp is not None:
-                        camp.resolve_kind("pool", "rebuilt")
+            # a tripped invariant rebuilds the cache from host state
+            if camp is not None or rounds % 256 == 0:
+                store.inject("decode/pool")
+                store.check()
             # -- admission: prefill queued requests into freed slots --------
             admitted = 0
-            if queue and self._admission_open(slots):
-                for s in range(B):
-                    if not queue or slots[s] is not None:
+            for s in (range(B) if queue else ()):
+                if not queue or slots[s] is not None:
+                    continue
+                rid, r = queue[0]
+                plen = len(r.prompt)
+                # token 1 comes from the prefill logits; token k needs a
+                # cache write at plen + k - 2 <= max_len - 1
+                budget = min(r.max_new_tokens, self.max_len - plen + 1)
+                if not store.reserve(s, r, budget):
+                    break  # backpressure: wait for a slot to finish
+                with jax.profiler.TraceAnnotation("serve/admit", rid=rid):
+                    queue.popleft()
+                    admitted += 1
+                    stats.admissions += 1
+                    r.t_admit = time.perf_counter() - t_start
+                    out = admit_prefill(r.prompt)
+                    if out is None:
+                        # transient faults exhausted the retry budget:
+                        # this request alone fails; its reservation is
+                        # returned
+                        r.out_tokens = np.asarray([], np.int32)
+                        r.finish_reason = "error"
+                        r.t_done = time.perf_counter() - t_start
+                        stats.failures["admit"] += 1
+                        store.release(s)
                         continue
-                    rid, r = queue[0]
-                    plen = len(r.prompt)
-                    # token 1 comes from the prefill logits; token k needs a
-                    # cache write at plen + k - 2 <= max_len - 1
-                    budget = min(r.max_new_tokens, self.max_len - plen + 1)
-                    if self.paged:
-                        if camp is not None:
-                            inj = camp.draw("admit/oom", kinds=("oom",))
-                            if inj is not None:
-                                # injected pool starvation rides the normal
-                                # backpressure path: the request just waits
-                                camp.resolve(inj, "backpressure")
-                                stats.backpressure += 1
-                                break
-                        blocks = reserve(r, budget)
-                        if blocks is None:
-                            if not any(x is not None for x in slots):
-                                try:
-                                    with jax.profiler.TraceAnnotation(
-                                            "serve/pool_check"):
-                                        alloc.check()
-                                except flt.PoolIntegrityFault:
-                                    # a corrupted allocator can fake
-                                    # exhaustion: repair, retry next round
-                                    with jax.profiler.TraceAnnotation(
-                                            "serve/rebuild"):
-                                        alloc, cache = rebuild_pool()
-                                    if camp is not None:
-                                        camp.resolve_kind("pool", "rebuilt")
-                                    break
-                                raise flt.AdmissionImpossible(
-                                    f"paged KV pool of {self.num_blocks} "
-                                    f"blocks cannot ever admit a "
-                                    f"{plen}-token prompt with budget "
-                                    f"{budget}; raise num_blocks",
-                                    num_blocks=self.num_blocks,
-                                    blocks_needed=-(-(plen + budget - 1)
-                                                    // bs),
-                                    available_blocks=alloc.available(),
-                                    live_blocks=alloc.live_blocks())
-                            stats.backpressure += 1
-                            break  # backpressure: wait for a slot to finish
-                        slot_blocks[s] = blocks
-                        tables[s, :] = 0
-                        tables[s, :len(blocks)] = blocks
-                        tables_dev.clear()
-                    with jax.profiler.TraceAnnotation("serve/admit",
-                                                      rid=rid):
-                        queue.popleft()
-                        admitted += 1
-                        stats.admissions += 1
-                        r.t_admit = time.perf_counter() - t_start
-                        out = admit_prefill(plen, r.prompt)
-                        if out is None:
-                            # transient faults exhausted the retry budget:
-                            # this request alone fails; its reservation is
-                            # returned
-                            r.out_tokens = np.asarray([], np.int32)
-                            r.finish_reason = "error"
-                            r.t_done = time.perf_counter() - t_start
-                            stats.failures["admit"] += 1
-                            if self.paged:
-                                for bid in slot_blocks[s]:
-                                    alloc.free(bid)
-                                slot_blocks[s] = []
-                                tables[s, :] = 0
-                                tables_dev.clear()
-                            continue
-                        logits1, row = out
-                        slots[s] = _Slot(request=r, budget=budget,
-                                         t0=time.monotonic())
-                        seeds[s] = (r.rng_seed if r.rng_seed is not None
-                                    else rid)
-                        temps[s] = r.temperature
-                        pos[s] = plen
-                        with jax.profiler.TraceAnnotation("serve/insert"):
-                            if self.paged:
-                                # land the prompt's blocks in the pool (a
-                                # shared hit is rewritten with bit-identical
-                                # K/V: same tokens, positions, params; RoPE
-                                # is applied pre-cache)
-                                nt = -(-plen // bs)
-                                cache = paged_insert(
-                                    cache, row, slot_blocks[s][:nt], s)
-                            else:
-                                cache = self._insert(cache, row, s)
-                        with jax.profiler.TraceAnnotation("serve/first_token"):
-                            first, _ = self._sample(
-                                logits1, self.base_key,
-                                jnp.asarray(seeds[s:s + 1]),
-                                jnp.zeros(1, jnp.int32),
-                                jnp.asarray(temps[s:s + 1]))
-                            first = int(np.asarray(first)[0])
-                        r.t_first = time.perf_counter() - t_start
-                        record(s, first)
+                    logits1, row = out
+                    slots[s] = _Slot(request=r, budget=budget,
+                                     t0=time.monotonic())
+                    seeds[s] = (r.rng_seed if r.rng_seed is not None
+                                else rid)
+                    temps[s] = r.temperature
+                    pos[s] = plen
+                    with jax.profiler.TraceAnnotation("serve/insert"):
+                        store.insert(row, s, plen)
+                    with jax.profiler.TraceAnnotation("serve/first_token"):
+                        first, _ = self._sample(
+                            logits1, self.base_key,
+                            jnp.asarray(seeds[s:s + 1]),
+                            jnp.zeros(1, jnp.int32),
+                            jnp.asarray(temps[s:s + 1]))
+                        first = int(np.asarray(first)[0])
+                    r.t_first = time.perf_counter() - t_start
+                    record(s, first)
             active = [s for s in range(B) if slots[s] is not None]
             if not active:
                 # everything admitted this round finished instantly, or
@@ -858,21 +578,8 @@ class Engine:
                                pos, 0)
                 for attempt in range(self.numeric_retries + 1):
                     with jax.profiler.TraceAnnotation("serve/decode"):
-                        if self.paged:
-                            stats.kv_read += sum(
-                                -(-(int(i) + 1) // bs) * bs for i in idx)
-                            stats.kv_live += sum(int(pos[s]) + 1
-                                                 for s in active)
-                            if not tables_dev:  # dead rows: all block 0
-                                tables_dev.append(jnp.asarray(tables))
-                            logits, cache = counted(self._paged_decode(
-                                self.params, cache, jnp.asarray(tok)[:, None],
-                                jnp.asarray(idx, jnp.int32), tables_dev[0]),
-                                "decode")
-                        else:
-                            logits, cache = counted(self._decode(
-                                self.params, cache, jnp.asarray(tok)[:, None],
-                                jnp.asarray(idx, jnp.int32)), "decode")
+                        (logits,) = counted(store.decode(
+                            self.params, tok, idx, active), "decode")
                     num_inj = None
                     if camp is not None:
                         num_inj = camp.draw("decode/numeric",
@@ -913,6 +620,7 @@ class Engine:
                             continue  # failed/expired rows were closed above
                         pos[s] += 1
                         record(s, int(nxt[s]))
+        store.close()
         if camp is not None:
             # the pool dies with the loop: any still-latent corruption from a
             # last-round finish is discarded wholesale — the degenerate
@@ -923,14 +631,3 @@ class Engine:
                 stats.moe_rows[kind], stats.moe_groups[kind] = \
                 (int(v) for v in np.asarray(tot))
         return requests
-
-
-class WaveEngine(Engine):
-    """Wave-lockstep baseline: the old engine's scheduling (admit a full
-    batch, then barrier until every request in it finishes) on top of the
-    same corrected slot primitives. Kept as the benchmark baseline so
-    ``benchmarks/serving_bench.py`` can show what continuous batching buys
-    on mixed prompt/output lengths."""
-
-    def _admission_open(self, slots: List[Optional[_Slot]]) -> bool:
-        return all(s is None for s in slots)

@@ -17,6 +17,7 @@ from repro.models import transformer as T
 from repro.plan import CPU_INTERPRET
 from repro.resilience import errors as flt
 from repro.resilience import faults as fj
+from repro.serving import kv
 from repro.serving.engine import Engine, Request
 
 KEY = jax.random.PRNGKey(0)
@@ -404,6 +405,53 @@ def test_pool_corruption_triggers_exact_rebuild(engine_setup):
     camp.verify_accounted()
     assert camp.summary()["resolutions"].get("rebuilt", 0) >= 1
     for c, f in zip(clean, out):  # rebuilds reproduce the cache exactly
+        assert f.finish_reason == c.finish_reason
+        assert np.array_equal(f.out_tokens, c.out_tokens)
+
+
+def test_pool_rebuild_keeps_shared_prefixes(engine_setup, monkeypatch):
+    """Rebuilds while live requests share a prompt-prefix block: every
+    request ends with the clean run's tokens and finish reason, and at every
+    decode step each slot's blocks carry the clean run's refcounts, so the
+    rebuilt pool shares the prefix block again rather than copying it."""
+    cfg, params = engine_setup
+    prefix = np.arange(1, 17, dtype=np.int32)  # one full block of 16
+    specs = ((2, 6), (3, 4), (5, 7), (1, 5), (4, 3))
+
+    def reqs():
+        return [Request(prompt=np.concatenate(
+            [prefix, np.full(n, 20 + n, np.int32)]), max_new_tokens=m)
+            for n, m in specs]
+
+    steps, shared_at_rebuild = [], []
+    decode, check = kv.PagedStore.decode, kv.PagedStore.check
+
+    def spy_decode(self, *args):
+        steps[-1].append([[self.alloc.refcount(b) for b in blocks]
+                          for blocks in self.blocks])
+        return decode(self, *args)
+
+    def spy_check(self):
+        sound = check(self)
+        if not sound:
+            shared_at_rebuild.append(max(
+                (self.alloc.refcount(b) for bl in self.blocks for b in bl),
+                default=0))
+        return sound
+
+    monkeypatch.setattr(kv.PagedStore, "decode", spy_decode)
+    monkeypatch.setattr(kv.PagedStore, "check", spy_check)
+    camp = fj.FaultCampaign(seed=2, rate=0.5, kinds=("pool",))
+    runs = []
+    for c in (None, camp):
+        steps.append([])
+        with fj.activate(c):
+            runs.append(Engine(cfg, params, max_len=32,
+                               batch_size=2).serve(reqs()))
+    camp.verify_accounted()
+    assert max(shared_at_rebuild) == 2  # rebuilt with both slots sharing
+    assert steps[0] == steps[1]
+    for c, f in zip(*runs):
         assert f.finish_reason == c.finish_reason
         assert np.array_equal(f.out_tokens, c.out_tokens)
 

@@ -1,7 +1,7 @@
 """Serving engine tests: slot-based continuous batching, batch invariance
 (greedy and sampled), EOS / cache-limit accounting, seeded reproducibility,
-wave-baseline parity, recurrent-arch decode, plan-aware batch sizing, and
-the paged KV-cache mode (block tables, prefix sharing, backpressure)."""
+recurrent-arch decode, plan-aware batch sizing, and the paged KV-cache mode
+(block tables, prefix sharing, backpressure)."""
 
 import dataclasses
 
@@ -14,7 +14,7 @@ from repro import ops
 from repro.configs import get_smoke
 from repro.models import transformer as T
 from repro.plan import CPU_INTERPRET
-from repro.serving.engine import Engine, Request, WaveEngine, plan_batch_size
+from repro.serving.engine import Engine, Request, plan_batch_size
 
 KEY = jax.random.PRNGKey(0)
 
@@ -170,22 +170,6 @@ def test_cache_limit_returns_only_real_tokens():
     np.testing.assert_array_equal(trunc.out_tokens, full.out_tokens[:cap])
 
 
-def test_wave_baseline_matches_continuous_outputs():
-    """Scheduling must not change tokens: the wave baseline and the slot
-    engine agree request-by-request (they differ only in admission timing)."""
-    params, cfg = _params_and_cfg("stablelm_1_6b")
-    rng = np.random.default_rng(2)
-    specs = [(rng.integers(0, cfg.vocab_size, size=n).astype(np.int32), m)
-             for n, m in ((5, 6), (2, 2), (3, 4), (6, 3), (1, 5))]
-    a = [Request(prompt=p, max_new_tokens=m) for p, m in specs]
-    b = [Request(prompt=p, max_new_tokens=m) for p, m in specs]
-    Engine(cfg, params, max_len=32, batch_size=2).serve(a)
-    WaveEngine(cfg, params, max_len=32, batch_size=2).serve(b)
-    for ra, rb in zip(a, b):
-        np.testing.assert_array_equal(ra.out_tokens, rb.out_tokens)
-        assert ra.finish_reason == rb.finish_reason
-
-
 def test_prefill_bucket_exactness_and_guard():
     """Masked bucketed prefill (attention archs) must equal exact-length
     prefill token-for-token; recurrent patterns must reject bucketing."""
@@ -258,10 +242,10 @@ def test_paged_matches_contiguous_outputs():
     Engine(cfg, params, max_len=64, batch_size=3, paged=False).serve(a)
     eng = Engine(cfg, params, max_len=64, batch_size=3, paged=True)
     assert eng.paged and eng.num_blocks >= 1 + 64 // eng.block_size
-    traced = eng._paged_decode._cache_size()
+    traced = eng.store.decode_step._cache_size()
     eng.serve(b)
     # rows of every depth share one decode program: the whole tables
-    assert eng._paged_decode._cache_size() <= traced + 1
+    assert eng.store.decode_step._cache_size() <= traced + 1
     for ra, rb in zip(a, b):
         np.testing.assert_array_equal(ra.out_tokens, rb.out_tokens)
         assert ra.finish_reason == rb.finish_reason

@@ -232,7 +232,7 @@ def test_paged_decode_step_updates_the_pool_in_place(compile_for_chip,
     input the step donates to its output. qwen keeps 2 of its 36 layers (the scan
     carries the stack); LFM2 keeps layers 0-6, attention at 2 and 6."""
     from repro.models import transformer as T
-    from repro.serving.engine import _make_paged_steps
+    from repro.serving.kv import PagedStore
 
     ctx = _ctx("pallas")
     if model == "qwen":
@@ -244,7 +244,7 @@ def test_paged_decode_step_updates_the_pool_in_place(compile_for_chip,
     params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
     pool = jax.eval_shape(lambda: T.init_paged_cache(
         cfg, blocks, 16, batch=B, head_dim=hp))
-    decode = _make_paged_steps(cfg, 384, ctx, 16)[1]
+    decode = PagedStore(cfg, 384, B, ctx, 16, blocks).decode_step
     hlo = compile_for_chip(decode, params, pool, ((B, 1), jnp.int32),
                            ((B,), jnp.int32), ((B, 384 // 16), jnp.int32))
     assert "%paged_decode_attention." in hlo
