@@ -368,6 +368,15 @@ def optimize_blocking(
 # lane axis, the batch on the sublane axis, and both spatial axes leading
 # and untiled. It unrolls every tap (no q/r blocking), and its input window
 # is the exact halo extent b_hO + h_F' - 1 rows by b_wO + w_F' - 1 columns.
+#
+# Column-tap folding: where one reduction block holds every channel and its
+# c = b_cI * s_h * s_w space-to-depth channels times the w_F' column taps
+# still fit one lane tile, the column taps ride the lanes too (lane
+# (tw, ch) of column j holds channel ch of column j + tw). The kernel then
+# multiplies c * w_F' live lanes in h_F' x 1 taps, rather than c live lanes
+# in h_F' x w_F', and its window needs no column halo. Tiles keep their
+# meaning in the original problem. Only narrow stems fold (ResNet-50's
+# conv1: 12 channels x 4 taps = 48 of 128 lanes).
 # ---------------------------------------------------------------------------
 
 def layout_align(align: Tuple[int, int], p_in: float) -> Tuple[int, int]:
@@ -414,12 +423,13 @@ class ConvKernelGeometry:
     """Launch geometry of the channels-last conv2d kernel: the single source
     of truth shared by its lowering, its HBM-word counter, its access plan
     and the planner's VMEM fit. Extents after the ``p`` suffix are padded;
-    channel extents count space-to-depth channels."""
+    channel extents count space-to-depth channels, times ``fold``."""
 
     tiles: Tuple[int, int, int, int, int]  # conv_kernel_tiles(...)
-    b_c: int  # space-to-depth channels per reduction block (lane-aligned)
-    hF: int  # taps of the stride-1 space-to-depth filter
+    b_c: int  # lanes per reduction block (lane-aligned)
+    hF: int  # taps the kernel runs (column taps folded into lanes: wF = 1)
     wF: int
+    fold: int  # column taps folded into the lanes (1: none)
     Np: int
     Cp: int  # padded space-to-depth channels (n_ci * b_c)
     cOp: int
@@ -438,17 +448,19 @@ def conv_kernel_geometry(shape: ConvShape, tiles: Sequence[int],
     _, lane = layout_align(_conv_layout_align(shape, align), shape.prec.p_I)
     t = conv_kernel_tiles(shape, tiles, align)
     bN, b_cI, b_cO, bh, bw = t
-    phases = shape.sh * shape.sw
+    c = b_cI * shape.sh * shape.sw
     hF = ceil_div(shape.h_F, shape.sh)
     wF = ceil_div(shape.w_F, shape.sw)
     n_ci = ceil_div(shape.c_I, b_cI)
-    b_c = round_up(b_cI * phases, lane)
+    fold = wF if n_ci == 1 and wF > 1 and c * wF <= lane else 1
+    wF //= fold
+    b_c = round_up(c * fold, lane)
     Np, cOp = round_up(shape.N, bN), round_up(shape.c_O, b_cO)
     hOp, wOp = round_up(shape.h_O, bh), round_up(shape.w_O, bw)
     grid = (Np // bN, cOp // b_cO, hOp // bh, wOp // bw, n_ci)
     return ConvKernelGeometry(
-        tiles=t, b_c=b_c, hF=hF, wF=wF, Np=Np, Cp=n_ci * b_c, cOp=cOp,
-        hOp=hOp, wOp=wOp, Hp=hOp + hF - 1, Wp=wOp + wF - 1,
+        tiles=t, b_c=b_c, hF=hF, wF=wF, fold=fold, Np=Np, Cp=n_ci * b_c,
+        cOp=cOp, hOp=hOp, wOp=wOp, Hp=hOp + hF - 1, Wp=wOp + wF - 1,
         h_in=bh + hF - 1, w_in=bw + wF - 1, grid=grid)
 
 

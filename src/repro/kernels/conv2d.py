@@ -16,6 +16,11 @@ only slice whole tiles:
     into the channel axis, so the strided conv becomes a stride-1 conv with
     ceil(h_F/s_h) x ceil(w_F/s_w) taps and no tap is a strided slice; the
     filter becomes (taps_h, taps_w, channels, c_O) to match;
+  * where one reduction block holds every channel and the column taps fit
+    one lane tile beside them (ResNet-50's stem: 12 channels x 4 taps),
+    the column taps ride the lanes too (``ConvKernelGeometry.fold``), so
+    the kernel runs ceil(h_F/s_h) x 1 taps and its window has no column
+    halo;
   * channels ride the lanes (padded to whole lanes), the batch rides the
     sublanes (blocked in whole sublane tiles), and both spatial axes lead,
     untiled — so the halo windows start at any row and column, and a tap is
@@ -28,10 +33,11 @@ overlapping input window of bh + taps_h - 1 space-to-depth rows, and
 similarly for columns. Overlapping windows cannot be expressed with blocked
 BlockSpecs, so the input and filter stay in ANY/HBM memory and the kernel
 streams each window itself with ``pltpu.make_async_copy`` into a two-slot
-VMEM scratch, double-buffered across the c_I reduction grid axis: while the
-MXU runs the taps of reduction step ci, the DMAs for step ci + 1 are already
-in flight (§5's double-buffering, which is also why the LP halves usable
-capacity).
+VMEM scratch, double-buffered over the whole grid, taken in order: while the
+MXU runs the taps of one grid step, the DMAs for the next are already in
+flight, whether that step is the next c_I block or the next tile (§5's
+double-buffering, which is also why the LP halves usable capacity). The
+grid's axes therefore run in order, on one core.
 
 ``conv2d_hbm_words`` reports the measured HBM words one dispatch moves,
 computed from the same launch geometry the kernel lowers (layout passes +
@@ -47,6 +53,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,14 +98,30 @@ def _geometry(x, w, stride, plan=None, target=None, tiles=None, ctx=None
 def _input_layout(x: jax.Array, shape: ConvShape, g: ConvKernelGeometry
                   ) -> jax.Array:
     """NCHW -> padded space-to-depth (Hp, Wp, Np, Cp); the channel order
-    (c, phase_h, phase_w) keeps each c_I block contiguous."""
+    (c, phase_h, phase_w) keeps each c_I block contiguous. Folded, lane
+    (tw, c, phase_h, phase_w) of column j holds column j + tw: a one-hot
+    convolution gathers each (s_h, fold * s_w) window straight into the
+    kernel's lane-padded layout, so XLA writes that array once (shifted
+    copies concatenated on the lane axis are each laid out lane-padded
+    first, ten times their size at ResNet-50's stem)."""
     N, C, H, W = x.shape
     sh, sw = shape.sh, shape.sw
-    rows, cols = g.Hp * sh, g.Wp * sw
+    rows, cols = g.Hp * sh, (g.Wp + g.fold - 1) * sw
     cpad = g.grid[4] * g.tiles[1]
     x = x[:, :, :min(H, rows), :min(W, cols)]
     x = jnp.pad(x, ((0, g.Np - N), (0, cpad - C), (0, rows - x.shape[2]),
                     (0, cols - x.shape[3])))
+    if g.fold > 1:
+        lanes = g.fold * cpad * sh * sw
+        taps = np.eye(g.Cp, lanes, dtype=np.float32).reshape(
+            g.Cp, g.fold, cpad, sh, sw).transpose(0, 2, 3, 1, 4)
+        return jax.lax.conv_general_dilated(
+            x, jnp.asarray(taps.reshape(g.Cp, cpad, sh, g.fold * sw),
+                           x.dtype),
+            window_strides=(sh, sw), padding="VALID",
+            dimension_numbers=("NCHW", "OIHW", "HWNC"),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=x.dtype)
     x = x.reshape(g.Np, cpad, g.Hp, sh, g.Wp, sw).transpose(2, 4, 0, 1, 3, 5)
     x = x.reshape(g.Hp, g.Wp, g.Np, cpad * sh * sw)
     return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, g.Cp - x.shape[3])))
@@ -106,14 +129,17 @@ def _input_layout(x: jax.Array, shape: ConvShape, g: ConvKernelGeometry
 
 def _filter_layout(w: jax.Array, shape: ConvShape, g: ConvKernelGeometry
                    ) -> jax.Array:
-    """OIHW -> (taps_h, taps_w, Cp, cOp), matching :func:`_input_layout`."""
+    """OIHW -> (taps_h, taps_w, Cp, cOp), matching :func:`_input_layout`:
+    folded column taps lead the lanes, as (tw, ch)."""
     c_O, C, h_F, w_F = w.shape
     sh, sw = shape.sh, shape.sw
     cpad = g.grid[4] * g.tiles[1]
+    taps_w = g.wF * g.fold
     w = jnp.pad(w, ((0, g.cOp - c_O), (0, cpad - C), (0, g.hF * sh - h_F),
-                    (0, g.wF * sw - w_F)))
-    w = w.reshape(g.cOp, cpad, g.hF, sh, g.wF, sw).transpose(2, 4, 1, 3, 5, 0)
-    w = w.reshape(g.hF, g.wF, cpad * sh * sw, g.cOp)
+                    (0, taps_w * sw - w_F)))
+    w = w.reshape(g.cOp, cpad, g.hF, sh, taps_w, sw).transpose(
+        2, 4, 1, 3, 5, 0)
+    w = w.reshape(g.hF, g.wF, g.fold * cpad * sh * sw, g.cOp)
     return jnp.pad(w, ((0, 0), (0, 0), (0, g.Cp - w.shape[2]), (0, 0)))
 
 
@@ -128,40 +154,57 @@ def _gemm_block(bh: int, bw: int, bN: int) -> Tuple[int, int]:
                   if bw % c == 0 and (c == 1 or c * bN <= _GEMM_ROWS))
 
 
+def _grid_index(step, grid: Sequence[int]):
+    """The grid indices of linear step ``step`` (row-major, the order the
+    grid runs in)."""
+    idx = []
+    for extent in reversed(grid):
+        idx.append(step % extent)
+        step = step // extent
+    return tuple(reversed(idx))
+
+
 def _conv_kernel(x_hbm, w_hbm, o_ref, x_vmem, w_vmem, acc_ref, sems, *,
                  g: ConvKernelGeometry):
     bN, _, b_cO, bh, bw = g.tiles
     b_c, n_ci = g.b_c, g.grid[4]
-    n, co, h, wb, ci = (pl.program_id(i) for i in range(5))
+    idx = tuple(pl.program_id(i) for i in range(5))
+    ci = idx[4]
+    step = 0
+    for i, extent in zip(idx, g.grid):
+        step = step * extent + i
+    slot = step % 2
 
-    def stream(slot, ci_idx):
-        """The two HBM->VMEM copies feeding reduction step ci_idx: the halo
-        input window of this (n, h, wb) tile and the (co, ci) filter block."""
+    def stream(slot, n, co, h, wb, ci):
+        """The two HBM->VMEM copies feeding grid step (n, co, h, wb, ci):
+        the halo input window of the (n, h, wb) tile's c_I block ci and the
+        (co, ci) filter block."""
         return (
             pltpu.make_async_copy(
                 x_hbm.at[pl.ds(h * bh, g.h_in), pl.ds(wb * bw, g.w_in),
-                         pl.ds(n * bN, bN), pl.ds(ci_idx * b_c, b_c)],
+                         pl.ds(n * bN, bN), pl.ds(ci * b_c, b_c)],
                 x_vmem.at[slot], sems.at[slot, 0]),
             pltpu.make_async_copy(
-                w_hbm.at[:, :, pl.ds(ci_idx * b_c, b_c),
+                w_hbm.at[:, :, pl.ds(ci * b_c, b_c),
                          pl.ds(co * b_cO, b_cO)],
                 w_vmem.at[slot], sems.at[slot, 1]),
         )
 
-    @pl.when(ci == 0)
+    @pl.when(step == 0)
     def _warmup():
+        for cp in stream(0, *idx):
+            cp.start()
+
+    @pl.when(step + 1 < math.prod(g.grid))
+    def _prefetch():  # overlap the next grid step's DMAs with the GEMMs
+        for cp in stream(1 - slot, *_grid_index(step + 1, g.grid)):
+            cp.start()
+
+    @pl.when(ci == 0)
+    def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        for cp in stream(0, 0):
-            cp.start()
 
-    slot = ci % 2
-
-    @pl.when(ci + 1 < n_ci)
-    def _prefetch():  # overlap the next reduction step's DMA with the GEMMs
-        for cp in stream(1 - slot, ci + 1):
-            cp.start()
-
-    for cp in stream(slot, ci):
+    for cp in stream(slot, *idx):
         cp.wait()
 
     rows, cols = _gemm_block(bh, bw, bN)
@@ -232,6 +275,7 @@ def conv2d(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 5,
             vmem_limit_bytes=tgt.vmem_limit_bytes),
         interpret=pallas_interpret(),
         name="conv2d_lp",
@@ -283,13 +327,15 @@ def conv2d_shard(
 def _layout_words(x, w, shape: ConvShape, g: ConvKernelGeometry, out_dtype
                   ) -> Tuple[float, float, float]:
     """HBM words of the three XLA layout passes around the kernel: input to
-    space-to-depth HWNC (read what is kept, write the padded copy), filter
-    to (taps, Cp, cOp), and the output crop + NCHW transpose."""
+    space-to-depth HWNC (read what is kept, write the padded copy, column
+    taps folded in or not), filter to (taps, Cp, cOp), and the output crop
+    + NCHW transpose."""
     N, C, H, W = x.shape
     p_in = jnp.dtype(x.dtype).itemsize / 4.0
     p_flt = jnp.dtype(w.dtype).itemsize / 4.0
     p_out = jnp.dtype(out_dtype).itemsize / 4.0
-    kept = N * C * min(H, g.Hp * shape.sh) * min(W, g.Wp * shape.sw)
+    cols = g.Wp + g.fold - 1  # space-to-depth columns the layout reads
+    kept = N * C * min(H, g.Hp * shape.sh) * min(W, cols * shape.sw)
     x_words = p_in * (kept + g.Np * g.Hp * g.Wp * g.Cp)
     w_words = p_flt * (math.prod(w.shape) + g.hF * g.wF * g.Cp * g.cOp)
     o_words = 2.0 * p_out * shape.N * shape.c_O * shape.h_O * shape.w_O
@@ -329,11 +375,12 @@ def conv2d_access_plan(
     x_layout, w_layout, o_layout = _layout_words(x, w, shape, g, out_dtype)
 
     def x_requires(n, co, h, wb, ci):
-        # first/last output row of the tile -> last pixel row of its taps
+        # first/last output row of the tile -> last pixel row of its taps;
+        # folded column j holds space-to-depth columns j .. j + fold - 1
         row_lo, row_hi = h * bh, h * bh + bh - 1
         col_lo, col_hi = wb * bw, wb * bw + bw - 1
         return ((row_lo, (row_hi * sh + shape.h_F - 1) // sh + 1),
-                (col_lo, (col_hi * sw + shape.w_F - 1) // sw + 1),
+                (col_lo, (col_hi * sw + shape.w_F - 1) // sw + 2 - g.fold),
                 (n * bN, (n + 1) * bN),
                 (ci * b_c, (ci + 1) * b_c))
 
@@ -371,9 +418,10 @@ def conv2d_access_plan(
     )
     return KernelAccessPlan(
         op="conv2d", grid=g.grid, accesses=accesses, scratch=scratch,
-        dma=double_buffered_schedule(g.grid[4], n_slots=2,
-                                     name="input/filter c_I stream"),
-        note="DMA schedule repeats identically per (n, co, h, w) tile")
+        dma=double_buffered_schedule(math.prod(g.grid), n_slots=2,
+                                     name="input/filter stream"),
+        note="one DMA stream over the whole grid, in order: each step "
+             "prefetches the next step's window, across tiles too")
 
 
 def conv2d_hbm_words(
@@ -403,3 +451,21 @@ def conv2d_hbm_words(
             + n_steps * bN * g.h_in * g.w_in * g.b_c * p_in
             + n_steps * g.hF * g.wF * g.b_c * b_cO * p_flt
             + g.Np * g.hOp * g.wOp * g.cOp * p_out)
+
+
+def conv2d_mxu_flops(
+    x,  # array or ShapeDtypeStruct, (N, c_I, H, W)
+    w,  # array or ShapeDtypeStruct, (c_O, c_I, h_F, w_F)
+    stride: Tuple[int, int] = (1, 1),
+    tiles: Optional[Sequence[int]] = None,
+    plan: Optional[ExecutionPlan] = None,
+    target: Optional[HardwareTarget] = None,
+) -> int:
+    """MXU FLOPs one ``conv2d`` dispatch executes: every grid step runs a
+    (bh * bw * bN, b_c) @ (b_c, b_cO) GEMM per tap, padding included. The
+    conv's own FLOPs over this is the share of the MXU's work that is
+    useful."""
+    _, g, _ = _geometry(x, w, stride, plan=plan, target=target, tiles=tiles)
+    bN, _, b_cO, bh, bw = g.tiles
+    return (2 * math.prod(g.grid) * bh * bw * bN * g.hF * g.wF * g.b_c
+            * b_cO)

@@ -73,7 +73,10 @@ class DispatchDecision:
     (32-bit) for single-device ops, *inter-device* words per device for the
     distributed ops — reported via ``bound_ratio`` against the matching
     bound: the plan's Thm 2.1 ``lower_bound``, or the plan's ``parallel``
-    section's Thm 2.2/2.3 bound for ``*_dist`` ops.
+    section's Thm 2.2/2.3 bound for ``*_dist`` ops. ``mxu_flops`` the MXU
+    FLOPs the same launch geometry executes, padding included (None when
+    the entry does not count them): the op's own FLOPs over it is the share
+    of the MXU's work that is useful.
 
     ``audited`` is the static auditor's independent word count (set only
     when dispatch ran with ``audit=True``): ``repro.verify`` abstractly
@@ -99,6 +102,7 @@ class DispatchDecision:
     plan: Optional[Any] = None
     plan_source: str = "analytic"  # "explicit" | "tuned" | "analytic"
     measured_words: Optional[float] = None
+    mxu_flops: Optional[int] = None
     audited: Optional[float] = None
     degraded: bool = False
     fault: Optional[str] = None
@@ -293,8 +297,8 @@ def _attach_plan_and_words(entry: OpEntry, decision: DispatchDecision,
                            plan: Optional[Any] = None) -> DispatchDecision:
     """Resolve the entry's plan — explicit ``plan`` > stored tuned winner >
     analytic LP, via the shared ``ctx.plan_with_source`` path — and its
-    measured-HBM-words counter (both need only shapes/dtypes, so tracers and
-    ShapeDtypeStructs work)."""
+    measured-HBM-words and MXU-FLOP counters (all need only shapes/dtypes,
+    so tracers and ShapeDtypeStructs work)."""
     if spec_args is None:
         return decision
     kw = spec_kw or {}
@@ -311,6 +315,10 @@ def _attach_plan_and_words(entry: OpEntry, decision: DispatchDecision,
             decision,
             measured_words=entry.words_fn(ctx, decision.plan,
                                           *spec_args, **kw))
+    if entry.flops_fn is not None:
+        decision = dataclasses.replace(
+            decision,
+            mxu_flops=entry.flops_fn(ctx, decision.plan, *spec_args, **kw))
     return decision
 
 

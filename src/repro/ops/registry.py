@@ -50,7 +50,8 @@ from repro.kernels.conv1d import (conv1d_access_plan,
                                   conv1d_causal as _conv1d_pallas,
                                   conv1d_hbm_words)
 from repro.kernels.conv2d import (_conv_spec, conv2d as _conv2d_pallas,
-                                  conv2d_access_plan, conv2d_hbm_words)
+                                  conv2d_access_plan, conv2d_hbm_words,
+                                  conv2d_mxu_flops)
 from repro.kernels.flash_attention import (attention_blocks,
                                            attention_hbm_words,
                                            flash_attention as _flash_pallas,
@@ -118,6 +119,9 @@ class OpEntry:
     # (ctx, plan, *spec_args, **spec_kw) -> float. None = not instrumented
     # (XLA entries delegate data movement to the compiler).
     words_fn: Optional[Callable] = None
+    # MXU FLOPs the kernel's launch geometry executes for this call, padding
+    # included: (ctx, plan, *spec_args, **spec_kw) -> int. None = not counted.
+    flops_fn: Optional[Callable] = None
     # structured launch metadata for the static auditor: (ctx, plan,
     # *spec_args, **spec_kw) -> repro.verify.access.KernelAccessPlan. The
     # auditor abstractly interprets it and must reproduce words_fn exactly;
@@ -691,6 +695,11 @@ def _pallas_conv2d_words(ctx, plan, x, w, stride=(1, 1), out_dtype=None,
                             out_dtype=out_dtype or ctx.acc_dtype)
 
 
+def _pallas_conv2d_flops(ctx, plan, x, w, stride=(1, 1), **kw):
+    return conv2d_mxu_flops(x, w, stride=stride, plan=plan,
+                            target=ctx.target)
+
+
 def _pallas_attention_words(ctx, plan, q, k, v, **kw):
     B, H, Lq, Dh = q.shape
     Hkv, Lk = k.shape[1], k.shape[2]
@@ -782,6 +791,7 @@ register_backend(Backend(
         # stays measured (conv_bench's Im2Col-vs-tiled gap)
         "conv2d": OpEntry(_pallas_conv2d, spec_fn=_conv2d_plan_spec,
                           words_fn=_pallas_conv2d_words,
+                          flops_fn=_pallas_conv2d_flops,
                           access_plan_fn=_pallas_conv2d_access,
                           degrade_to="im2col"),
         # quantized entries: int8 streams only (f32/bf16 callers should use

@@ -65,10 +65,11 @@ class Hazard:
 def double_buffered_schedule(n_steps: int, n_slots: int = 2,
                              name: str = "stream",
                              write_back: bool = False) -> DmaSchedule:
-    """The schedule the conv and matmul kernels issue across the reduction
-    axis. ``write_back``: the last step's page, once landed, takes the new
-    row and is copied back out (store), read, and the copy drained — the
-    paged decode's page that holds the row's newest position."""
+    """The schedule the matmul kernel issues across its reduction axis, and
+    the conv kernel across its whole grid. ``write_back``: the last step's
+    page, once landed, takes the new row and is copied back out (store),
+    read, and the copy drained — the paged decode's page that holds the
+    row's newest position."""
     ev: List[DmaEvent] = []
     for ci in range(n_steps):
         slot = ci % n_slots

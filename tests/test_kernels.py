@@ -6,10 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import ops
+from repro.configs.resnet50_convs import RESNET50
 from repro.core.conv_model import Precision
 from repro.kernels import ref
 from repro.kernels.conv1d import conv1d_causal
-from repro.kernels.conv2d import conv2d
+from repro.kernels.conv2d import _geometry, conv2d
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matmul import matmul
 from repro.plan import ConvSpec, MatmulSpec, Planner, TPU_V5E
@@ -51,6 +53,24 @@ def test_matmul_tiles_divide_padded_problem():
 # conv2d
 # ---------------------------------------------------------------------------
 
+# the few-channel cases, whose column taps fold into the lanes at v5e
+# alignment: c_I 8 at 3x3, c_I 3 at 7x7 stride 2, c_I 5 at 3x5 stride (1, 2)
+FOLDED_CASES = [
+    (2, 8, 16, 12, 12, 3, 3, 1, 1),
+    (4, 3, 32, 20, 20, 7, 7, 2, 2),
+    (3, 5, 7, 11, 13, 3, 5, 1, 2),
+]
+
+
+def _conv2d_agrees(case, dtype, ctx=None):
+    N, cI, cO, H, W, hF, wF, sh, sw = case
+    x = jax.random.normal(KEY, (N, cI, H, W), dtype)
+    w = jax.random.normal(K2, (cO, cI, hF, wF), dtype)
+    got = conv2d(x, w, stride=(sh, sw), ctx=ctx)
+    want = ref.conv2d_ref(x, w, stride=(sh, sw))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_tol(dtype))
+
+
 @pytest.mark.parametrize("case", [
     (2, 8, 16, 12, 12, 3, 3, 1, 1),
     (4, 3, 32, 20, 20, 7, 7, 2, 2),
@@ -60,12 +80,46 @@ def test_matmul_tiles_divide_padded_problem():
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_conv2d_sweep(case, dtype):
+    _conv2d_agrees(case, dtype)
+
+
+@pytest.mark.parametrize("case", FOLDED_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_conv2d_sweep_v5e_alignment(case, dtype):
+    """The same sweep laid out for a v5e (interpreted): lane-padded
+    channels, and the column taps folded into the lanes."""
+    ctx = ops.ExecutionContext(target=TPU_V5E)
     N, cI, cO, H, W, hF, wF, sh, sw = case
-    x = jax.random.normal(KEY, (N, cI, H, W), dtype)
-    w = jax.random.normal(K2, (cO, cI, hF, wF), dtype)
-    got = conv2d(x, w, stride=(sh, sw))
-    want = ref.conv2d_ref(x, w, stride=(sh, sw))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_tol(dtype))
+    x = jax.ShapeDtypeStruct((N, cI, H, W), dtype)
+    w = jax.ShapeDtypeStruct((cO, cI, hF, wF), dtype)
+    assert _geometry(x, w, (sh, sw), ctx=ctx)[1].fold == -(-wF // sw)
+    _conv2d_agrees(case, dtype, ctx)
+
+
+def _v5e_geometry(layer):
+    s = RESNET50[layer]
+    x = jax.ShapeDtypeStruct(
+        (s.N, s.c_I, (s.h_O - 1) * s.sh + s.h_F, (s.w_O - 1) * s.sw + s.w_F),
+        jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((s.c_O, s.c_I, s.h_F, s.w_F), jnp.bfloat16)
+    return _geometry(x, w, (s.sh, s.sw),
+                     ctx=ops.ExecutionContext(target=TPU_V5E))[1]
+
+
+def test_only_the_stem_folds_at_v5e():
+    """ResNet-50's conv1 (12 space-to-depth channels x 4 column taps = 48
+    lanes) runs 4 x 1 taps on 128 lanes with no column halo; conv2_x
+    (64 x 3 = 192 lanes) and the full-lane layers keep their geometry."""
+    g = _v5e_geometry("conv1")
+    assert (g.hF, g.wF, g.fold, g.b_c) == (4, 1, 4, 128)
+    assert g.w_in == g.tiles[4] and g.Wp == g.wOp
+    for layer, grid in (("conv2_x", (63, 1, 4, 2, 1)),
+                        ("conv3_x", (63, 1, 2, 1, 1)),
+                        ("conv4_x", (21, 1, 2, 1, 2)),
+                        ("conv5_x", (16, 1, 3, 1, 4))):
+        g = _v5e_geometry(layer)
+        assert (g.hF, g.wF, g.fold, g.b_c, g.grid) == (3, 3, 1, 128, grid)
+        assert g.w_in == g.tiles[4] + 2 and g.Wp == g.wOp + 2
 
 
 def test_conv2d_tiles_from_lp_fit_vmem():

@@ -95,6 +95,12 @@ def _conv_shapes(layer):
     return s, ((s.N, s.c_I, H, W), BF16), ((s.c_O, s.c_I, s.h_F, s.w_F), BF16)
 
 
+# the kernel's filter operand (taps_h, taps_w, lanes, c_O lanes): only the
+# stem folds its column taps into the lanes
+CONV_FILTERS = {"conv1": "bf16[4,1,128,128]", "conv2_x": "bf16[3,3,128,128]",
+                "conv5_x": "bf16[3,3,512,512]"}
+
+
 @pytest.mark.parametrize("layer", ["conv1", "conv2_x", "conv5_x"])
 def test_pallas_conv_compiles(compile_for_chip, layer):
     """The paper's kernel on a strided stem, the widest halo and the
@@ -104,6 +110,10 @@ def test_pallas_conv_compiles(compile_for_chip, layer):
         ops.conv2d, stride=(s.sh, s.sw), ctx=_ctx("pallas")), x, w)
     assert "tpu_custom_call" in hlo
     assert "%conv2d_lp." in hlo  # the kernel's name, as a trace shows it
+    operands = re.search(r"%conv2d_lp\.\S* = .*operand_layout_constraints="
+                         r"\{(\w+\[[\d,]*\])\{[\d,]*\}, (\w+\[[\d,]*\])",
+                         hlo)
+    assert operands.group(2) == CONV_FILTERS[layer]
 
 
 def test_im2col_gemm_compiles_conv3_x(compile_for_chip):

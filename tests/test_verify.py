@@ -34,13 +34,48 @@ def _assert_exact(decision):
 # audit exactness: the abstract walk reproduces words_fn per registered op
 # ---------------------------------------------------------------------------
 
+# a stem whose column taps fold into the lanes: 3 channels x 4 phases x 4
+# column taps = 48 of 128 lanes
+FOLDED_X = jax.ShapeDtypeStruct((8, 3, 37, 37), jnp.bfloat16)
+FOLDED_W = jax.ShapeDtypeStruct((16, 3, 7, 7), jnp.bfloat16)
+
+
 def test_conv2d_audit_matches_words_fn_both_backends():
-    x = jax.ShapeDtypeStruct((8, 64, 58, 58), jnp.bfloat16)
-    w = jax.ShapeDtypeStruct((128, 64, 3, 3), jnp.bfloat16)
-    for ctx in (PALLAS, IM2COL):
-        d = ops.explain("conv2d", ctx, spec_args=(x, w),
-                        spec_kw={"stride": (2, 2)}, audit=True)
-        _assert_exact(d)
+    for x, w in ((jax.ShapeDtypeStruct((8, 64, 58, 58), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((128, 64, 3, 3), jnp.bfloat16)),
+                 (FOLDED_X, FOLDED_W)):
+        for ctx in (PALLAS, IM2COL):
+            d = ops.explain("conv2d", ctx, spec_args=(x, w),
+                            spec_kw={"stride": (2, 2)}, audit=True)
+            _assert_exact(d)
+
+
+def test_folded_conv2d_window_one_column_off_fails_coverage():
+    """Folded column j holds space-to-depth columns j .. j + 3, so a tile's
+    window needs exactly its own columns: one column to the right moves the
+    same words and misses the tile's first column."""
+    from repro.kernels.conv2d import _geometry, conv2d_access_plan
+    from repro.verify.access import WindowAccess
+
+    g = _geometry(FOLDED_X, FOLDED_W, (2, 2), target=TPU_V5E)[1]
+    assert (g.fold, g.wF, g.w_in) == (4, 1, g.tiles[4])
+    ap = conv2d_access_plan(FOLDED_X, FOLDED_W, stride=(2, 2),
+                            target=TPU_V5E)
+    assert audit_access_plan(ap).ok
+
+    def shifted(acc):
+        def window(*axes):
+            win = list(acc.window(*axes))
+            win[1] = (win[1][0] + 1, win[1][1])
+            return tuple(win)
+        return dataclasses.replace(acc, window=window)
+
+    off = tuple(shifted(a) if isinstance(a, WindowAccess) and a.name == "input"
+                else a for a in ap.accesses)
+    bad = audit_access_plan(dataclasses.replace(ap, accesses=off))
+    assert not bad.ok
+    assert any(p.startswith("input: dim 1") and "misses the required" in p
+               for p in bad.problems)
 
 
 def test_matmul_audit_matches_words_fn_including_fit_shrunk_tiles():
